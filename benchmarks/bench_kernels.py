@@ -71,7 +71,7 @@ def _plans_by_label(cfg, bucket):
 
 def run() -> Csv:
     be = engine.probe_backend()
-    peak = roofline.peak_bytes_per_s(be)
+    peak = roofline.peak_bytes_per_s()
     label = "measured-cpu" if be == "cpu" else f"measured-{be}"
     csv = Csv(["cell", "path", "plan", "qps", "modeled_mb",
                "achieved_frac_pct", "label"])
@@ -98,7 +98,7 @@ def run() -> Csv:
                 "plan": lbl, "wall_s": wall, "qps": BUCKET / wall,
                 "modeled_bytes": step_bytes,
                 "achieved_frac": roofline.achieved_fraction(
-                    step_bytes, wall, backend=be),
+                    step_bytes, wall),
             }
         for path, row in sorted(paths.items()):
             csv.add(f"{name}/b{BUCKET}", path, row["plan"], row["qps"],

@@ -89,6 +89,8 @@ def main(argv=None) -> int:
     names = [args.only] if args.only else BENCHES
     if args.report:
         return report(names)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rc = 0
     for name in names:
         mod = __import__(f"benchmarks.bench_{name}", fromlist=["run"])

@@ -37,7 +37,7 @@ python examples/replicas.py
 python examples/batch_query.py
 # engine-plane smoke: tiny-budget autotune (interpret mode, <=2 candidates
 # per kernel, nothing persisted) + the heuristic-fallback gate — asserts
-# an empty plan cache resolves to exactly the pre-engine plan_for choices
+# an empty plan cache resolves to exactly the heuristic's pinned choices
 python -m repro.engine --smoke
 # chaos-plane smoke: seeded kill + share-corruption scenarios on the
 # 2-replica LWE fleet — asserts detection (InjectedFault / IntegrityError,

@@ -174,19 +174,14 @@ def plan_for(cfg: PIRConfig, n_queries: int, *,
 
     Since the engine plane this is a thin alias of
     ``engine.heuristic_plan`` — the deterministic fallback the plan cache
-    misses to. The selection rules (DESIGN.md §7.3) are unchanged:
-      * additive protocols contract via the GEMM regardless — ``scan``
-        chooses jnp dot vs the Pallas ``pir_matmul`` body;
-      * XOR protocols materialize bits only while the per-query bit vector
-        stays small (db <= 2^chunk_log rows — a global-size heuristic: a
-        sharded mesh divides the per-device rows further, only making
-        materialization cheaper); past that the fused chunked expand+scan
-        keeps selection bits out of HBM;
-      * the Pallas bodies run real Mosaic only on a TPU backend — on CPU
-        they would execute in interpret mode, so the jnp oracle (which XLA
-        compiles natively) is the fast CPU path;
-      * batch bucket: single-query buckets skip the fused chunk machinery
-        (nothing to amortize; the materialized form has the simpler HLO).
+    misses to. The selection rules (DESIGN.md §7.3), in short: the
+    selection vector is materialized only while the DB fits one chunk
+    (db <= 2^chunk_log rows — a global-size rule: a sharded mesh divides
+    the per-device rows further, only making materialization cheaper);
+    past that, XOR protocols take the fused chunked expand+scan and
+    additive protocols on a TPU the ``fused-pallas`` megakernel; the
+    Pallas bodies run only on a TPU backend (on CPU they would execute in
+    interpret mode); LWE always contracts with XLA's int32 dot.
     """
     from repro.engine.tuner import heuristic_plan
     return heuristic_plan(cfg, n_queries, backend=backend,

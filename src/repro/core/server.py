@@ -43,7 +43,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.config import PIRConfig
 from repro.core import dpf
 from repro.core import protocol as protocol_mod
@@ -193,7 +192,7 @@ def build_serve_fn(
 
     def serve(db, keys):
         ks = keys_spec_builder(keys)
-        fn = shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(db_spec, ks), out_specs=out_spec,
             check_vma=False,

@@ -17,7 +17,8 @@ Views (DESIGN.md §8.1)
     (``PIRProtocol.db_view``): ``words`` (u32, XOR schemes), ``bytes``
     (int8, the additive GEMM) or ``bytes32`` (int32 bytes, the LWE GEMM).
     Derived views are packed **on device** from the resident word view
-    (one elementwise pack, lazily on first use) and thereafter maintained
+    (each shard packs its rows, in chunks, lazily on first use) and
+    thereafter maintained
     *incrementally* by the update path — never re-packed from scratch,
     never round-tripped through the host.
 
@@ -48,6 +49,7 @@ O(db_bytes).
 """
 from __future__ import annotations
 
+import math
 import threading
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple, Union
@@ -60,6 +62,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.config import PIRConfig
 from repro.db.spec import DatabaseSpec
 from repro.launch.mesh import pir_shard_axis
+
+#: most rows a derived view is packed in at once (see ``_derive``)
+_PACK_ROWS = 1 << 20
 
 
 @dataclass
@@ -232,9 +237,21 @@ class ShardedDatabase:
         self.stats.n_view_packs += 1
         if name not in self._pack_cache:
             spec = self.spec
-            self._pack_cache[name] = jax.jit(
-                lambda w, name=name: spec.words_to_view_device(name, w),
-                out_shardings=self.sharding(name))
+
+            def pack(w, name=name):
+                # each shard packs its own rows in chunks of <= 2^20: as
+                # one op over a GiB-scale shard the TPU compile takes ~2 min
+                # and reports 8 GiB of temp; per chunk it takes seconds
+                n = w.shape[0]
+                chunk = math.gcd(n, _PACK_ROWS)
+                out = jax.lax.map(
+                    lambda x: spec.words_to_view_device(name, x),
+                    w.reshape(n // chunk, chunk, w.shape[1]))
+                return out.reshape(n, out.shape[-1])
+
+            self._pack_cache[name] = jax.jit(jax.shard_map(
+                pack, mesh=self.mesh, in_specs=self._row_spec,
+                out_specs=self._row_spec, check_vma=False))
         return self._pack_cache[name](words)
 
     # ------------------------------------------------------------------

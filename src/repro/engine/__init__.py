@@ -17,8 +17,8 @@ This package owns plan selection end to end (DESIGN.md §9):
 
 :func:`resolve` is the seam the protocol plane delegates to
 (``core/protocol.py resolve_plan`` with ``path=None/"auto"``): cache hit →
-tuned plan; miss → the deterministic heuristic, bit-for-bit the
-pre-engine ``plan_for``. Resolution happens once per bucket at
+tuned plan; miss → the deterministic heuristic (``heuristic_plan``, which
+``plan_for`` aliases). Resolution happens once per bucket at
 ``BucketedServeFns`` build time — never on the dispatch path.
 """
 from __future__ import annotations
@@ -75,8 +75,7 @@ def resolve(cfg, n_queries: int, *, backend_name: Optional[str] = None,
 
     The tuned plan keeps its measured tiling (including chunk_log); only
     the collective — a topology choice the tuner does not measure — is
-    taken from the caller. The miss path is ``heuristic_plan``, i.e. the
-    pre-engine ``plan_for`` verbatim.
+    taken from the caller. The miss path is ``heuristic_plan``.
     """
     be = backend_name or probe_backend()
     hit = plan_cache().get(be, cfg.protocol, spec_signature(cfg), n_queries)
@@ -110,11 +109,11 @@ def record_plans(cfg, plans: dict, *, backend_name: Optional[str] = None,
 
 
 def plan_report(cfg, plan, bucket: int, *, n_shards: int = 1,
-                measured_wall_s: Optional[float] = None,
-                backend_name: Optional[str] = None) -> dict:
+                measured_wall_s: Optional[float] = None) -> dict:
     """Reporting row for one bucket's chosen plan: provenance, the modeled
-    HBM bytes its answer step moves, and the backend's bandwidth roof those
-    bytes are judged against (dry-run / launch / bench surfaces).
+    HBM bytes its answer step moves, and the bandwidth roof of this
+    process's device (``analysis.roofline.PEAKS``, keyed by device kind)
+    those bytes are judged against (dry-run / launch / bench surfaces).
 
     Pass ``measured_wall_s`` (e.g. a tuner timing) to additionally report
     ``achieved_frac`` — the fraction of peak bandwidth the measured run
@@ -122,7 +121,6 @@ def plan_report(cfg, plan, bucket: int, *, n_shards: int = 1,
     """
     from repro.analysis.roofline import achieved_fraction, peak_bytes_per_s
     from repro.core import protocol as protocol_mod
-    be = backend_name or probe_backend()
     proto = protocol_mod.get(cfg.protocol)
     shape = problem_shape(cfg, bucket, n_shards=n_shards)
     step_bytes = predicted_step_bytes(plan, proto.share_kind, shape)
@@ -131,11 +129,10 @@ def plan_report(cfg, plan, bucket: int, *, n_shards: int = 1,
         "label": plan_label(plan),
         "provenance": plan.provenance,
         "predicted_step_bytes": step_bytes,
-        "peak_bytes_per_s": peak_bytes_per_s(be),
+        "peak_bytes_per_s": peak_bytes_per_s(),
     }
     if measured_wall_s is not None:
         out["measured_wall_s"] = measured_wall_s
         out["achieved_frac"] = achieved_fraction(step_bytes,
-                                                 measured_wall_s,
-                                                 backend=be)
+                                                 measured_wall_s)
     return out

@@ -10,8 +10,8 @@ where the spec signature is ``"{n_items}x{item_bytes}"`` — exactly the
 shape axes plan selection depends on. Lookup happens once per bucket at
 ``BucketedServeFns`` build time (never on the dispatch path); a hit
 returns the tuned plan (provenance ``"tuned"``), a miss falls through to
-the deterministic heuristic, so a machine without a cache file behaves
-bit-for-bit like the pre-engine stack.
+the deterministic heuristic, so a machine without a cache file runs the
+heuristic's plans.
 
 Robustness contract (tested): a missing, corrupted, or stale-schema cache
 file silently degrades to "no cache" — tuning artifacts must never be able

@@ -80,6 +80,9 @@ class KernelDescriptor:
     bytes_fn: Callable[[ProblemShape, Dict[str, int]], int] = \
         field(default=lambda s, p: 0)
     serve: bool = True
+    #: False for a body the TPU compiler refuses (Mosaic has no int32
+    #: matmul on v5e): the tuner never offers it on a TPU backend
+    mosaic: bool = True
 
     def feasible(self, shape: ProblemShape, params: Dict[str, int]) -> bool:
         return self.footprint_fn(shape, params) <= VMEM_BYTES
@@ -123,10 +126,13 @@ def register_kernel(desc: KernelDescriptor) -> KernelDescriptor:
     return desc
 
 
-def serve_kernels(share_kind: str) -> List[KernelDescriptor]:
-    """Serve-path descriptors for one share algebra, registry order."""
+def serve_kernels(share_kind: str, backend: Optional[str] = None
+                  ) -> List[KernelDescriptor]:
+    """Serve-path descriptors for one share algebra, registry order;
+    with ``backend="tpu"``, only the bodies the TPU compiler accepts."""
     return [d for d in KERNELS.values()
-            if d.serve and d.share_kind == share_kind]
+            if d.serve and d.share_kind == share_kind
+            and (d.mosaic or backend != "tpu")]
 
 
 def get_kernel(name: str) -> KernelDescriptor:
@@ -257,16 +263,47 @@ def _fused_pallas_legalize(shape: ProblemShape,
     return {**p, "tile_r": tr, "chunk_log": cl, "depth": d}
 
 
+#: Mosaic's VMEM for the in-kernel GGM expansion, in u32 words per DB row
+#: of a tile and per query (ChaCha state, both children, the interleave
+#: and the fold / share-conversion temporaries), queries padded to 4
+#: sublanes. Calibrated against the v5e compiler's scoped-VMEM reports
+#: (tests/test_tpu_compile.py compiles a case on each side of the 16 MiB
+#: bound); the model over-counts by a few percent, never under.
+_EXPAND_WORDS_XOR = 128
+_EXPAND_WORDS_ADD = 144
+_FOLD_WORDS = 256
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _fused_pallas_common(shape: ProblemShape, p: Dict[str, int],
+                         words_per_row: int) -> Tuple[int, int, int]:
+    """(tile_r, depth, VMEM bytes both bodies hold besides their DB
+    buffers): the expansion, one packed key block per DMA slot (5 words
+    per chunk root, lanes padded to 128), the VMEM-resident CW levels and
+    the output block."""
+    q = shape.bucket
+    tr = p.get("tile_r", legal_tile(shape.rows, 2048, pow2=True))
+    cl = min(p.get("chunk_log", 12), tr.bit_length() - 1)
+    d = p.get("depth", 2)
+    q8 = _round_up(q, 8)
+    key_block = q8 * _round_up(5 * (tr >> cl), 128)
+    cw_levels = max(cl, 1) * q8 * 128
+    out = q8 * _round_up(shape.item_bytes, 128)
+    words = (_round_up(q, 4) * tr * words_per_row + d * key_block
+             + cw_levels + out)
+    return tr, d, U32_BYTES * words
+
+
 def _fused_pallas_xor_footprint(shape: ProblemShape,
                                 p: Dict[str, int]) -> int:
-    q, w = shape.bucket, shape.words
-    tr = p.get("tile_r", legal_tile(shape.rows, 2048, pow2=True))
-    d = p.get("depth", 2)
-    # d rotating DB buffers [W, TR]; expand scratch per tile: 16 ChaCha
-    # state rows + 10 output rows + 1 t row at [Q, TR]; the masked
-    # intermediate [Q, W, TR]; the accumulator [Q, W]
-    return U32_BYTES * (d * w * tr + q * tr * (16 + 10 + 1)
-                        + q * w * tr + q * w)
+    tr, d, rest = _fused_pallas_common(shape, p, _EXPAND_WORDS_XOR)
+    # d rotating u32 DB buffers [W, TR]; the masked tile's lane fold
+    # keeps up to _FOLD_WORDS words per (query, record word)
+    fold = _round_up(shape.bucket, 4) * shape.words * _FOLD_WORDS
+    return (d * shape.words * tr + fold) * U32_BYTES + rest
 
 
 def _fused_pallas_xor_bytes(shape: ProblemShape, p: Dict[str, int]) -> int:
@@ -281,12 +318,10 @@ def _fused_pallas_xor_bytes(shape: ProblemShape, p: Dict[str, int]) -> int:
 
 def _fused_pallas_add_footprint(shape: ProblemShape,
                                 p: Dict[str, int]) -> int:
-    q, l = shape.bucket, shape.item_bytes
-    tr = p.get("tile_r", legal_tile(shape.rows, 2048, pow2=True))
-    d = p.get("depth", 2)
-    # d int8 DB buffers [TR, L]; u32 expand + share-conversion scratch
-    # (16 state + 10 out + 1 t + 1 conv rows at [Q, TR]); int32 out [Q, L]
-    return (d * tr * l + 4 * q * tr * (16 + 10 + 1 + 1) + 4 * q * l)
+    tr, d, rest = _fused_pallas_common(shape, p, _EXPAND_WORDS_ADD)
+    # d rotating int8 DB buffers [L, TR] + the cw_final column [Q, 1]
+    q8 = _round_up(shape.bucket, 8)
+    return d * shape.item_bytes * tr + rest + U32_BYTES * q8 * 128
 
 
 def _fused_pallas_add_bytes(shape: ProblemShape, p: Dict[str, int]) -> int:
@@ -367,7 +402,7 @@ LWE_GEMM_PALLAS = register_kernel(KernelDescriptor(
     name="lwe-gemm-pallas", share_kind="lwe",
     expand="materialize", scan="pallas",
     space_fn=_gemm_space, footprint_fn=_lwe_gemm_footprint,
-    bytes_fn=_lwe_gemm_bytes,
+    bytes_fn=_lwe_gemm_bytes, mosaic=False,
 ))
 
 GGM_EXPAND = register_kernel(KernelDescriptor(

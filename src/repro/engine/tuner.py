@@ -18,8 +18,7 @@ measurement. The tuner closes that loop:
 
 The **heuristic is always candidate #0** and is always measured, so a tune
 can only ever match or beat it — and a cache miss falls back to it
-bit-for-bit (``heuristic_plan`` reproduces the pre-engine ``plan_for``
-exactly, modulo the backend probe now honoring ``REPRO_FORCE_BACKEND``).
+bit-for-bit (``heuristic_plan``, the rules ``plan_for`` aliases).
 
 Budgets: measurement costs wall clock (and, on this CPU container, XLA
 compiles of interpret-mode Pallas bodies), so every entry point takes a
@@ -44,46 +43,77 @@ from repro.engine.kernels import (ProblemShape, GEMM_TILE_R_DEFAULT,
 
 
 # ---------------------------------------------------------------------------
-# The deterministic fallback: the pre-engine plan_for, verbatim
+# The deterministic fallback (what plan_for aliases)
 # ---------------------------------------------------------------------------
 
 def heuristic_plan(cfg, n_queries: int, *, backend: Optional[str] = None,
                    chunk_log: int = 12):
     """Pick the kernel path per (db size, batch bucket, backend).
 
-    The selection rules are the pre-engine ``core.protocol.plan_for``
-    logic, preserved bit-for-bit (DESIGN.md §7.3, asserted by
-    tests/test_engine.py against an inline replica):
+    The deterministic fallback a plan-cache miss resolves to (DESIGN.md
+    §7.3, asserted by tests/test_engine.py against an inline replica):
 
-      * additive protocols contract via the GEMM regardless — ``scan``
-        chooses jnp dot vs the Pallas ``pir_matmul`` body (reduction tile
-        pinned to the pre-engine kernel default);
-      * XOR protocols materialize bits only while the per-query bit vector
-        stays small (db <= 2^chunk_log rows); past that the fused chunked
-        expand+scan keeps selection bits out of HBM;
+      * the selection vector is materialized only while the DB fits one
+        chunk (``n_items <= 2^chunk_log``): the full-domain evaluation
+        keeps ``[Q, rows, 4]`` u32 seeds, lane-padded 32x on a TPU, so at
+        2^25 rows one party's single-query step needs 9.5 GiB of temp and
+        a four-query additive step 32 GiB;
+      * past that, XOR protocols take the fused chunked expand+scan (the
+        selection bits never reach HBM) at every bucket size — its inner
+        fold is always the jnp ``dpxor``, so its ``scan`` is "jnp" on
+        every backend — and additive protocols on a TPU take the
+        ``fused-pallas`` megakernel at the largest tile (<= 2048 rows)
+        whose VMEM footprint fits;
       * the Pallas bodies run real Mosaic only on a TPU backend — on CPU
         they would execute in interpret mode, so the jnp oracle is the
-        fast CPU path;
-      * single-query buckets skip the fused chunk machinery.
+        fast CPU path, and additive protocols keep the materialized GEMM;
+      * LWE contracts with XLA's int32 dot on every backend: the v5e MXU
+        has no int32 matmul, so Mosaic refuses the Pallas int32 body.
 
-    The only behavioral delta vs the pre-engine code: the backend probe is
-    ``engine.probe_backend()`` (one probe for the whole stack, ``REPRO_FORCE_
-    BACKEND``-overridable) instead of a raw ``jax.default_backend()``.
+    The backend probe is ``engine.probe_backend()`` (one probe for the
+    whole stack, ``REPRO_FORCE_BACKEND``-overridable).
     """
     from repro.core import protocol as protocol_mod
     if backend is None:
         backend = probe_backend()
     scan = "pallas" if backend == "tpu" else "jnp"
     proto = protocol_mod.get(cfg.protocol)
-    if proto.share_kind in ("additive", "lwe"):
-        # both contract via a materialized GEMM (int8 / int32); same rule
+    small_db = cfg.n_items <= (1 << chunk_log)
+    if proto.share_kind == "lwe":
+        return protocol_mod.ExecutionPlan(
+            expand="materialize", scan="jnp", chunk_log=chunk_log,
+            tile_r=GEMM_TILE_R_DEFAULT)
+    if proto.share_kind == "additive":
+        if backend == "tpu" and not small_db:
+            plan = _fitting_fused_pallas(cfg, n_queries, chunk_log)
+            if plan is not None:
+                return plan
         return protocol_mod.ExecutionPlan(
             expand="materialize", scan=scan, chunk_log=chunk_log,
             tile_r=GEMM_TILE_R_DEFAULT)
-    small_db = cfg.n_items <= (1 << chunk_log)
-    expand = "materialize" if small_db or n_queries <= 1 else "fused"
-    return protocol_mod.ExecutionPlan(expand=expand, scan=scan,
+    if small_db:
+        return protocol_mod.ExecutionPlan(expand="materialize", scan=scan,
+                                          chunk_log=chunk_log)
+    return protocol_mod.ExecutionPlan(expand="fused", scan="jnp",
                                       chunk_log=chunk_log)
+
+
+def _fitting_fused_pallas(cfg, n_queries: int, chunk_log: int):
+    """The additive megakernel plan at the largest tile <= 2048 rows whose
+    VMEM footprint fits, or None when even a 128-row tile does not."""
+    from repro.core import protocol as protocol_mod
+    desc = get_kernel("gemm-fused-pallas")
+    shape = problem_shape(cfg, n_queries)
+    tile = 2048
+    while tile >= 128:
+        params = desc.legalize_fn(shape, {"tile_r": tile,
+                                          "chunk_log": chunk_log,
+                                          "depth": 2})
+        if desc.feasible(shape, params):
+            return protocol_mod.ExecutionPlan(
+                expand="fused-pallas", scan="pallas", **params)
+        tile //= 2
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -92,13 +122,15 @@ def heuristic_plan(cfg, n_queries: int, *, backend: Optional[str] = None,
 
 def candidate_plans(cfg, bucket: int, *, n_shards: int = 1,
                     chunk_log: int = 12, collective: str = "gather",
-                    max_per_kernel: Optional[int] = None) -> List:
+                    max_per_kernel: Optional[int] = None,
+                    backend: Optional[str] = None) -> List:
     """Feasible ExecutionPlans for (cfg, bucket): the tuner's search space.
 
     One entry per surviving point of each registered serve kernel's
     parameter space; infeasible tilings (VMEM-footprint model) are pruned
-    here, without ever being run. ``n_shards`` scales the per-shard row
-    count the tiles must be legal for.
+    here, without ever being run, and so are bodies the TPU compiler
+    refuses when ``backend`` (default: the probe) is a TPU. ``n_shards``
+    scales the per-shard row count the tiles must be legal for.
     """
     from repro.core import protocol as protocol_mod
     proto = protocol_mod.get(cfg.protocol)
@@ -107,7 +139,8 @@ def candidate_plans(cfg, bucket: int, *, n_shards: int = 1,
                                                     shape.log_rows),
                                       collective=collective)
     plans: List = []
-    for desc in serve_kernels(proto.share_kind):
+    for desc in serve_kernels(proto.share_kind,
+                              backend or probe_backend()):
         for plan in plans_from_kernel(desc, shape, base_plan=base,
                                       max_candidates=max_per_kernel):
             if plan not in plans:
@@ -154,20 +187,6 @@ def _plan_defaults():
         from repro.core.protocol import ExecutionPlan
         _DEFAULT_PLAN = ExecutionPlan()
     return _DEFAULT_PLAN
-
-
-def _canonical(plan):
-    """Normalize execution-irrelevant plan fields before dedup/timing.
-
-    The fused XOR body's inner fold is always the jnp ``dpxor`` —
-    ``plan.scan`` never reaches it — so on a TPU backend the heuristic's
-    fused/pallas and the registry's fused/jnp candidate are the same
-    executable. Canonicalizing ``scan`` keeps the tuner from compiling
-    and timing it twice.
-    """
-    if plan.expand == "fused" and plan.scan != "jnp":
-        return replace(plan, scan="jnp")
-    return plan
 
 
 # ---------------------------------------------------------------------------
@@ -259,17 +278,17 @@ def tune(cfg, bucket: int, *, backend: Optional[str] = None,
     be = backend or probe_backend()
     proto = protocol_mod.get(cfg.protocol)
     heur = heuristic_plan(cfg, bucket, backend=be, chunk_log=chunk_log)
-    heur = _canonical(replace(heur, collective=collective))
-    cands = [_canonical(p) for p in
-             candidate_plans(cfg, bucket, chunk_log=chunk_log,
-                             collective=collective,
-                             max_per_kernel=budget.max_candidates)]
+    heur = replace(heur, collective=collective)
+    cands = candidate_plans(cfg, bucket, chunk_log=chunk_log,
+                            collective=collective,
+                            max_per_kernel=budget.max_candidates,
+                            backend=be)
     ordered = [heur] + [p for p in cands if p != heur]
 
     db, keys = _measurement_inputs(cfg, bucket, proto, seed)
     log_local = cfg.log_n
     shape = problem_shape(cfg, bucket)
-    peak = roofline.peak_bytes_per_s(be)
+    peak = roofline.peak_bytes_per_s()        # this device's own kind
     t_start = time.perf_counter()
     timings: Dict[str, float] = {}
     n_pruned = 0
@@ -375,20 +394,23 @@ def tune_standalone(kernel_name: str, n: int, *,
 # CI smoke: tiny-budget tune + heuristic-fallback equivalence gate
 # ---------------------------------------------------------------------------
 
-#: the pre-engine ``plan_for`` choices on the smoke grid, as literals —
+#: the heuristic's choices on the smoke grid, as literals —
 #: (protocol, log_n, n_queries, backend) -> (expand, scan). Hardcoded
 #: rather than computed so the gate is independent of ``heuristic_plan``
 #: (a rule change there cannot silently rewrite its own oracle).
-_PRE_ENGINE_EXPECTED = {
+_EXPECTED_PLANS = {
     ("xor-dpf-2", 10, 1, "cpu"): ("materialize", "jnp"),
     ("xor-dpf-2", 10, 4, "cpu"): ("materialize", "jnp"),
     ("xor-dpf-2", 10, 4, "tpu"): ("materialize", "pallas"),
     ("additive-dpf-2", 10, 1, "cpu"): ("materialize", "jnp"),
     ("additive-dpf-2", 10, 4, "cpu"): ("materialize", "jnp"),
     ("additive-dpf-2", 10, 4, "tpu"): ("materialize", "pallas"),
-    ("xor-dpf-2", 14, 1, "cpu"): ("materialize", "jnp"),   # single query
+    ("xor-dpf-2", 14, 1, "cpu"): ("fused", "jnp"),         # single query
     ("xor-dpf-2", 14, 4, "cpu"): ("fused", "jnp"),         # big-db regime
-    ("xor-dpf-2", 14, 4, "tpu"): ("fused", "pallas"),
+    ("xor-dpf-2", 14, 4, "tpu"): ("fused", "jnp"),
+    ("additive-dpf-2", 14, 4, "cpu"): ("materialize", "jnp"),
+    ("additive-dpf-2", 14, 4, "tpu"): ("fused-pallas", "pallas"),
+    ("lwe-simple-1", 14, 4, "tpu"): ("materialize", "jnp"),
 }
 
 
@@ -398,26 +420,31 @@ def smoke() -> int:
     Interpret mode (CPU), ≤2 candidates per kernel, one bucket per
     protocol — and, for every cell of a small grid, asserts the
     heuristic-fallback plan (what an empty cache resolves to) equals the
-    pre-engine ``plan_for`` output, pinned above as literals. Nothing is
-    persisted. (tests/test_engine.py holds the broader independent
-    replica of the old rules; this is the fast CI spot check.)
+    choice pinned above as literals. Nothing is persisted.
+    (tests/test_engine.py holds the broader independent replica of the
+    rules; this is the fast CI spot check.)
     """
     from repro.config import PIRConfig
     from repro.core.protocol import plan_for
     from repro.engine.cache import PlanCache
 
-    for (proto, log_n, n_q, be), want in _PRE_ENGINE_EXPECTED.items():
-        cfg = PIRConfig(n_items=1 << log_n, item_bytes=32, protocol=proto)
+    for (proto, log_n, n_q, be), want in _EXPECTED_PLANS.items():
+        cfg = PIRConfig(n_items=1 << log_n, item_bytes=32, protocol=proto,
+                        n_servers=1 if proto == "lwe-simple-1" else 2)
         got = plan_for(cfg, n_q, backend=be)
         assert (got.expand, got.scan) == want, (
-            f"heuristic drifted from the pre-engine plan_for: "
+            f"heuristic drifted from its pinned choice: "
             f"{proto} 2^{log_n} n_q={n_q} {be}: "
             f"{(got.expand, got.scan)} != {want}")
-        assert got.chunk_log == 12 and got.provenance == "heuristic"
-        if proto == "additive-dpf-2":
+        assert got.provenance == "heuristic"
+        if got.expand == "fused-pallas":      # legalized: whole chunks/tile
+            assert (1 << got.chunk_log) <= got.tile_r
+        else:
+            assert got.chunk_log == 12
+        if got.expand == "materialize" and proto != "xor-dpf-2":
             assert got.tile_r == GEMM_TILE_R_DEFAULT
-    print("[smoke] heuristic fallback == pre-engine plan_for "
-          f"on {len(_PRE_ENGINE_EXPECTED)} grid cells")
+    print("[smoke] heuristic fallback == pinned plan choices "
+          f"on {len(_EXPECTED_PLANS)} grid cells")
     grid = [
         PIRConfig(n_items=1 << 10, item_bytes=32),
         PIRConfig(n_items=1 << 10, item_bytes=32,

@@ -13,28 +13,35 @@ but still round-tripped each chunk's fold through separate XLA ops).
 
 Structure (DESIGN.md §13)
 -------------------------
-One ``pallas_call`` with no grid. The DB input lives in ``pltpu.ANY``
-memory space (HBM on TPU); a ``[depth, ...]`` VMEM scratch holds the
-rotating DMA buffers, paired with a ``[depth]`` DMA-semaphore array:
+One ``pallas_call`` with no grid. The DB and the per-tile key blocks live
+in ``pl.ANY`` memory space (HBM on TPU); ``[depth, ...]`` VMEM scratch
+buffers hold the rotating DMA slots, paired with a ``[2, depth]``
+DMA-semaphore array (row 0: DB tile, row 1: key block):
 
   prologue:  start async copies for tiles 0..depth-1
   tile i:    wait slot (i % depth)  ->  expand the tile's GGM leaves
              from its chunk roots   ->  accumulate the select-reduction
-             ->  start the copy for tile i+depth into the freed slot
+             ->  start the copies for tile i+depth into the freed slot
 
-The same ``fori_loop`` program runs under interpret mode (bit-exact CPU
-validation — ``pltpu.emit_pipeline`` cannot, which is why the rotation is
-manual) and compiles to genuinely overlapped DMA on real TPUs.
+Only VMEM refs are ever loaded: the correction words of the last ``clog``
+levels (a few hundred bytes) are VMEM-resident whole, and the answer is
+written through a VMEM output block. The same ``fori_loop`` program runs
+under interpret mode (bit-exact CPU validation — ``pltpu.emit_pipeline``
+cannot, which is why the rotation is manual) and compiles to genuinely
+overlapped DMA on real TPUs.
 
 Inputs are *chunk roots*: the host precomputes each query's GGM descent
 down to depth ``log_n - chunk_log`` (``dpf.eval_roots_batch`` — shared
 across all chunks, unlike the chunked-jnp path which re-descends per
 chunk) and ships ``[Q, n_chunks]`` subtree seeds + control bits plus the
-last ``chunk_log`` levels of correction words. The kernel breadth-expands
-those ``chunk_log`` levels in VMEM with the same ChaCha rounds as
-``kernels/ggm_expand.py`` (bit-exactness with ``crypto.chacha`` is what
-makes the byte-parity suite possible), interleaving children so leaf j of
-the tile lands in lane j.
+last ``chunk_log`` levels of correction words. The jitted entry points
+pack the roots per DMA tile as ``[n_tiles, Q, 5 * cpt]`` (4 seed words and
+the control bit of the tile's ``cpt`` chunks, side by side on lanes, padded
+to whole 128-lane vregs), so each tile's block is one leading-dim slice —
+aligned for any ``cpt``. The kernel breadth-expands those ``chunk_log``
+levels in VMEM with the same ChaCha rounds as ``kernels/ggm_expand.py``
+(bit-exactness with ``crypto.chacha`` is what makes the byte-parity suite
+possible), interleaving children so leaf j of the tile lands in lane j.
 
 Two accumulation bodies share the expansion:
 
@@ -42,9 +49,11 @@ Two accumulation bodies share the expansion:
             -> lane-halving XOR fold (exactly ``dpxor``'s reduction), so
             the answer is bit-identical to the materialized path.
   additive  leaf seeds -> payload-conversion PRG (counter=1) -> Z_256
-            shares with int8 *sign semantics* reproduced in-kernel
-            (share - 256 where share >= 128) -> int32 dot against the
-            int8 DB tile: bit-identical int32 to the materialized GEMM.
+            shares as int8 (two's complement of the byte, the sign
+            semantics of the materialized int8 GEMM) -> int8 x int8 ->
+            int32 dot against the [L, tile_r] int8 DB tile (the byte view
+            transposed, which is its resident HBM layout): bit-identical
+            int32 to the materialized GEMM.
 """
 from __future__ import annotations
 
@@ -61,6 +70,11 @@ from repro.kernels.ggm_expand import _chacha_rows
 
 U32 = jnp.uint32
 
+#: words per chunk root in a packed key block: 4 seed words + control bit
+KEY_WORDS = 5
+#: correction-word columns per level: 4 seed words + (tL, tR)
+CW_WORDS = 6
+
 
 def _interleave(left: jax.Array, right: jax.Array) -> jax.Array:
     """[Q, m] x2 -> [Q, 2m] with children interleaved to leaf order."""
@@ -68,112 +82,112 @@ def _interleave(left: jax.Array, right: jax.Array) -> jax.Array:
     return jnp.stack([left, right], axis=-1).reshape(q, 2 * m)
 
 
-def _expand_tile(seed_rows, t, cws_ref, cwt_ref, *, clog: int, rounds: int):
+def _expand_tile(seed_rows, t, cw_ref, *, clog: int, rounds: int):
     """Breadth-expand ``clog`` corrected GGM levels for one DB tile.
 
     seed_rows: list of 4 ``[Q, m]`` u32 chunk-root seed words; t: ``[Q, m]``
-    control bits. cws_ref ``[clog, 4, Q]`` / cwt_ref ``[clog, 2, Q]`` carry
-    the per-query correction words for the *last* clog tree levels.
+    control bits. cw_ref ``[clog, Q, 6]`` carries the per-query correction
+    words (4 seed words, then tL, tR) for the *last* clog tree levels.
     Returns (leaf seed_rows [Q, m << clog] x4, leaf t [Q, m << clog]).
     """
     for lvl in range(clog):
         out = _chacha_rows(seed_rows, counter=0, rounds=rounds)
+        cw = cw_ref[lvl]                                     # [Q, 6]
         mask = U32(0) - t                                    # [Q, m]
         new_rows = []
         for w in range(4):
-            cw = cws_ref[lvl, w, :][:, None]                 # [Q, 1]
-            new_rows.append(_interleave(out[w] ^ (mask & cw),
-                                        out[4 + w] ^ (mask & cw)))
-        t_l = (out[8] & U32(1)) ^ (t & cwt_ref[lvl, 0, :][:, None])
-        t_r = (out[9] & U32(1)) ^ (t & cwt_ref[lvl, 1, :][:, None])
+            cw_w = mask & cw[:, w:w + 1]
+            new_rows.append(_interleave(out[w] ^ cw_w, out[4 + w] ^ cw_w))
+        t_l = (out[8] & U32(1)) ^ (t & cw[:, 4:5])
+        t_r = (out[9] & U32(1)) ^ (t & cw[:, 5:6])
         seed_rows = new_rows
         t = _interleave(t_l, t_r)
     return seed_rows, t
 
 
-def _fused_xor_kernel(roots_ref, troots_ref, cws_ref, cwt_ref, db_ref,
-                      out_ref, buf_ref, sem_ref, *, tile_r: int, clog: int,
-                      depth: int, rounds: int, n_tiles: int):
-    """XOR body: db_t [W, R] (ANY) -> out [Q, W] (VMEM)."""
-    cpt = tile_r >> clog                   # chunk roots per tile
-    q, w_words = out_ref.shape
+def _scan_tiles(db_hbm, keys_hbm, db_buf, key_buf, sem, cw_ref, acc0,
+                accumulate, *, tile_r: int, clog: int, depth: int,
+                rounds: int, n_tiles: int):
+    """The rotating-DMA tile loop both bodies share.
 
-    def copy_in(i, slot):
-        return pltpu.make_async_copy(
-            db_ref.at[:, pl.ds(i * tile_r, tile_r)],
-            buf_ref.at[slot], sem_ref.at[slot])
+    ``db_hbm`` is the DB shard with rows on its last axis; ``accumulate(
+    acc, seed_rows, t, db_tile_ref)`` folds one tile's expanded leaves
+    into the accumulator.
+    """
+    cpt = tile_r >> clog
+
+    def copies(i, slot):               # DB tile i and its key block
+        return (pltpu.make_async_copy(db_hbm.at[:, pl.ds(i * tile_r, tile_r)],
+                                      db_buf.at[slot], sem.at[0, slot]),
+                pltpu.make_async_copy(keys_hbm.at[i], key_buf.at[slot],
+                                      sem.at[1, slot]))
 
     for s in range(min(depth, n_tiles)):   # prologue: fill the pipeline
-        copy_in(s, s).start()
+        for c in copies(s, s):
+            c.start()
 
     def body(i, acc):
         slot = jax.lax.rem(i, depth)
-        copy_in(i, slot).wait()
-        c0 = i * cpt
-        seed_rows = [roots_ref[w, :, pl.ds(c0, cpt)] for w in range(4)]
-        t = troots_ref[:, pl.ds(c0, cpt)]
-        _, bits = _expand_tile(seed_rows, t, cws_ref, cwt_ref,
-                               clog=clog, rounds=rounds)
-        mask = U32(0) - bits                               # [Q, tile_r]
-        db_tile = buf_ref[slot]                            # [W, tile_r]
-        masked = mask[:, None, :] & db_tile[None, :, :]    # [Q, W, tile_r]
-        acc = acc ^ _fold_xor_lanes(masked)[..., 0]
+        for c in copies(i, slot):
+            c.wait()
+        blk = key_buf[slot]                                # [Q, 5*cpt]
+        seed_rows = [blk[:, w * cpt:(w + 1) * cpt] for w in range(4)]
+        t = blk[:, 4 * cpt:5 * cpt]
+        seed_rows, t = _expand_tile(seed_rows, t, cw_ref, clog=clog,
+                                    rounds=rounds)
+        acc = accumulate(acc, seed_rows, t, db_buf.at[slot])
 
         @pl.when(i + depth < n_tiles)
         def _():                           # refill the slot just freed
-            copy_in(i + depth, slot).start()
+            for c in copies(i + depth, slot):
+                c.start()
         return acc
 
-    acc0 = jnp.zeros((q, w_words), U32)
-    out_ref[...] = jax.lax.fori_loop(0, n_tiles, body, acc0)
+    return jax.lax.fori_loop(0, n_tiles, body, acc0)
 
 
-def _fused_add_kernel(roots_ref, troots_ref, cws_ref, cwt_ref, cwf_ref,
-                      db_ref, out_ref, buf_ref, sem_ref, *, tile_r: int,
-                      clog: int, depth: int, rounds: int, n_tiles: int,
-                      party: int):
-    """Additive body: db [R, L] i8 (ANY) -> out [Q, L] i32 (VMEM)."""
-    cpt = tile_r >> clog
+def _fused_xor_kernel(cw_ref, keys_hbm, db_hbm, out_ref, db_buf, key_buf,
+                      sem, *, tile_r: int, clog: int, depth: int,
+                      rounds: int, n_tiles: int):
+    """XOR body: db_t [W, R] (ANY) -> out [Q, W] (VMEM)."""
+    q, w_words = out_ref.shape
+
+    def accumulate(acc, seed_rows, t, db_tile):
+        mask = U32(0) - t                                  # [Q, tile_r]
+        masked = mask[:, None, :] & db_tile[...][None, :, :]  # [Q, W, TR]
+        return acc ^ _fold_xor_lanes(masked)[..., 0]
+
+    out_ref[...] = _scan_tiles(
+        db_hbm, keys_hbm, db_buf, key_buf, sem, cw_ref,
+        jnp.zeros((q, w_words), U32), accumulate, tile_r=tile_r, clog=clog,
+        depth=depth, rounds=rounds, n_tiles=n_tiles)
+
+
+def _fused_add_kernel(cw_ref, cwf_ref, keys_hbm, db_hbm, out_ref, db_buf,
+                      key_buf, sem, *, tile_r: int, clog: int, depth: int,
+                      rounds: int, n_tiles: int, party: int):
+    """Additive body: db_t [L, R] i8 (ANY) -> out [Q, L] i32 (VMEM)."""
     q, n_bytes = out_ref.shape
+    cwf = cwf_ref[...] & U32(0xFF)                         # [Q, 1]
 
-    def copy_in(i, slot):
-        return pltpu.make_async_copy(
-            db_ref.at[pl.ds(i * tile_r, tile_r), :],
-            buf_ref.at[slot], sem_ref.at[slot])
-
-    for s in range(min(depth, n_tiles)):
-        copy_in(s, s).start()
-
-    def body(i, acc):
-        slot = jax.lax.rem(i, depth)
-        copy_in(i, slot).wait()
-        c0 = i * cpt
-        seed_rows = [roots_ref[w, :, pl.ds(c0, cpt)] for w in range(4)]
-        t = troots_ref[:, pl.ds(c0, cpt)]
-        seed_rows, t = _expand_tile(seed_rows, t, cws_ref, cwt_ref,
-                                    clog=clog, rounds=rounds)
+    def accumulate(acc, seed_rows, t, db_tile):
         # payload conversion: word 0 of the counter=1 block (prg_bits)
         conv = _chacha_rows(seed_rows, counter=1, rounds=rounds)[0]
-        cwf = cwf_ref[0, :][:, None] & U32(0xFF)           # [Q, 1]
         share = ((conv & U32(0xFF)) + t * cwf) & U32(0xFF)
         if party == 1:
             share = (U32(256) - share) & U32(0xFF)
-        # int8 sign semantics, reproduced so the int32 accumulation is
-        # bit-identical to the materialized int8 GEMM
+        # the byte as int8 (share - 256 where share >= 128): the same
+        # operand the materialized int8 GEMM contracts
         s32 = share.astype(jnp.int32)
-        s32 = jnp.where(share >= U32(128), s32 - 256, s32)
-        db32 = buf_ref[slot].astype(jnp.int32)             # [tile_r, L]
-        acc = acc + jax.lax.dot_general(
-            s32, db32, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
+        s8 = jnp.where(share >= U32(128), s32 - 256, s32).astype(jnp.int8)
+        return acc + jax.lax.dot_general(
+            s8, db_tile[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32)              # [Q, L]
 
-        @pl.when(i + depth < n_tiles)
-        def _():
-            copy_in(i + depth, slot).start()
-        return acc
-
-    acc0 = jnp.zeros((q, n_bytes), jnp.int32)
-    out_ref[...] = jax.lax.fori_loop(0, n_tiles, body, acc0)
+    out_ref[...] = _scan_tiles(
+        db_hbm, keys_hbm, db_buf, key_buf, sem, cw_ref,
+        jnp.zeros((q, n_bytes), jnp.int32), accumulate, tile_r=tile_r,
+        clog=clog, depth=depth, rounds=rounds, n_tiles=n_tiles)
 
 
 def _check_args(r, c, clog, tile_r, depth):
@@ -190,120 +204,150 @@ def _check_args(r, c, clog, tile_r, depth):
         raise ValueError(f"buffer depth must be >= 1, got {depth}")
 
 
-def fused_scan_xor_t(db_t: jax.Array, roots_t: jax.Array,
-                     t_roots: jax.Array, cw_seed_t: jax.Array,
-                     cw_t_t: jax.Array, *, tile_r: int, depth: int,
-                     rounds: int = 12,
+def _pack_keys(roots, t_roots, cw_seed_lv, cw_t_lv, *, tile_r: int):
+    """Lay the chunk-root inputs out for the kernel.
+
+    roots ``[Q, C, 4]`` + t_roots ``[Q, C]`` -> per-tile key blocks
+    ``[n_tiles, Q, 5 * cpt]`` (lanes: seed word 0 of the tile's cpt chunks,
+    ..., word 3, then their control bits; zero-padded to whole vregs); the
+    CW levels ``[Q, clog, 4]`` + ``[Q, clog, 2]`` -> ``[max(clog, 1), Q,
+    6]``. A zero-level expansion
+    (roots already are the leaves) ships one never-read CW level, since
+    zero-sized operands break interpret-mode block padding.
+    """
+    q, c, _ = roots.shape
+    clog = cw_seed_lv.shape[1]
+    cpt = tile_r >> clog
+    n_tiles = c // cpt
+    words = jnp.concatenate([roots.astype(U32),
+                             t_roots.astype(U32)[..., None]], axis=-1)
+    keys = words.reshape(q, n_tiles, cpt, KEY_WORDS).transpose(1, 0, 3, 2)
+    keys = keys.reshape(n_tiles, q, KEY_WORDS * cpt)
+    pad = _key_lanes(cpt) - KEY_WORDS * cpt
+    keys = jnp.pad(keys, ((0, 0), (0, 0), (0, pad)))
+    cws = jnp.concatenate([cw_seed_lv.astype(U32), cw_t_lv.astype(U32)],
+                          axis=-1).transpose(1, 0, 2)      # [clog, Q, 6]
+    if clog == 0:
+        cws = jnp.zeros((1, q, CW_WORDS), U32)
+    return keys, cws
+
+
+def _key_lanes(cpt: int) -> int:
+    """Lane width of a packed key block: 5 * cpt words, padded to whole
+    128-lane vregs (DMA windows must be lane-tile aligned)."""
+    return -(-KEY_WORDS * cpt // 128) * 128
+
+
+def _scratch(depth, n_tiles, db_tile, db_dtype, q, cpt):
+    d = min(depth, n_tiles)
+    return [pltpu.VMEM((d,) + db_tile, db_dtype),
+            pltpu.VMEM((d, q, _key_lanes(cpt)), U32),
+            pltpu.SemaphoreType.DMA((2, d))]
+
+
+def fused_scan_xor_t(db_t: jax.Array, roots: jax.Array, t_roots: jax.Array,
+                     cw_seed_lv: jax.Array, cw_t_lv: jax.Array, *,
+                     tile_r: int, depth: int, rounds: int = 12,
                      interpret: bool | None = None) -> jax.Array:
     """Fused expand+XOR-scan over a word-transposed DB shard.
 
     Args:
-      db_t:      ``[W, R] uint32`` word-transposed DB shard.
-      roots_t:   ``[4, Q, C] uint32`` chunk-root seed words.
-      t_roots:   ``[Q, C] uint32`` chunk-root control bits.
-      cw_seed_t: ``[clog, 4, Q] uint32`` seed CWs for the last clog levels.
-      cw_t_t:    ``[clog, 2, Q] uint32`` (tL, tR) CWs for the same levels.
-      tile_r:    DB rows per DMA tile (power of two dividing R).
-      depth:     rotating DMA buffer count (2 = classic double buffer).
+      db_t:       ``[W, R] uint32`` word-transposed DB shard.
+      roots:      ``[Q, C, 4] uint32`` chunk-root seeds.
+      t_roots:    ``[Q, C] uint32`` chunk-root control bits.
+      cw_seed_lv: ``[Q, clog, 4] uint32`` seed CWs for the last clog levels.
+      cw_t_lv:    ``[Q, clog, 2] uint32`` (tL, tR) CWs for the same levels.
+      tile_r:     DB rows per DMA tile (power of two dividing R).
+      depth:      rotating DMA buffer count (2 = classic double buffer).
       interpret: ``None`` resolves against the engine backend probe
         (``REPRO_FORCE_BACKEND``), outside the jit boundary.
 
     Returns ``[Q, W] uint32`` per-query XOR answers, bit-identical to the
     materialized ``eval_bits`` + ``dpxor`` path.
     """
-    return _fused_scan_xor_jit(db_t, roots_t, t_roots, cw_seed_t, cw_t_t,
+    return _fused_scan_xor_jit(db_t, roots, t_roots, cw_seed_lv, cw_t_lv,
                                tile_r=tile_r, depth=depth, rounds=rounds,
                                interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_r", "depth", "rounds",
                                              "interpret"))
-def _fused_scan_xor_jit(db_t: jax.Array, roots_t: jax.Array,
-                        t_roots: jax.Array, cw_seed_t: jax.Array,
-                        cw_t_t: jax.Array, *, tile_r: int, depth: int,
+def _fused_scan_xor_jit(db_t: jax.Array, roots: jax.Array,
+                        t_roots: jax.Array, cw_seed_lv: jax.Array,
+                        cw_t_lv: jax.Array, *, tile_r: int, depth: int,
                         rounds: int, interpret: bool) -> jax.Array:
     w, r = db_t.shape
-    clog = cw_seed_t.shape[0]
     q, c = t_roots.shape
+    clog = cw_seed_lv.shape[1]
     _check_args(r, c, clog, tile_r, depth)
     n_tiles = r // tile_r
-    if clog == 0:
-        # Degenerate point: the roots already are the leaves, so no CW
-        # levels ship. Zero-sized operands break interpret-mode block
-        # padding; pad to one (never-read) level instead.
-        cw_seed_t = jnp.zeros((1, 4, q), U32)
-        cw_t_t = jnp.zeros((1, 2, q), U32)
+    keys, cws = _pack_keys(roots, t_roots, cw_seed_lv, cw_t_lv,
+                           tile_r=tile_r)
     kernel = functools.partial(
         _fused_xor_kernel, tile_r=tile_r, clog=clog,
         depth=min(depth, n_tiles), rounds=rounds, n_tiles=n_tiles)
     return pl.pallas_call(
         kernel,
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),    # roots_t
-            pl.BlockSpec(memory_space=pltpu.ANY),    # t_roots
-            pl.BlockSpec(memory_space=pltpu.ANY),    # cw_seed_t
-            pl.BlockSpec(memory_space=pltpu.ANY),    # cw_t_t
-            pl.BlockSpec(memory_space=pltpu.ANY),    # db_t (streamed)
+            pl.BlockSpec(memory_space=pltpu.VMEM),   # cws (whole)
+            pl.BlockSpec(memory_space=pl.ANY),    # keys (per tile)
+            pl.BlockSpec(memory_space=pl.ANY),    # db_t (streamed)
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((q, w), U32),
-        scratch_shapes=[
-            pltpu.VMEM((min(depth, n_tiles), w, tile_r), U32),
-            pltpu.SemaphoreType.DMA((min(depth, n_tiles),)),
-        ],
+        scratch_shapes=_scratch(depth, n_tiles, (w, tile_r), U32, q,
+                                tile_r >> clog),
         interpret=interpret,
-    )(roots_t.astype(U32), t_roots.astype(U32), cw_seed_t.astype(U32),
-      cw_t_t.astype(U32), db_t.astype(U32))
+    )(cws, keys, db_t.astype(U32))
 
 
-def fused_scan_add(db_bytes: jax.Array, roots_t: jax.Array,
-                   t_roots: jax.Array, cw_seed_t: jax.Array,
-                   cw_t_t: jax.Array, cw_final: jax.Array, *, party: int,
+def fused_scan_add(db_bytes_t: jax.Array, roots: jax.Array,
+                   t_roots: jax.Array, cw_seed_lv: jax.Array,
+                   cw_t_lv: jax.Array, cw_final: jax.Array, *, party: int,
                    tile_r: int, depth: int, rounds: int = 12,
                    interpret: bool | None = None) -> jax.Array:
-    """Fused expand+select-add over an int8 byte-view DB shard.
+    """Fused expand+select-add over a byte-transposed int8 DB shard.
 
-    ``db_bytes [R, L] int8``; ``cw_final [Q] uint32`` is the payload
+    ``db_bytes_t [L, R] int8``; ``cw_final [Q] uint32`` is the payload
     correction word; other args as :func:`fused_scan_xor_t`. Returns
     ``[Q, L] int32`` — bit-identical to ``eval_bytes_batch`` + the int8
     GEMM (``answer_additive_matmul``).
     """
-    return _fused_scan_add_jit(db_bytes, roots_t, t_roots, cw_seed_t,
-                               cw_t_t, cw_final, party=party,
+    return _fused_scan_add_jit(db_bytes_t, roots, t_roots, cw_seed_lv,
+                               cw_t_lv, cw_final, party=party,
                                tile_r=tile_r, depth=depth, rounds=rounds,
                                interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("tile_r", "depth", "rounds",
                                              "party", "interpret"))
-def _fused_scan_add_jit(db_bytes: jax.Array, roots_t: jax.Array,
-                        t_roots: jax.Array, cw_seed_t: jax.Array,
-                        cw_t_t: jax.Array, cw_final: jax.Array, *,
+def _fused_scan_add_jit(db_bytes_t: jax.Array, roots: jax.Array,
+                        t_roots: jax.Array, cw_seed_lv: jax.Array,
+                        cw_t_lv: jax.Array, cw_final: jax.Array, *,
                         party: int, tile_r: int, depth: int, rounds: int,
                         interpret: bool) -> jax.Array:
-    r, l = db_bytes.shape
-    clog = cw_seed_t.shape[0]
+    l, r = db_bytes_t.shape
     q, c = t_roots.shape
+    clog = cw_seed_lv.shape[1]
     _check_args(r, c, clog, tile_r, depth)
     n_tiles = r // tile_r
-    if clog == 0:
-        # See fused_scan_xor_t: pad the zero-level CW operands.
-        cw_seed_t = jnp.zeros((1, 4, q), U32)
-        cw_t_t = jnp.zeros((1, 2, q), U32)
+    keys, cws = _pack_keys(roots, t_roots, cw_seed_lv, cw_t_lv,
+                           tile_r=tile_r)
     kernel = functools.partial(
         _fused_add_kernel, tile_r=tile_r, clog=clog,
         depth=min(depth, n_tiles), rounds=rounds, n_tiles=n_tiles,
         party=party)
     return pl.pallas_call(
         kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 6,
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        out_shape=jax.ShapeDtypeStruct((q, l), jnp.int32),
-        scratch_shapes=[
-            pltpu.VMEM((min(depth, n_tiles), tile_r, l), jnp.int8),
-            pltpu.SemaphoreType.DMA((min(depth, n_tiles),)),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.VMEM),   # cws (whole)
+            pl.BlockSpec(memory_space=pltpu.VMEM),   # cw_final [Q, 1]
+            pl.BlockSpec(memory_space=pl.ANY),    # keys (per tile)
+            pl.BlockSpec(memory_space=pl.ANY),    # db_bytes_t (streamed)
         ],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((q, l), jnp.int32),
+        scratch_shapes=_scratch(depth, n_tiles, (l, tile_r), jnp.int8, q,
+                                tile_r >> clog),
         interpret=interpret,
-    )(roots_t.astype(U32), t_roots.astype(U32), cw_seed_t.astype(U32),
-      cw_t_t.astype(U32), cw_final.astype(U32)[None, :],
-      db_bytes.astype(jnp.int8))
+    )(cws, cw_final.astype(U32)[:, None], keys, db_bytes_t.astype(jnp.int8))

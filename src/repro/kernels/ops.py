@@ -110,11 +110,9 @@ def fused_scan_xor(db_words: jax.Array, roots: jax.Array, t_roots: jax.Array,
       depth:      rotating DMA buffer count.
     """
     tile, _ = fused_tile(db_words.shape[0], tile_r, cw_seed_lv.shape[1])
-    return fused_scan_xor_t(
-        db_words.T, jnp.transpose(roots, (2, 0, 1)), t_roots,
-        jnp.transpose(cw_seed_lv, (1, 2, 0)),
-        jnp.transpose(cw_t_lv, (1, 2, 0)),
-        tile_r=tile, depth=depth, rounds=rounds, interpret=interpret)
+    return fused_scan_xor_t(db_words.T, roots, t_roots, cw_seed_lv, cw_t_lv,
+                            tile_r=tile, depth=depth, rounds=rounds,
+                            interpret=interpret)
 
 
 def fused_scan_bytes(db_bytes: jax.Array, roots: jax.Array,
@@ -126,15 +124,14 @@ def fused_scan_bytes(db_bytes: jax.Array, roots: jax.Array,
 
     Same chunk-root inputs as :func:`fused_scan_xor` plus ``cw_final [Q]``
     (payload correction word) and the static ``party``; returns
-    ``[Q, L] int32`` bit-identical to the materialized int8 GEMM.
+    ``[Q, L] int32`` bit-identical to the materialized int8 GEMM. The
+    kernel streams the view transposed (``[L, R]``, a free bitcast of its
+    resident layout, see ``kernels/pir_matmul.py``).
     """
     tile, _ = fused_tile(db_bytes.shape[0], tile_r, cw_seed_lv.shape[1])
-    return fused_scan_add(
-        db_bytes, jnp.transpose(roots, (2, 0, 1)), t_roots,
-        jnp.transpose(cw_seed_lv, (1, 2, 0)),
-        jnp.transpose(cw_t_lv, (1, 2, 0)), cw_final,
-        party=party, tile_r=tile, depth=depth, rounds=rounds,
-        interpret=interpret)
+    return fused_scan_add(db_bytes.T, roots, t_roots, cw_seed_lv, cw_t_lv,
+                          cw_final, party=party, tile_r=tile, depth=depth,
+                          rounds=rounds, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

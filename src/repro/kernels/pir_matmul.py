@@ -17,7 +17,14 @@ mod 256 at reconstruction.
 Kernel: classic three-loop blocked matmul. Grid = (Q tiles, L tiles, R
 tiles); R is the innermost (sequential) accumulation dimension so each
 ``[TQ, TL]`` output block stays resident in VMEM while ``[TQ, TR]`` share
-and ``[TR, TL]`` DB tiles stream through.
+and ``[TL, TR]`` DB tiles stream through.
+
+Layout: the kernel reads the DB *transposed*, ``[L, R]``, and contracts
+the last dims of both operands. A byte view ``[R, L]`` with L < 128 is
+resident on a TPU in the transposed physical layout (XLA makes R the
+minor dim), so ``db.T`` is a free bitcast; a row-major ``[R, L]`` operand
+would instead cost a lane-padded relayout copy of the whole DB per call
+(4 GiB of temp for a 1 GiB, 32-byte-record DB).
 """
 from __future__ import annotations
 
@@ -40,7 +47,7 @@ def _matmul_kernel(s_ref, d_ref, o_ref):
     o_ref[...] += jax.lax.dot_general(
         s_ref[...],
         d_ref[...],
-        (((1,), (0,)), ((), ())),
+        (((1,), (1,)), ((), ())),          # [TQ, TR] x [TL, TR] -> [TQ, TL]
         preferred_element_type=I32,
     )
 
@@ -92,12 +99,12 @@ def _pir_matmul_jit(
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_q, tile_r), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tile_r, tile_l), lambda i, j, k: (k, j)),
+            pl.BlockSpec((tile_l, tile_r), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((tile_q, tile_l), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, l), I32),
         interpret=interpret,
-    )(shares.astype(jnp.int8), db_bytes.astype(jnp.int8))
+    )(shares.astype(jnp.int8), db_bytes.astype(jnp.int8).T)
 
 
 def lwe_matmul(
@@ -116,7 +123,9 @@ def lwe_matmul(
     with q = 2^32: int32 accumulation wraps mod 2^32, so the GEMM computes
     the Z_q contraction exactly (DESIGN.md §10). Streams are 4× wider than
     the int8 path, which is why the engine registers a separate descriptor
-    with its own VMEM footprint model.
+    with its own VMEM footprint model. The TPU compiler refuses this body
+    (the v5e MXU has no int32 matmul), so the engine never offers it on a
+    TPU backend; there the LWE step contracts with XLA's int32 dot.
     """
     return _lwe_matmul_jit(ct, db_bytes32, tile_q=tile_q, tile_r=tile_r,
                            tile_l=tile_l,
@@ -149,9 +158,9 @@ def _lwe_matmul_jit(
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_q, tile_r), lambda i, j, k: (i, k)),
-            pl.BlockSpec((tile_r, tile_l), lambda i, j, k: (k, j)),
+            pl.BlockSpec((tile_l, tile_r), lambda i, j, k: (j, k)),
         ],
         out_specs=pl.BlockSpec((tile_q, tile_l), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, l), I32),
         interpret=interpret,
-    )(ct.astype(I32), db_bytes32.astype(I32))
+    )(ct.astype(I32), db_bytes32.astype(I32).T)

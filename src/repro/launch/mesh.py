@@ -37,14 +37,15 @@ def make_mesh(cfg: MeshConfig) -> jax.sharding.Mesh:
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """A mesh over however many devices this process actually has.
+    """A ``data x model`` mesh over the first devices of this process.
 
-    Used by smoke tests and the CPU benchmarks; collapses gracefully to
-    (1, 1) on the single-CPU container.
+    Raises when the process has fewer than ``data * model`` devices: a
+    request for a sharded layout never quietly becomes a smaller one.
     """
     n = len(jax.devices())
-    data = min(data, n)
-    model = min(model, max(1, n // data))
+    if data * model > n:
+        raise ValueError(f"a {data}x{model} mesh needs {data * model} "
+                         f"devices; this process has {n}")
     devs = np.asarray(jax.devices()[: data * model]).reshape(data, model)
     return jax.sharding.Mesh(devs, ("data", "model"))
 

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis import hlo_cost, roofline
-from repro.compat import shard_map
 
 
 def _compile(f, *shapes):
@@ -83,8 +82,9 @@ def test_collective_parse_counts_psum():
     def f(a):
         return jax.lax.psum(a, "d")
 
-    fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P("d", None),
-                               out_specs=P(None, None), check_vma=False))
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("d", None),
+                                   out_specs=P(None, None),
+                                   check_vma=False))
     c = fn.lower(x).compile()
     cost = hlo_cost.analyze(c.as_text())
     # single-device all-reduce may fold away; just assert the parse ran
@@ -102,3 +102,20 @@ def test_format_table():
                           model_flops=5e11)
     txt = roofline.format_table([r.to_dict()])
     assert "cell" in txt and "|" in txt
+
+
+def test_peak_table_keyed_by_device_kind():
+    v5e = roofline.peaks("TPU v5 lite")
+    assert (v5e.hbm_bytes_per_s, v5e.bf16_flops, v5e.int8_ops) == \
+        (819e9, 197e12, 393e12)
+    assert roofline.HBM_BW == v5e.hbm_bytes_per_s
+    # this process's device: the CPU host reads its one nominal row
+    assert roofline.device_kind() == "cpu"
+    assert roofline.peak_bytes_per_s() == roofline.PEAKS["cpu"].hbm_bytes_per_s
+    assert roofline.achieved_fraction(819e9, 1.0, kind="TPU v5 lite") == 1.0
+
+
+@pytest.mark.parametrize("kind", ["TPU v4", "TPU v6 lite", "gpu"])
+def test_unknown_device_kind_is_an_error(kind):
+    with pytest.raises(KeyError, match="no peak rates"):
+        roofline.peak_bytes_per_s(kind)

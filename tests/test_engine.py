@@ -7,9 +7,9 @@ kernel jits (log N <= 6 DBs, tiny tune budgets) — no serve-step compiles.
 The two load-bearing guarantees (ISSUE 5 acceptance):
   * every candidate plan in the search space produces byte-identical
     answers (the tuner can never trade correctness for speed);
-  * an empty/corrupted/stale plan cache resolves to exactly the pre-engine
-    ``plan_for`` choices (asserted against an inline replica of the old
-    rules), so default behavior is unchanged bit-for-bit.
+  * an empty/corrupted/stale plan cache resolves to exactly the
+    heuristic's choices (asserted against an inline replica of its
+    rules), so default behavior never depends on a cache file.
 """
 import json
 
@@ -109,27 +109,49 @@ def test_ops_non_pow2_shard_shapes_regression():
 
 
 # ---------------------------------------------------------------------------
-# heuristic fallback == the pre-engine plan_for, bit for bit
+# heuristic fallback == an inline replica of its rules
 # ---------------------------------------------------------------------------
 
+#: the additive megakernel's tile per bucket at 32 B records: the largest
+#: tile <= 2048 whose modeled VMEM fits 16 MiB (engine/kernels.py)
+_FUSED_ADD_TILE = {1: 2048, 4: 2048, 32: 512}
+
+
 def _pre_engine_plan_for(cfg, n_queries, backend, chunk_log=12):
-    """Inline replica of the pre-PR ``core.protocol.plan_for`` body."""
+    """Inline replica of the heuristic's rules (independent of the engine
+    code): materialize only while the DB fits one chunk; past that XOR
+    takes fused and additive-on-TPU the megakernel; LWE always the XLA
+    int32 dot."""
     scan = "pallas" if backend == "tpu" else "jnp"
     proto = protocol_mod.get(cfg.protocol)
+    small_db = cfg.n_items <= (1 << chunk_log)
+    if proto.share_kind == "lwe":
+        return ExecutionPlan(expand="materialize", scan="jnp",
+                             chunk_log=chunk_log, tile_r=1024)
     if proto.share_kind == "additive":
+        if backend == "tpu" and not small_db:
+            tile = _FUSED_ADD_TILE[n_queries]
+            return ExecutionPlan(expand="fused-pallas", scan="pallas",
+                                 chunk_log=min(chunk_log,
+                                               tile.bit_length() - 1),
+                                 tile_r=tile, depth=2)
         # tiles were then hardcoded in kernels/ops.py: gemm tile_r=1024
         return ExecutionPlan(expand="materialize", scan=scan,
                              chunk_log=chunk_log, tile_r=1024)
-    small_db = cfg.n_items <= (1 << chunk_log)
-    expand = "materialize" if small_db or n_queries <= 1 else "fused"
-    return ExecutionPlan(expand=expand, scan=scan, chunk_log=chunk_log)
+    if small_db:
+        return ExecutionPlan(expand="materialize", scan=scan,
+                             chunk_log=chunk_log)
+    # the fused XOR body's fold is always the jnp dpxor
+    return ExecutionPlan(expand="fused", scan="jnp", chunk_log=chunk_log)
 
 
 @pytest.mark.parametrize("protocol", ["xor-dpf-2", "additive-dpf-2",
-                                      "xor-dpf-k"])
+                                      "xor-dpf-k", "lwe-simple-1"])
 def test_heuristic_reproduces_pre_engine_plan_for(protocol):
+    n_servers = {"xor-dpf-k": 3, "lwe-simple-1": 1}.get(protocol, 2)
     for n_items in (1 << 10, 1 << 14, 1 << 20):
-        cfg = PIRConfig(n_items=n_items, protocol=protocol, n_servers=3)
+        cfg = PIRConfig(n_items=n_items, protocol=protocol,
+                        n_servers=n_servers)
         for n_q in (1, 4, 32):
             for be in ("cpu", "tpu"):
                 want = _pre_engine_plan_for(cfg, n_q, be)
@@ -215,6 +237,36 @@ def test_lwe_gemm_candidates_cover_and_legalize():
         if p.scan == "pallas":
             assert N % p.tile_r == 0 and 2 % p.tile_q == 0 \
                 and 32 % p.tile_l == 0
+
+
+def test_lwe_pallas_gemm_not_offered_on_tpu():
+    """The v5e MXU has no int32 matmul, so Mosaic refuses the Pallas LWE
+    body: on a TPU backend neither the heuristic nor the tuner's search
+    space may offer it (the XLA int32 dot compiles)."""
+    cfg = PIRConfig(n_items=N, protocol="lwe-simple-1", n_servers=1)
+    assert not engine.get_kernel("lwe-gemm-pallas").mosaic
+    plans = engine.candidate_plans(cfg, 2, backend="tpu")
+    assert {(p.expand, p.scan) for p in plans} == {("materialize", "jnp")}
+    assert plan_for(cfg, 4, backend="tpu").scan == "jnp"
+    # every other share algebra keeps its Pallas candidates on a TPU
+    for proto in ("xor-dpf-2", "additive-dpf-2"):
+        got = engine.candidate_plans(PIRConfig(n_items=N, protocol=proto),
+                                     2, backend="tpu")
+        assert any(p.scan == "pallas" for p in got)
+
+
+def test_additive_megakernel_tile_fits_vmem_per_bucket():
+    """The heuristic's megakernel plan shrinks its tile until the VMEM
+    model fits, so big buckets never resolve to a plan Mosaic refuses."""
+    desc = engine.get_kernel("gemm-fused-pallas")
+    cfg = PIRConfig(n_items=1 << 25, protocol="additive-dpf-2")
+    for bucket, tile in ((1, 2048), (8, 2048), (16, 1024), (32, 512)):
+        plan = plan_for(cfg, bucket, backend="tpu")
+        assert (plan.expand, plan.tile_r) == ("fused-pallas", tile)
+        shape = engine.problem_shape(cfg, bucket)
+        assert desc.feasible(shape, {"tile_r": plan.tile_r,
+                                     "chunk_log": plan.chunk_log,
+                                     "depth": plan.depth})
 
 
 def test_lwe_gemm_feasibility_prunes_before_int8_gemm():

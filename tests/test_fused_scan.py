@@ -10,7 +10,8 @@ Three concerns, in cost order:
   start_block cases run in the slow tier.
 * **VMEM footprint model** at the 16 MiB boundary — pure arithmetic on
   the engine descriptors, no compiles. The double-buffer factor must be
-  the term that flips feasibility.
+  the term that flips feasibility. (tests/test_tpu_compile.py checks the
+  model against what the v5e compiler accepts.)
 * **Backend resolution** (REPRO_FORCE_BACKEND) — the one probe governs
   interpret mode for every Pallas entry point, enforced both
   functionally and as a source convention.
@@ -49,10 +50,15 @@ def _xor_keys(party=0):
                            for i in IDXS])
 
 
+def _add_key_pairs():
+    """Both parties' batched additive keys, from the same key pairs."""
+    pairs = [dpf.gen_keys(RNG, i, LOG_N, payload=np.array([1], np.uint32),
+                          payload_mod=256) for i in IDXS]
+    return tuple(dpf.stack_keys([p[b] for p in pairs]) for b in (0, 1))
+
+
 def _add_keys(party=0):
-    return dpf.stack_keys(
-        [dpf.gen_keys(RNG, i, LOG_N, payload=np.array([1], np.uint32),
-                      payload_mod=256)[party] for i in IDXS])
+    return _add_key_pairs()[party]
 
 
 def _fused_xor(keys, db, tile_r, clog, depth, start_block=0,
@@ -121,7 +127,7 @@ def test_fused_xor_parity_full_grid():
 
 @pytest.mark.slow
 def test_fused_add_party1_and_reconstruction():
-    k0, k1 = _add_keys(0), _add_keys(1)
+    k0, k1 = _add_key_pairs()
     got0 = _fused_add(k0, DB_BYTES, tile_r=16, clog=3, depth=2)
     got1 = _fused_add(k1, DB_BYTES, tile_r=16, clog=3, depth=2)
     sh0 = dpf.eval_bytes_batch(k0, 0, LOG_N)
@@ -162,7 +168,12 @@ def test_xor_footprint_formula():
     desc = get_kernel("xor-fused-pallas")
     shape = ProblemShape(bucket=4, rows=1 << 20, item_bytes=32)
     p = {"tile_r": 1024, "chunk_log": 8, "depth": 2}
-    want = 4 * (2 * 8 * 1024 + 4 * 1024 * 27 + 4 * 8 * 1024 + 4 * 8)
+    want = 4 * (2 * 8 * 1024          # two u32 DB buffers [W, TR]
+                + 4 * 1024 * 128      # expansion: 128 words/row/query
+                + 4 * 8 * 256         # lane fold: 256 words/query/word
+                + 2 * 8 * 128         # two key blocks [Q->8, 5*cpt->128]
+                + 8 * 8 * 128         # 8 CW levels [Q->8, 6->128]
+                + 8 * 128)            # out [Q->8, W->128]
     assert desc.footprint_fn(shape, p) == want
 
 
@@ -171,17 +182,18 @@ def test_vmem_boundary_double_buffer_factor():
     feasibility: same tile, deeper buffering -> infeasible."""
     from repro.analysis.roofline import VMEM_BYTES
     desc = get_kernel("xor-fused-pallas")
-    shape = ProblemShape(bucket=1, rows=1 << 20, item_bytes=512)
-    shallow = {"tile_r": 8192, "chunk_log": 8, "depth": 2}
+    shape = ProblemShape(bucket=1, rows=1 << 20, item_bytes=1536)
+    shallow = {"tile_r": 2048, "chunk_log": 8, "depth": 2}
     deep = dict(shallow, depth=4)
     assert desc.footprint_fn(shape, shallow) <= VMEM_BYTES
     assert desc.footprint_fn(shape, deep) > VMEM_BYTES
     assert desc.feasible(shape, shallow)
     assert not desc.feasible(shape, deep)
-    # the delta between the two is exactly the extra DB buffers
+    # the delta between the two is exactly the extra slots: a u32 DB
+    # buffer [W, TR] and a key block [Q->8, 128] each
     extra = desc.footprint_fn(shape, deep) - desc.footprint_fn(shape,
                                                                shallow)
-    assert extra == 4 * 2 * 128 * 8192   # (4-2) u32 buffers of [W, TR]
+    assert extra == 4 * 2 * (384 * 2048 + 8 * 128)
 
 
 def test_add_footprint_counts_buffers():
@@ -191,7 +203,8 @@ def test_add_footprint_counts_buffers():
                                    "depth": 1})
     f3 = desc.footprint_fn(shape, {"tile_r": 2048, "chunk_log": 8,
                                    "depth": 3})
-    assert f3 - f1 == 2 * 2048 * 64      # two extra int8 tiles [TR, L]
+    # two extra int8 tiles [L, TR] and two extra u32 key blocks [8, 128]
+    assert f3 - f1 == 2 * (2048 * 64 + 4 * 8 * 128)
 
 
 def test_legalize_couples_chunk_to_tile():

@@ -5,7 +5,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.config import OptimizerConfig
 from repro.optim import compression
 from repro.optim.optimizer import (adafactor_init, adafactor_update,
@@ -115,7 +114,7 @@ def test_compressed_psum_single_axis():
     def f(g, ef):
         return compression.compressed_psum(g, ef, "pod")
 
-    out, _ = jax.jit(shard_map(
+    out, _ = jax.jit(jax.shard_map(
         f, mesh=mesh,
         in_specs=(P(), P()), out_specs=(P(), P()),
         check_vma=False))(g, ef)

@@ -122,16 +122,20 @@ def test_plan_selection_rules():
     with pytest.raises(ValueError, match="additive"):
         build_serve_fn(PIRConfig(n_items=N), make_local_mesh(),
                        n_queries=2, path="matmul")
-    # selector: additive -> GEMM contraction; XOR small db / single query
-    # -> materialize; XOR big db -> fused; Pallas bodies only on TPU
+    # selector: XOR small db -> materialize; XOR big db -> fused at every
+    # bucket size (a single query's full-domain eval does not fit at
+    # scale); Pallas bodies only on TPU
     small = plan_for(PIRConfig(n_items=1 << 10), 4, backend="cpu")
     big = plan_for(PIRConfig(n_items=1 << 20), 8, backend="cpu")
     single = plan_for(PIRConfig(n_items=1 << 20), 1, backend="cpu")
     assert small.expand == "materialize" and big.expand == "fused"
-    assert single.expand == "materialize"
-    assert plan_for(PIRConfig(n_items=1 << 20), 8, backend="tpu").scan \
+    assert single.expand == "fused"
+    assert small.scan == "jnp"   # CPU: interpret-mode Pallas would be slow
+    assert plan_for(PIRConfig(n_items=1 << 10), 8, backend="tpu").scan \
         == "pallas"
-    assert big.scan == "jnp"     # CPU: interpret-mode Pallas would be slow
+    # the fused XOR body never reaches a scan kernel: jnp on any backend
+    assert plan_for(PIRConfig(n_items=1 << 20), 8, backend="tpu").scan \
+        == "jnp" == big.scan
 
 
 # ---------------------------------------------------------------------------

@@ -19,15 +19,27 @@ work is re-sharded onto healthy clusters (``StragglerMonitor.shed_stragglers``,
 wired into ``QueryScheduler.rebalance``) — the clustered replica topology is
 exactly what makes this cheap (paper Take-away 5's structure, used for fault
 tolerance too).
+
+Profiler spans (``jax.profiler.TraceAnnotation``, on the host plane of the
+device trace and its clock; they record only while a trace is active):
+``pir.gen_lock`` and ``pir.gen`` (a request's wait for the client keygen
+lock, and keygen under it; metadata ``request``) and ``pir.reconstruct``
+(one batch's reconstruction after its answer shares are ready; ``batch``,
+``bucket``, ``n``). A future's ``context`` carries its ``request`` and
+``batch`` numbers, which join the two (DESIGN.md §6.2).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import itertools
 import queue
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Deque, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 import numpy as np
@@ -48,6 +60,16 @@ PIPELINE_DEPTH = 2
 #: before the scheduler cuts an under-full (padded) batch.
 DEFAULT_MAX_WAIT_S = 0.005
 
+#: how many of the most recent batch latencies ``ServeStats`` keeps (the
+#: replica snapshot's recent p50/p99); older ones are dropped
+LATENCY_WINDOW = 1024
+
+#: sequence number of the batch whose ``finalize`` runs on this thread
+#: (set by ``QueryScheduler._complete``): the ``pir.reconstruct`` span's
+#: ``batch``, without widening the finalize callable's signature
+_FINALIZING_BATCH: contextvars.ContextVar = contextvars.ContextVar(
+    "pir_finalizing_batch", default=None)
+
 
 @dataclass
 class ServeStats:
@@ -55,7 +77,11 @@ class ServeStats:
     batches: int = 0
     padded: int = 0              # pad slots computed-and-discarded
     reassignments: int = 0       # queued batches moved off stragglers
-    latencies: List[float] = field(default_factory=list)
+    # seconds the answered queries waited from submit to their batch's
+    # launch, summed (added at completion, like ``answered``)
+    queue_wait_s: float = 0.0
+    latencies: Deque[float] = field(
+        default_factory=lambda: deque(maxlen=LATENCY_WINDOW))
     bucket_counts: Dict[int, int] = field(default_factory=dict)
     # serving window: earliest dispatch .. latest completion. Overlapped
     # (pipelined) batches make sum(latencies) exceed wall time, so QPS is
@@ -82,6 +108,15 @@ class ServeStats:
     def pad_fraction(self) -> float:
         slots = self.answered + self.padded
         return self.padded / slots if slots else 0.0
+
+
+def _reconstruct_span(bucket: int, n: int) -> jax.profiler.TraceAnnotation:
+    """Profiler span ``pir.reconstruct`` over the reconstruction of the
+    batch being finalized: its ``batch`` number, ``bucket`` and ``n`` real
+    queries."""
+    return jax.profiler.TraceAnnotation(
+        "pir.reconstruct", batch=_FINALIZING_BATCH.get(), bucket=int(bucket),
+        n=n)
 
 
 class QueryTimeout(TimeoutError):
@@ -206,10 +241,13 @@ class _Batch:
     items: List[Any]                  # raw per-query payloads
     futures: List[AnswerFuture]
     cluster: str
+    t_submit: List[float]             # each query's submit time (clock)
+    seq: int                          # the scheduler's batch number
     payload: Any = None               # collated (stacked) keys
     staged: Any = None                # padded + device_put keys
     bucket: int = 0
     epoch: Optional[int] = None       # DB epoch captured at dispatch
+    t_launch: Optional[float] = None  # launch start (clock)
 
 
 class QueryScheduler:
@@ -292,6 +330,7 @@ class QueryScheduler:
         self.queues: Dict[str, List[_Batch]] = {
             f"cluster{i}": [] for i in range(self.n_clusters)}
         self._rr = 0                          # round-robin lane counter
+        self._n_cut = 0                       # batches formed so far
         self._n_inflight = 0                  # real queries dispatched, unresolved
         self._thread: Optional[threading.Thread] = None
         self._stopping = False
@@ -408,10 +447,13 @@ class QueryScheduler:
         self._rr += 1
         batch = _Batch(items=[t[0] for t in taken],
                        futures=[t[1] for t in taken],
-                       cluster=lane)
+                       cluster=lane, t_submit=[t[2] for t in taken],
+                       seq=self._n_cut)
+        self._n_cut += 1
         batch.bucket = self.bucket_for(n)
         for fut in batch.futures:    # timeout-attribution breadcrumb
             fut.context.setdefault("bucket", batch.bucket)
+            fut.context["batch"] = batch.seq
         self.queues[lane].append(batch)
 
     def _cut_ripe_locked(self) -> bool:
@@ -459,6 +501,7 @@ class QueryScheduler:
         ever resolve them.
         """
         try:
+            batch.t_launch = self.clock()
             if self.chaos is not None:
                 self.chaos.visit("scheduler.dispatch", self.chaos_target)
             batch.payload = self._collate(batch.items)
@@ -482,6 +525,7 @@ class QueryScheduler:
         return batch, raw, t0
 
     def _complete(self, batch: _Batch, raw: Any, t0: float):
+        finalizing = _FINALIZING_BATCH.set(batch.seq)
         try:
             answers = self._finalize(raw, len(batch.items))
             dt = self.clock() - t0
@@ -493,6 +537,7 @@ class QueryScheduler:
                 fut.set_exception(e)
             raise
         finally:
+            _FINALIZING_BATCH.reset(finalizing)
             with self._cv:
                 self._n_inflight -= len(batch.items)
         self.monitor.record(batch.cluster, dt)
@@ -500,6 +545,8 @@ class QueryScheduler:
         self.stats.latencies.append(dt)
         self.stats.batches += 1
         self.stats.answered += len(batch.items)
+        self.stats.queue_wait_s += sum(batch.t_launch - t
+                                       for t in batch.t_submit)
         self.stats.padded += batch.bucket - len(batch.items)
         self.stats.bucket_counts[batch.bucket] = \
             self.stats.bucket_counts.get(batch.bucket, 0) + 1
@@ -776,6 +823,7 @@ class MultiServerPIR:
         self.rng = (client_rng if client_rng is not None
                     else np.random.default_rng())
         self._lock = threading.Lock()
+        self._requests = itertools.count()    # request numbers, submit order
         # per-query deadline default (DESIGN.md §12.3): every submit()
         # stamps an absolute deadline onto its AnswerFuture, which both
         # result() and the replica router's hedging reaper read. The
@@ -829,12 +877,15 @@ class MultiServerPIR:
 
         def finalize(raw, n):
             answers, _ = raw
-            # reconstruct_with routes through checksum verification when
-            # cfg.checksum — a corrupted share raises IntegrityError here
-            # (failing this batch's futures) instead of resolving garbage
-            rec = np.asarray(proto.reconstruct_with(
-                [r[:n] for r in answers], [None] * n, cfg=cfg))
-            return list(rec)
+            jax.block_until_ready(answers)
+            with _reconstruct_span(answers[0].shape[0], n):
+                # reconstruct_with routes through checksum verification
+                # when cfg.checksum — a corrupted share raises
+                # IntegrityError here (failing this batch's futures)
+                # instead of resolving garbage
+                rec = np.asarray(proto.reconstruct_with(
+                    [r[:n] for r in answers], [None] * n, cfg=cfg))
+                return list(rec)
 
         return QueryScheduler(
             collate=collate, stage=stage, dispatch=dispatch,
@@ -865,6 +916,22 @@ class MultiServerPIR:
         return AnswerFuture(
             deadline=None if d is None else time.monotonic() + d)
 
+    @contextlib.contextmanager
+    def _keygen(self, fut: AnswerFuture):
+        """Number one request (``fut.context["request"]``) and hold the
+        client keygen lock for it (keygen shares one rng): the wait for
+        the lock is the span ``pir.gen_lock``, the time holding it
+        ``pir.gen``."""
+        request = next(self._requests)
+        fut.context["request"] = request
+        with jax.profiler.TraceAnnotation("pir.gen_lock", request=request):
+            self._lock.acquire()
+        try:
+            with jax.profiler.TraceAnnotation("pir.gen", request=request):
+                yield
+        finally:
+            self._lock.release()
+
     def submit(self, index: int, *,
                deadline_s: Optional[float] = None) -> AnswerFuture:
         """Private retrieval of ``db[index]``; resolves to one record
@@ -876,7 +943,7 @@ class MultiServerPIR:
         explicit timeout waits only until it.
         """
         fut = self._deadline_future(deadline_s)
-        with self._lock:     # client-side keygen shares one rng
+        with self._keygen(fut):
             q = pir.query_gen(self.rng, index, self.cfg)
         return self.scheduler.submit(q.keys, future=fut)
 
@@ -1026,10 +1093,12 @@ class SingleServerPIR(MultiServerPIR):
 
         def finalize(raw, n):
             ans, epoch, states = raw
-            hint = self._client_hint(epoch)
-            rec = np.asarray(proto.reconstruct_with(
-                [np.asarray(ans[:n])], states[:n], cfg=cfg, hint=hint))
-            return list(rec)
+            jax.block_until_ready(ans)
+            with _reconstruct_span(ans.shape[0], n):
+                hint = self._client_hint(epoch)
+                rec = np.asarray(proto.reconstruct_with(
+                    [np.asarray(ans[:n])], states[:n], cfg=cfg, hint=hint))
+                return list(rec)
 
         return QueryScheduler(
             collate=collate, stage=stage, dispatch=dispatch,
@@ -1044,7 +1113,7 @@ class SingleServerPIR(MultiServerPIR):
         ([item_bytes] u8). The per-query LWE secret stays client-side:
         only the ciphertext enters the scheduler's device path."""
         fut = self._deadline_future(deadline_s)
-        with self._lock:     # client-side keygen shares one rng
+        with self._keygen(fut):
             keys, state = self.protocol.query_gen_full(self.rng, index,
                                                        self.cfg)
         return self.scheduler.submit((keys, state), future=fut)

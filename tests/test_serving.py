@@ -1,15 +1,19 @@
 """Serving frontend: dynamic batching, pipelining, straggler shedding.
 
 Fast tier: the scheduler's control plane driven by fake collate/stage/
-dispatch/finalize callables (no XLA compiles, deterministic). Slow tier:
+dispatch/finalize callables (no XLA compiles, deterministic), and one
+profiler trace of a small real query (one compiled step per party, a
+few seconds). Slow tier:
 the real two-party protocol through the scheduler — ragged batch sizes,
 bucket-cache reuse, and the streaming session API — sharing one pair of
 compiled serve steps across the module (compiles cost ~40 s each on this
 container).
 """
+import glob
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -17,7 +21,8 @@ from repro.config import PIRConfig
 from repro.core import dpf, pir
 from repro.launch.mesh import make_local_mesh
 from repro.runtime.fault import StragglerMonitor
-from repro.runtime.serve_loop import (DEFAULT_MAX_WAIT_S, AnswerFuture,
+from repro.runtime.serve_loop import (DEFAULT_MAX_WAIT_S, LATENCY_WINDOW,
+                                      AnswerFuture, MultiServerPIR,
                                       QueryScheduler, TwoServerPIR)
 
 # ---------------------------------------------------------------------------
@@ -331,6 +336,42 @@ def test_scheduler_heartbeat_fires_per_pump_and_loop():
     assert len(beats) > n                        # session loop beats too
 
 
+def test_queue_wait_sums_submit_to_launch_per_answered_query():
+    """queue_wait_s adds, per real query, its batch's launch start minus
+    its submit time on the scheduler's clock, once the batch completes;
+    every future carries its batch's number."""
+    t = [0.0]
+
+    def dispatch(staged):
+        t[0] += 1.0                                  # each launch takes 1 s
+        return [x * 2 for x in staged]
+
+    sched = QueryScheduler(collate=list, stage=lambda p: p,
+                           dispatch=dispatch, finalize=lambda raw, n: raw[:n],
+                           buckets=(2,), clock=lambda: t[0])
+    futs = []
+    for t_submit in (0.0, 1.0, 3.0, 3.5, 4.0):       # batches (0,1) (3,3.5)
+        t[0] = t_submit
+        futs.append(sched.submit(len(futs)))
+    assert sched.stats.queue_wait_s == 0.0           # nothing completed yet
+    t[0] = 10.0
+    assert sched.pump() == 5                         # (4) cut by the flush
+    # launches at 10, 11 and 12 (each dispatch advances the clock by 1)
+    assert sched.stats.queue_wait_s == (10 - 0) + (10 - 1) + (11 - 3) + (
+        11 - 3.5) + (12 - 4)
+    assert [f.context["batch"] for f in futs] == [0, 0, 1, 1, 2]
+    assert [f.result(0) for f in futs] == [0, 2, 4, 6, 8]
+
+
+def test_serve_stats_keep_only_recent_latencies():
+    sched, _ = make_fake_scheduler(buckets=(1,))
+    for i in range(LATENCY_WINDOW + 5):
+        sched.submit(i)
+    sched.pump()
+    assert sched.stats.batches == LATENCY_WINDOW + 5
+    assert len(sched.stats.latencies) == LATENCY_WINDOW
+
+
 def test_pad_keys_replicates_last_key():
     k0, _ = dpf.gen_keys(np.random.default_rng(0), 3, 5)
     batch = dpf.stack_keys([k0, k0])
@@ -341,6 +382,60 @@ def test_pad_keys_replicates_last_key():
     assert padded.cw_seed.shape == (4,) + batch.cw_seed.shape[1:]
     with pytest.raises(ValueError):
         dpf.pad_keys(batch, 1)
+
+
+def _host_spans(tracedir, prefix):
+    """``(name, start_ns, end_ns, metadata)`` of the host-plane events
+    whose names start with ``prefix``, from the one trace under
+    ``tracedir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(f"{tracedir}/**/*.xplane.pb", recursive=True)
+    return sorted(
+        (e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+        for plane in ProfileData.from_file(path).planes
+        if plane.name == "/host:CPU"
+        for line in plane.lines for e in line.events
+        if e.name.startswith(prefix))
+
+
+def test_profiler_spans_join_requests_to_their_batch(tmp_path):
+    """A traced query shows each request's ``pir.gen_lock`` then
+    ``pir.gen``, and its batch's ``pir.reconstruct`` after both; the
+    ``request``/``batch`` metadata match the futures' context."""
+    n = 1 << 6
+    db = pir.make_database(np.random.default_rng(0), n, 32)
+    system = MultiServerPIR(db, PIRConfig(n_items=n, item_bytes=32),
+                            make_local_mesh(), path="fused", n_queries=2,
+                            buckets=(2,),
+                            client_rng=np.random.default_rng(1))
+    system.query([1, 2])                             # compile outside the trace
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        futs = [system.submit(i) for i in (5, 60)]
+        system.scheduler.pump()
+        rows = [f.result(0) for f in futs]
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(np.stack(rows), db[[5, 60]])
+    spans = _host_spans(tmp_path, "pir.")
+    by = {}
+    for name, s, e, meta in spans:
+        by.setdefault(name, []).append((s, e, meta))
+    assert sorted(by) == ["pir.gen", "pir.gen_lock", "pir.reconstruct"]
+    (rec_s, _, rec_meta), = by["pir.reconstruct"]
+    assert rec_meta == {"batch": futs[0].context["batch"], "bucket": 2,
+                        "n": 2}
+    assert futs[1].context["batch"] == futs[0].context["batch"]
+    assert futs[0].context["request"] + 1 == futs[1].context["request"]
+    for fut in futs:
+        request = fut.context["request"]
+        (lock_s, lock_e, _), = [x for x in by["pir.gen_lock"]
+                                if x[2] == {"request": request}]
+        (gen_s, gen_e, _), = [x for x in by["pir.gen"]
+                              if x[2] == {"request": request}]
+        assert lock_s <= lock_e <= gen_s < gen_e <= rec_s
 
 
 # ---------------------------------------------------------------------------
@@ -392,3 +487,4 @@ def test_streaming_session_reconciles_async(system):
     for i, r in zip(indices, rows):
         np.testing.assert_array_equal(r, db[i])
     assert not sys2.scheduler.running
+

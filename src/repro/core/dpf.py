@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.crypto.chacha import ggm_double, prg_bits
+from repro.crypto.chacha import chacha_rows, ggm_double, prg_bits
 
 U32 = jnp.uint32
 
@@ -163,6 +163,27 @@ def _expand_level(seeds, t_bits, cw_seed_l, cw_t_l, rounds):
     return seeds2, t2
 
 
+def _descend(key: DPFKey, start_block, depth: int):
+    """Path-descend ``depth`` levels along the bits of ``start_block``
+    (MSB first): the corrected seed ``[4]`` and control bit of that
+    subtree's root."""
+    start_block = jnp.asarray(start_block, U32)
+    seeds = key.root_seed
+    t = jnp.asarray(key.party, U32)
+    for level in range(depth):
+        bit = (start_block >> U32(depth - 1 - level)) & U32(1)
+        s_l, t_l, s_r, t_r = ggm_double(seeds, rounds=key.rounds)
+        s_cw = key.cw_seed[level]
+        t_cw = key.cw_t[level]
+        s_l = s_l ^ (t * s_cw)
+        s_r = s_r ^ (t * s_cw)
+        t_l = t_l ^ (t & t_cw[0])
+        t_r = t_r ^ (t & t_cw[1])
+        seeds = jnp.where(bit, s_r, s_l)
+        t = jnp.where(bit, t_r, t_l)
+    return seeds, t
+
+
 def eval_range(
     key: DPFKey,
     start_block: jax.Array | int,
@@ -181,20 +202,7 @@ def eval_range(
     if log_range > key.log_n:
         raise ValueError("log_range exceeds domain")
     depth = key.log_n - log_range
-    start_block = jnp.asarray(start_block, U32)
-    seeds = key.root_seed
-    t = jnp.asarray(key.party, U32)
-    for level in range(depth):
-        bit = (start_block >> U32(depth - 1 - level)) & U32(1)
-        s_l, t_l, s_r, t_r = ggm_double(seeds, rounds=key.rounds)
-        s_cw = key.cw_seed[level]
-        t_cw = key.cw_t[level]
-        s_l = s_l ^ (t * s_cw)
-        s_r = s_r ^ (t * s_cw)
-        t_l = t_l ^ (t & t_cw[0])
-        t_r = t_r ^ (t & t_cw[1])
-        seeds = jnp.where(bit, s_r, s_l)
-        t = jnp.where(bit, t_r, t_l)
+    seeds, t = _descend(key, start_block, depth)
     seeds = seeds[None, :]
     t = t[None]
     for level in range(depth, key.log_n):
@@ -209,63 +217,68 @@ def eval_all(key: DPFKey) -> Tuple[jax.Array, jax.Array]:
     return eval_range(key, 0, key.log_n)
 
 
-def eval_to_depth(
-    key: DPFKey,
-    start_block: jax.Array | int,
-    log_range: int,
-    stop_log: int,
-) -> Tuple[jax.Array, jax.Array]:
-    """Partial evaluation: the shard's *internal* nodes at chunk granularity.
+def _interleave(left: jax.Array, right: jax.Array, axis: int) -> jax.Array:
+    """Children to node order along ``axis``: the two child arrays become
+    one with that axis doubled, child b of node i at ``2i + b``."""
+    shape = list(left.shape)
+    shape[axis] *= 2
+    return jnp.stack([left, right], axis=axis + 1).reshape(shape)
 
-    Identical to :func:`eval_range` (same descent, same breadth expansion,
-    so parity is by construction) but stops ``stop_log`` levels above the
-    leaves: returns the corrected subtree-root seeds + control bits of the
-    shard's ``2^(log_range - stop_log)`` chunks of ``2^stop_log`` leaves
-    each. These are the inputs of the fused-scan megakernel
-    (``kernels/fused_scan.py``), which expands the remaining ``stop_log``
-    levels in VMEM — one descent shared across all chunks, unlike the
-    chunked-jnp fused path which re-descends per chunk.
 
-    Returns (seeds ``[2^(log_range - stop_log), 4]`` u32, t same-length).
-    """
-    if log_range > key.log_n:
-        raise ValueError("log_range exceeds domain")
-    if not (0 <= stop_log <= log_range):
-        raise ValueError(f"stop_log={stop_log} outside [0, {log_range}]")
-    depth = key.log_n - log_range
-    start_block = jnp.asarray(start_block, U32)
-    seeds = key.root_seed
-    t = jnp.asarray(key.party, U32)
-    for level in range(depth):
-        bit = (start_block >> U32(depth - 1 - level)) & U32(1)
-        s_l, t_l, s_r, t_r = ggm_double(seeds, rounds=key.rounds)
-        s_cw = key.cw_seed[level]
-        t_cw = key.cw_t[level]
-        s_l = s_l ^ (t * s_cw)
-        s_r = s_r ^ (t * s_cw)
-        t_l = t_l ^ (t & t_cw[0])
-        t_r = t_r ^ (t & t_cw[1])
-        seeds = jnp.where(bit, s_r, s_l)
-        t = jnp.where(bit, t_r, t_l)
-    seeds = seeds[None, :]
-    t = t[None]
-    for level in range(depth, key.log_n - stop_log):
-        seeds, t = _expand_level(
-            seeds, t, key.cw_seed[level], key.cw_t[level], key.rounds
-        )
-    return seeds, t
+#: the chunk-root expansion's last levels grow a major axis of up to
+#: 2^_ROW_LEVELS rows instead of the lanes (see eval_roots_batch)
+_ROW_LEVELS = 7
 
 
 @partial(jax.jit, static_argnames=("log_range", "stop_log"))
 def eval_roots_batch(keys: DPFKey, start_block, log_range: int,
-                     stop_log: int) -> Tuple[jax.Array, jax.Array]:
-    """vmap'd :func:`eval_to_depth` over a batched key pytree.
+                     stop_log: int) -> jax.Array:
+    """Partial evaluation of a batch of keys at chunk granularity.
 
-    Returns (seeds ``[Q, C, 4]`` u32, t ``[Q, C]`` u32) where
-    ``C = 2^(log_range - stop_log)`` chunk roots per query.
+    The same descent and breadth expansion as :func:`eval_range` (the same
+    ChaCha stream, so parity is by construction), stopped ``stop_log``
+    levels above the leaves: the corrected subtree roots of the shard's
+    ``C = 2^(log_range - stop_log)`` chunks of ``2^stop_log`` leaves each.
+    These are the inputs of the fused-scan megakernel
+    (``kernels/fused_scan.py``), which expands the remaining ``stop_log``
+    levels in VMEM — one descent shared across all chunks, unlike the
+    chunked-jnp fused path which re-descends per chunk.
+
+    Lane-dense: the expansion keeps each seed word as its own array of
+    nodes (``chacha_rows``), never ``[C, 4]``, whose 4-word minor axis a
+    TPU pads to 128 lanes (32x the bytes). The first levels interleave
+    children along the lanes; the last ``_ROW_LEVELS`` along a major axis,
+    since a lane interleave passes through a ``[..., 2]`` array (64x) at
+    full width. One transpose of ``[2^7, C / 2^7]`` puts the chunks in
+    order. The query axis is explicit rather than vmapped: the serve step
+    traces this once per bucket and party, and vmap doubles that time.
+
+    Returns ``[Q, 5, C]`` u32 — per query, rows 0-3 the seed words of its
+    chunk roots, row 4 their control bits.
     """
-    return jax.vmap(
-        lambda k: eval_to_depth(k, start_block, log_range, stop_log))(keys)
+    if log_range > keys.log_n:
+        raise ValueError("log_range exceeds domain")
+    if not (0 <= stop_log <= log_range):
+        raise ValueError(f"stop_log={stop_log} outside [0, {log_range}]")
+    depth = keys.log_n - log_range
+    stop = keys.log_n - stop_log
+    seeds, t = jax.vmap(lambda k: _descend(k, start_block, depth))(keys)
+    q = t.shape[0]
+    rows = [seeds[:, w].reshape(q, 1, 1) for w in range(4)]
+    t = t.reshape(q, 1, 1)
+    for level in range(depth, stop):
+        axis = 1 if stop - level <= _ROW_LEVELS else 2
+        out = chacha_rows(rows, counter=0, rounds=keys.rounds)
+        s_cw = keys.cw_seed[:, level, :, None, None]         # [Q, 4, 1, 1]
+        t_cw = keys.cw_t[:, level, :, None, None]            # [Q, 2, 1, 1]
+        rows = [_interleave(out[w] ^ (t * s_cw[:, w]),
+                            out[4 + w] ^ (t * s_cw[:, w]), axis)
+                for w in range(4)]
+        t = _interleave((out[8] & U32(1)) ^ (t & t_cw[:, 0]),
+                        (out[9] & U32(1)) ^ (t & t_cw[:, 1]), axis)
+    # node (q, i, h) is chunk h * 2^(row levels) + i of query q
+    return jnp.stack(rows + [t], axis=1).transpose(0, 1, 3, 2).reshape(
+        q, 5, -1)
 
 
 def leaf_bits(t_bits: jax.Array) -> jax.Array:

@@ -178,10 +178,11 @@ def plan_for(cfg: PIRConfig, n_queries: int, *,
     selection vector is materialized only while the DB fits one chunk
     (db <= 2^chunk_log rows — a global-size rule: a sharded mesh divides
     the per-device rows further, only making materialization cheaper);
-    past that, XOR protocols take the fused chunked expand+scan and
-    additive protocols on a TPU the ``fused-pallas`` megakernel; the
-    Pallas bodies run only on a TPU backend (on CPU they would execute in
-    interpret mode); LWE always contracts with XLA's int32 dot.
+    past that, on a TPU, XOR and additive protocols take the
+    ``fused-pallas`` megakernel where a tile fits its VMEM; elsewhere XOR
+    takes the fused chunked expand+scan and additive the materialized
+    GEMM (on CPU the Pallas bodies would execute in interpret mode); LWE
+    always contracts with XLA's int32 dot.
     """
     from repro.engine.tuner import heuristic_plan
     return heuristic_plan(cfg, n_queries, backend=backend,
@@ -499,12 +500,11 @@ def _fused_pallas_inputs(keys_local, start_block, log_local: int,
     from repro.kernels import ops
     tile, clog = ops.fused_tile(rows_local, plan.tile_r,
                                 min(plan.chunk_log, log_local))
-    roots, t_roots = dpf.eval_roots_batch(keys_local, start_block,
-                                          log_local, clog)
+    roots = dpf.eval_roots_batch(keys_local, start_block, log_local, clog)
     log_n = keys_local.log_n
     cw_seed_lv = keys_local.cw_seed[:, log_n - clog:, :]
     cw_t_lv = keys_local.cw_t[:, log_n - clog:, :]
-    return tile, roots, t_roots, cw_seed_lv, cw_t_lv
+    return tile, roots, cw_seed_lv, cw_t_lv
 
 
 def _fused_pallas_xor_answer(db_local, keys_local, start_block, log_local,
@@ -514,9 +514,9 @@ def _fused_pallas_xor_answer(db_local, keys_local, start_block, log_local,
     ``keys_local`` is a batched plain DPFKey pytree ([Q, ...] leaves).
     """
     from repro.kernels import ops
-    tile, roots, t_roots, cw_s, cw_t = _fused_pallas_inputs(
+    tile, roots, cw_s, cw_t = _fused_pallas_inputs(
         keys_local, start_block, log_local, db_local.shape[0], plan)
-    return ops.fused_scan_xor(db_local, roots, t_roots, cw_s, cw_t,
+    return ops.fused_scan_xor(db_local, roots, cw_s, cw_t,
                               tile_r=tile, depth=plan.depth)
 
 
@@ -541,9 +541,9 @@ def _fused_pallas_add_answer(db_local, keys_local, start_block, log_local,
     """Megakernel additive answer: in-kernel share conversion + select-add,
     bit-identical int32 to the materialized int8 GEMM."""
     from repro.kernels import ops
-    tile, roots, t_roots, cw_s, cw_t = _fused_pallas_inputs(
+    tile, roots, cw_s, cw_t = _fused_pallas_inputs(
         keys_local, start_block, log_local, db_local.shape[0], plan)
-    return ops.fused_scan_bytes(db_local, roots, t_roots, cw_s, cw_t,
+    return ops.fused_scan_bytes(db_local, roots, cw_s, cw_t,
                                 keys_local.cw_final[:, 0],
                                 party=keys_local.party, tile_r=tile,
                                 depth=plan.depth)

@@ -32,6 +32,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 # "expa nd 3 2-by te k" — the standard ChaCha constants.
 SIGMA = np.array([0x61707865, 0x3320646E, 0x79622D32, 0x6B206574], dtype=np.uint32)
@@ -39,19 +40,25 @@ SIGMA = np.array([0x61707865, 0x3320646E, 0x79622D32, 0x6B206574], dtype=np.uint
 PRG_ROUNDS = {"chacha8": 8, "chacha12": 12, "chacha20": 20}
 
 
+# The ARX steps are lax primitives, not jnp operators: each jnp operator
+# is a jitted function of its own, and tracing the ~2000 of them in one
+# serve step's GGM expansion (chacha_rows, once per tree level) took most
+# of the step's trace time.
+
 def _rotl32(x: jax.Array, n: int) -> jax.Array:
-    return (x << np.uint32(n)) | (x >> np.uint32(32 - n))
+    return lax.bitwise_or(lax.shift_left(x, np.uint32(n)),
+                          lax.shift_right_logical(x, np.uint32(32 - n)))
 
 
 def _quarter(a, b, c, d):
-    a = a + b
-    d = _rotl32(d ^ a, 16)
-    c = c + d
-    b = _rotl32(b ^ c, 12)
-    a = a + b
-    d = _rotl32(d ^ a, 8)
-    c = c + d
-    b = _rotl32(b ^ c, 7)
+    a = lax.add(a, b)
+    d = _rotl32(lax.bitwise_xor(d, a), 16)
+    c = lax.add(c, d)
+    b = _rotl32(lax.bitwise_xor(b, c), 12)
+    a = lax.add(a, b)
+    d = _rotl32(lax.bitwise_xor(d, a), 8)
+    c = lax.add(c, d)
+    b = _rotl32(lax.bitwise_xor(b, c), 7)
     return a, b, c, d
 
 
@@ -103,6 +110,31 @@ def chacha_block(key4: jax.Array, *, counter: int = 0, rounds: int = 12) -> jax.
         tuple(state[..., i] for i in range(16)))
     out = jnp.stack(x, axis=-1) + state
     return out
+
+
+def chacha_rows(seed_rows, counter: int = 0, rounds: int = 12):
+    """:func:`chacha_block` over row vectors: nodes on lanes, not ``[..., 4]``.
+
+    seed_rows: 4 u32 arrays of one shape (one per seed word). Returns the
+    16 words of each node's block as 16 arrays of that shape, the same
+    stream as ``chacha_block``. This lane-dense form is what the Pallas
+    kernels and the chunk-root expansion (``core/dpf.py eval_roots_batch``)
+    run.
+    """
+    shape = seed_rows[0].shape
+    const = [jnp.full(shape, np.uint32(c)) for c in SIGMA]
+    ctr_words = [counter & 0xFFFFFFFF, 0x5049522D, 0x494D5049, 0x52212121]
+    ctr = [jnp.full(shape, np.uint32(c)) for c in ctr_words]
+    state = const + list(seed_rows) + list(seed_rows) + ctr
+    # Rolled double rounds, as in chacha_block: callers like the fused
+    # megakernel instantiate this permutation once per tree level, and
+    # unrolled, the XLA:CPU graph of the interpret-mode emulation grew
+    # superlinearly in rounds x levels (the additive fused body hit a
+    # >15 min, >20 GB compile at rounds=12).
+    x = jax.lax.fori_loop(0, rounds // 2,
+                          lambda _, xs: tuple(_double_round(list(xs))),
+                          tuple(state))
+    return [lax.add(xi, si) for xi, si in zip(x, state)]
 
 
 def ggm_double(seeds: jax.Array, *, rounds: int = 12):

@@ -281,18 +281,18 @@ def _round_up(x: int, m: int) -> int:
 def _fused_pallas_common(shape: ProblemShape, p: Dict[str, int],
                          words_per_row: int) -> Tuple[int, int, int]:
     """(tile_r, depth, VMEM bytes both bodies hold besides their DB
-    buffers): the expansion, one packed key block per DMA slot (5 words
-    per chunk root, lanes padded to 128), the VMEM-resident CW levels and
-    the output block."""
+    buffers): the expansion, two key-group slots (5 rows of chunk roots,
+    128 lanes or one tile's chunks where more), the VMEM-resident CW
+    levels and the output block."""
     q = shape.bucket
     tr = p.get("tile_r", legal_tile(shape.rows, 2048, pow2=True))
     cl = min(p.get("chunk_log", 12), tr.bit_length() - 1)
     d = p.get("depth", 2)
     q8 = _round_up(q, 8)
-    key_block = q8 * _round_up(5 * (tr >> cl), 128)
+    key_group = 5 * q8 * max(128, tr >> cl)
     cw_levels = max(cl, 1) * q8 * 128
     out = q8 * _round_up(shape.item_bytes, 128)
-    words = (_round_up(q, 4) * tr * words_per_row + d * key_block
+    words = (_round_up(q, 4) * tr * words_per_row + 2 * key_group
              + cw_levels + out)
     return tr, d, U32_BYTES * words
 

@@ -58,15 +58,17 @@ def heuristic_plan(cfg, n_queries: int, *, backend: Optional[str] = None,
         keeps ``[Q, rows, 4]`` u32 seeds, lane-padded 32x on a TPU, so at
         2^25 rows one party's single-query step needs 9.5 GiB of temp and
         a four-query additive step 32 GiB;
-      * past that, XOR protocols take the fused chunked expand+scan (the
-        selection bits never reach HBM) at every bucket size — its inner
-        fold is always the jnp ``dpxor``, so its ``scan`` is "jnp" on
-        every backend — and additive protocols on a TPU take the
-        ``fused-pallas`` megakernel at the largest tile (<= 2048 rows)
-        whose VMEM footprint fits;
-      * the Pallas bodies run real Mosaic only on a TPU backend — on CPU
-        they would execute in interpret mode, so the jnp oracle is the
-        fast CPU path, and additive protocols keep the materialized GEMM;
+      * past that, on a TPU, XOR and additive protocols alike take the
+        ``fused-pallas`` megakernel of their share algebra at the largest
+        tile (<= 2048 rows) whose VMEM footprint fits the queries the
+        kernel scans (``xor-dpf-k`` scans one pseudo-query per key
+        component); its chunk roots reach it lane-dense, so the step
+        needs a few MB of HBM beside its arguments, as the fused path;
+      * where no tile fits, and on the CPU (where the Pallas bodies would
+        run in interpret mode), XOR protocols take the fused chunked
+        expand+scan (its inner fold is always the jnp ``dpxor``, so its
+        ``scan`` is "jnp" on every backend) and additive protocols the
+        materialized GEMM;
       * LWE contracts with XLA's int32 dot on every backend: the v5e MXU
         has no int32 matmul, so Mosaic refuses the Pallas int32 body.
 
@@ -83,11 +85,12 @@ def heuristic_plan(cfg, n_queries: int, *, backend: Optional[str] = None,
         return protocol_mod.ExecutionPlan(
             expand="materialize", scan="jnp", chunk_log=chunk_log,
             tile_r=GEMM_TILE_R_DEFAULT)
+    if backend == "tpu" and not small_db:
+        plan = _fitting_fused_pallas(cfg, n_queries, chunk_log,
+                                     proto.share_kind)
+        if plan is not None:
+            return plan
     if proto.share_kind == "additive":
-        if backend == "tpu" and not small_db:
-            plan = _fitting_fused_pallas(cfg, n_queries, chunk_log)
-            if plan is not None:
-                return plan
         return protocol_mod.ExecutionPlan(
             expand="materialize", scan=scan, chunk_log=chunk_log,
             tile_r=GEMM_TILE_R_DEFAULT)
@@ -98,12 +101,18 @@ def heuristic_plan(cfg, n_queries: int, *, backend: Optional[str] = None,
                                       chunk_log=chunk_log)
 
 
-def _fitting_fused_pallas(cfg, n_queries: int, chunk_log: int):
-    """The additive megakernel plan at the largest tile <= 2048 rows whose
-    VMEM footprint fits, or None when even a 128-row tile does not."""
+def _fitting_fused_pallas(cfg, n_queries: int, chunk_log: int,
+                          share_kind: str):
+    """The share algebra's megakernel plan at the largest tile <= 2048
+    rows whose VMEM footprint fits, or None when even a 128-row tile does
+    not. The footprint is judged at the queries the kernel scans: a key
+    with a component axis (``[Q, C, ...]`` leaves, ``xor-dpf-k``) runs
+    Q*C pseudo-queries."""
     from repro.core import protocol as protocol_mod
-    desc = get_kernel("gemm-fused-pallas")
-    shape = problem_shape(cfg, n_queries)
+    desc, = [d for d in serve_kernels(share_kind, "tpu")
+             if d.expand == "fused-pallas"]
+    root = protocol_mod.get(cfg.protocol).key_specs(cfg, n_queries).root_seed
+    shape = problem_shape(cfg, int(np.prod(root.shape[:-1])))
     tile = 2048
     while tile >= 128:
         params = desc.legalize_fn(shape, {"tile_r": tile,
@@ -407,7 +416,7 @@ _EXPECTED_PLANS = {
     ("additive-dpf-2", 10, 4, "tpu"): ("materialize", "pallas"),
     ("xor-dpf-2", 14, 1, "cpu"): ("fused", "jnp"),         # single query
     ("xor-dpf-2", 14, 4, "cpu"): ("fused", "jnp"),         # big-db regime
-    ("xor-dpf-2", 14, 4, "tpu"): ("fused", "jnp"),
+    ("xor-dpf-2", 14, 4, "tpu"): ("fused-pallas", "pallas"),
     ("additive-dpf-2", 14, 4, "cpu"): ("materialize", "jnp"),
     ("additive-dpf-2", 14, 4, "tpu"): ("fused-pallas", "pallas"),
     ("lwe-simple-1", 14, 4, "tpu"): ("materialize", "jnp"),

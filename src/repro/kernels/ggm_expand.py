@@ -29,69 +29,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.crypto.chacha import SIGMA
+from repro.crypto.chacha import chacha_rows
 from repro.engine.backend import resolve_interpret
 
 U32 = jnp.uint32
-
-
-def _rotl(x, n):
-    return (x << np.uint32(n)) | (x >> np.uint32(32 - n))
-
-
-def _quarter(a, b, c, d):
-    a = a + b
-    d = _rotl(d ^ a, 16)
-    c = c + d
-    b = _rotl(b ^ c, 12)
-    a = a + b
-    d = _rotl(d ^ a, 8)
-    c = c + d
-    b = _rotl(b ^ c, 7)
-    return a, b, c, d
-
-
-def _chacha_rows(seed_rows, counter: int, rounds: int):
-    """ChaCha permutation over row-vector lanes; mirrors crypto.chacha.
-
-    seed_rows: list of 4 ``[TILE]`` u32 vectors. Returns 16 ``[TILE]`` rows.
-    """
-    tile = seed_rows[0].shape
-    const = [jnp.full(tile, np.uint32(c)) for c in SIGMA]
-    ctr_words = [counter & 0xFFFFFFFF, 0x5049522D, 0x494D5049, 0x52212121]
-    ctr = [jnp.full(tile, np.uint32(c)) for c in ctr_words]
-    state = const + seed_rows + seed_rows + ctr
-
-    def double_round(_, xs):
-        x = list(xs)
-        # column rounds
-        for i in range(4):
-            x[i], x[4 + i], x[8 + i], x[12 + i] = _quarter(
-                x[i], x[4 + i], x[8 + i], x[12 + i]
-            )
-        # diagonal rounds
-        for i in range(4):
-            a, b, c, d = i, 4 + (i + 1) % 4, 8 + (i + 2) % 4, 12 + (i + 3) % 4
-            x[a], x[b], x[c], x[d] = _quarter(x[a], x[b], x[c], x[d])
-        return tuple(x)
-
-    # Rolled (not Python-unrolled) double rounds: every iteration is the
-    # same ARX dataflow, and callers like the fused megakernel instantiate
-    # this permutation once per tree level — unrolled, the XLA:CPU graph
-    # of the interpret-mode emulation grew superlinearly in rounds × levels
-    # (the additive fused body hit a >15 min, >20 GB compile at rounds=12).
-    x = jax.lax.fori_loop(0, rounds // 2, double_round, tuple(state))
-    return [xi + si for xi, si in zip(x, state)]
 
 
 def _ggm_expand_kernel(seeds_ref, t_ref, cw_seed_ref, cw_t_ref,
                        child_ref, tout_ref, *, rounds: int):
     """Expand one tile of GGM nodes: seeds [4,T] -> children [8,T], t [2,T]."""
     seed_rows = [seeds_ref[i, :] for i in range(4)]
-    out = _chacha_rows(seed_rows, counter=0, rounds=rounds)
+    out = chacha_rows(seed_rows, counter=0, rounds=rounds)
     t = t_ref[0, :]
     mask = jnp.uint32(0) - t                       # 0x0 / 0xFFFFFFFF
     t_l = (out[8] & U32(1)) ^ (t & cw_t_ref[0, 0])

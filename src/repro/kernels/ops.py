@@ -90,7 +90,7 @@ def fused_tile(rows: int, tile_r: int, clog: int) -> tuple[int, int]:
     return tile, min(clog, tile.bit_length() - 1)
 
 
-def fused_scan_xor(db_words: jax.Array, roots: jax.Array, t_roots: jax.Array,
+def fused_scan_xor(db_words: jax.Array, roots: jax.Array,
                    cw_seed_lv: jax.Array, cw_t_lv: jax.Array, *,
                    tile_r: int = 2048, depth: int = 2, rounds: int = 12,
                    interpret: bool | None = None) -> jax.Array:
@@ -98,9 +98,9 @@ def fused_scan_xor(db_words: jax.Array, roots: jax.Array, t_roots: jax.Array,
 
     Args:
       db_words:   ``[R, W] uint32`` row-major DB shard.
-      roots:      ``[Q, C, 4] uint32`` chunk-root seeds
+      roots:      ``[Q, 5, C] uint32`` chunk roots, chunks on lanes: 4
+                  seed-word rows, then the control bits
                   (``dpf.eval_roots_batch`` with ``stop_log = log2(R/C)``).
-      t_roots:    ``[Q, C] uint32`` chunk-root control bits.
       cw_seed_lv: ``[Q, clog, 4] uint32`` — the *last* clog levels of each
                   key's ``cw_seed`` (``key.cw_seed[:, log_n-clog:, :]``).
       cw_t_lv:    ``[Q, clog, 2] uint32`` — same slice of ``cw_t``.
@@ -110,14 +110,14 @@ def fused_scan_xor(db_words: jax.Array, roots: jax.Array, t_roots: jax.Array,
       depth:      rotating DMA buffer count.
     """
     tile, _ = fused_tile(db_words.shape[0], tile_r, cw_seed_lv.shape[1])
-    return fused_scan_xor_t(db_words.T, roots, t_roots, cw_seed_lv, cw_t_lv,
+    return fused_scan_xor_t(db_words.T, roots, cw_seed_lv, cw_t_lv,
                             tile_r=tile, depth=depth, rounds=rounds,
                             interpret=interpret)
 
 
 def fused_scan_bytes(db_bytes: jax.Array, roots: jax.Array,
-                     t_roots: jax.Array, cw_seed_lv: jax.Array,
-                     cw_t_lv: jax.Array, cw_final: jax.Array, *, party: int,
+                     cw_seed_lv: jax.Array, cw_t_lv: jax.Array,
+                     cw_final: jax.Array, *, party: int,
                      tile_r: int = 2048, depth: int = 2, rounds: int = 12,
                      interpret: bool | None = None) -> jax.Array:
     """Fused expand+select-add megakernel over the int8 byte view.
@@ -129,8 +129,8 @@ def fused_scan_bytes(db_bytes: jax.Array, roots: jax.Array,
     resident layout, see ``kernels/pir_matmul.py``).
     """
     tile, _ = fused_tile(db_bytes.shape[0], tile_r, cw_seed_lv.shape[1])
-    return fused_scan_add(db_bytes.T, roots, t_roots, cw_seed_lv, cw_t_lv,
-                          cw_final, party=party, tile_r=tile, depth=depth,
+    return fused_scan_add(db_bytes.T, roots, cw_seed_lv, cw_t_lv, cw_final,
+                          party=party, tile_r=tile, depth=depth,
                           rounds=rounds, interpret=interpret)
 
 

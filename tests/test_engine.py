@@ -112,29 +112,31 @@ def test_ops_non_pow2_shard_shapes_regression():
 # heuristic fallback == an inline replica of its rules
 # ---------------------------------------------------------------------------
 
-#: the additive megakernel's tile per bucket at 32 B records: the largest
-#: tile <= 2048 whose modeled VMEM fits 16 MiB (engine/kernels.py)
-_FUSED_ADD_TILE = {1: 2048, 4: 2048, 32: 512}
+#: the megakernel's tile per count of queries it scans, at 32 B records:
+#: the largest tile <= 2048 whose modeled VMEM fits 16 MiB
+#: (engine/kernels.py); the XOR and additive footprints agree at 32 B
+_FUSED_TILE = {1: 2048, 3: 2048, 4: 2048, 12: 2048, 32: 512, 96: 256}
 
 
 def _pre_engine_plan_for(cfg, n_queries, backend, chunk_log=12):
     """Inline replica of the heuristic's rules (independent of the engine
-    code): materialize only while the DB fits one chunk; past that XOR
-    takes fused and additive-on-TPU the megakernel; LWE always the XLA
-    int32 dot."""
+    code): materialize only while the DB fits one chunk; past that, on a
+    TPU, XOR and additive take the megakernel (xor-dpf-k scans one
+    pseudo-query per component of party 0's keys, 3), elsewhere XOR takes
+    fused and additive the GEMM; LWE always the XLA int32 dot."""
     scan = "pallas" if backend == "tpu" else "jnp"
     proto = protocol_mod.get(cfg.protocol)
     small_db = cfg.n_items <= (1 << chunk_log)
     if proto.share_kind == "lwe":
         return ExecutionPlan(expand="materialize", scan="jnp",
                              chunk_log=chunk_log, tile_r=1024)
+    if backend == "tpu" and not small_db:
+        components = 3 if cfg.protocol == "xor-dpf-k" else 1
+        tile = _FUSED_TILE[n_queries * components]
+        return ExecutionPlan(expand="fused-pallas", scan="pallas",
+                             chunk_log=min(chunk_log, tile.bit_length() - 1),
+                             tile_r=tile, depth=2)
     if proto.share_kind == "additive":
-        if backend == "tpu" and not small_db:
-            tile = _FUSED_ADD_TILE[n_queries]
-            return ExecutionPlan(expand="fused-pallas", scan="pallas",
-                                 chunk_log=min(chunk_log,
-                                               tile.bit_length() - 1),
-                                 tile_r=tile, depth=2)
         # tiles were then hardcoded in kernels/ops.py: gemm tile_r=1024
         return ExecutionPlan(expand="materialize", scan=scan,
                              chunk_log=chunk_log, tile_r=1024)
@@ -260,6 +262,20 @@ def test_additive_megakernel_tile_fits_vmem_per_bucket():
     model fits, so big buckets never resolve to a plan Mosaic refuses."""
     desc = engine.get_kernel("gemm-fused-pallas")
     cfg = PIRConfig(n_items=1 << 25, protocol="additive-dpf-2")
+    for bucket, tile in ((1, 2048), (8, 2048), (16, 1024), (32, 512)):
+        plan = plan_for(cfg, bucket, backend="tpu")
+        assert (plan.expand, plan.tile_r) == ("fused-pallas", tile)
+        shape = engine.problem_shape(cfg, bucket)
+        assert desc.feasible(shape, {"tile_r": plan.tile_r,
+                                     "chunk_log": plan.chunk_log,
+                                     "depth": plan.depth})
+
+
+def test_xor_megakernel_tile_fits_vmem_per_bucket():
+    """XOR twin: on a TPU the XOR heuristic takes the megakernel too, and
+    shrinks its tile by the XOR body's VMEM model as buckets grow."""
+    desc = engine.get_kernel("xor-fused-pallas")
+    cfg = PIRConfig(n_items=1 << 25)
     for bucket, tile in ((1, 2048), (8, 2048), (16, 1024), (32, 512)):
         plan = plan_for(cfg, bucket, backend="tpu")
         assert (plan.expand, plan.tile_r) == ("fused-pallas", tile)

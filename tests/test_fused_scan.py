@@ -63,20 +63,17 @@ def _add_keys(party=0):
 
 def _fused_xor(keys, db, tile_r, clog, depth, start_block=0,
                log_local=LOG_N):
-    roots, t_roots = dpf.eval_roots_batch(keys, start_block, log_local,
-                                          clog)
+    roots = dpf.eval_roots_batch(keys, start_block, log_local, clog)
     lvl0 = keys.log_n - clog
-    return ops.fused_scan_xor(db, roots, t_roots,
-                              keys.cw_seed[:, lvl0:, :],
+    return ops.fused_scan_xor(db, roots, keys.cw_seed[:, lvl0:, :],
                               keys.cw_t[:, lvl0:, :],
                               tile_r=tile_r, depth=depth)
 
 
 def _fused_add(keys, db, tile_r, clog, depth):
-    roots, t_roots = dpf.eval_roots_batch(keys, 0, LOG_N, clog)
+    roots = dpf.eval_roots_batch(keys, 0, LOG_N, clog)
     lvl0 = keys.log_n - clog
-    return ops.fused_scan_bytes(db, roots, t_roots,
-                                keys.cw_seed[:, lvl0:, :],
+    return ops.fused_scan_bytes(db, roots, keys.cw_seed[:, lvl0:, :],
                                 keys.cw_t[:, lvl0:, :],
                                 keys.cw_final[:, 0], party=int(keys.party),
                                 tile_r=tile_r, depth=depth)
@@ -103,6 +100,50 @@ def test_fused_add_parity():
     shares = dpf.eval_bytes_batch(keys, 0, LOG_N)
     want = pir.answer_additive_matmul(DB_BYTES, shares)
     got = _fused_add(keys, DB_BYTES, tile_r=8, clog=2, depth=2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+#: 2^9 rows: enough chunk roots for several 128-lane key groups, and for
+#: the chunk-root expansion's lane levels (C > 2^7)
+LOG_BIG = 9
+DB_WORDS_BIG = jnp.asarray(RNG.integers(0, 1 << 32, size=(1 << LOG_BIG, W),
+                                        dtype=np.uint32))
+DB_BYTES_BIG = jnp.asarray(RNG.integers(-128, 128, size=(1 << LOG_BIG, L))
+                           .astype(np.int8))
+
+
+@pytest.mark.parametrize("body,q,tile_r,clog,start_block", [
+    ("xor", 1, 128, 0, 0),   # 128 roots per tile: one tile per key group
+    ("xor", 3, 8, 3, 0),     # 1 root per tile, 64 tiles: a partial group
+    ("xor", 3, 8, 3, 1),     # the same kernel on the upper half of 2^10
+    ("add", 1, 2, 1, 0),     # 1 root per tile, 256 tiles: two full groups
+    ("add", 3, 8, 1, 0),     # 4 roots per tile, 32 tiles per group
+])
+def test_fused_lane_dense_parity(body, q, tile_r, clog, start_block):
+    """Chunk roots ``[Q, 5, C]`` in key groups of 128 lanes, each tile's
+    roots rolled out of its group: answers equal the materialized oracle's
+    across group boundaries, partial groups and a shard's start_block."""
+    log_n = LOG_BIG + (start_block > 0)
+    idxs = [int(i) for i in RNG.integers(0, 1 << log_n, size=q)]
+    payload = dict(payload=np.array([1], np.uint32), payload_mod=256) \
+        if body == "add" else {}
+    party = q % 2                        # party 1 at Q = 1, party 0 at 3
+    keys = dpf.stack_keys([dpf.gen_keys(RNG, i, log_n, **payload)[party]
+                           for i in idxs])
+    roots = dpf.eval_roots_batch(keys, start_block, LOG_BIG, clog)
+    assert roots.shape == (q, 5, 1 << (LOG_BIG - clog))
+    lv = slice(log_n - clog, None)
+    if body == "xor":
+        bits = dpf.eval_bits_batch(keys, start_block, LOG_BIG)
+        want = jax.vmap(lambda b: pir.dpxor(DB_WORDS_BIG, b))(bits)
+        got = ops.fused_scan_xor(DB_WORDS_BIG, roots, keys.cw_seed[:, lv],
+                                 keys.cw_t[:, lv], tile_r=tile_r, depth=2)
+    else:
+        shares = dpf.eval_bytes_batch(keys, start_block, LOG_BIG)
+        want = pir.answer_additive_matmul(DB_BYTES_BIG, shares)
+        got = ops.fused_scan_bytes(DB_BYTES_BIG, roots, keys.cw_seed[:, lv],
+                                   keys.cw_t[:, lv], keys.cw_final[:, 0],
+                                   party=party, tile_r=tile_r, depth=2)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
@@ -171,7 +212,7 @@ def test_xor_footprint_formula():
     want = 4 * (2 * 8 * 1024          # two u32 DB buffers [W, TR]
                 + 4 * 1024 * 128      # expansion: 128 words/row/query
                 + 4 * 8 * 256         # lane fold: 256 words/query/word
-                + 2 * 8 * 128         # two key blocks [Q->8, 5*cpt->128]
+                + 2 * 5 * 8 * 128     # two key-group slots [5, Q->8, 128]
                 + 8 * 8 * 128         # 8 CW levels [Q->8, 6->128]
                 + 8 * 128)            # out [Q->8, W->128]
     assert desc.footprint_fn(shape, p) == want
@@ -190,10 +231,10 @@ def test_vmem_boundary_double_buffer_factor():
     assert desc.feasible(shape, shallow)
     assert not desc.feasible(shape, deep)
     # the delta between the two is exactly the extra slots: a u32 DB
-    # buffer [W, TR] and a key block [Q->8, 128] each
+    # buffer [W, TR] each (the key groups keep two slots at any depth)
     extra = desc.footprint_fn(shape, deep) - desc.footprint_fn(shape,
                                                                shallow)
-    assert extra == 4 * 2 * (384 * 2048 + 8 * 128)
+    assert extra == 4 * 2 * 384 * 2048
 
 
 def test_add_footprint_counts_buffers():
@@ -203,8 +244,8 @@ def test_add_footprint_counts_buffers():
                                    "depth": 1})
     f3 = desc.footprint_fn(shape, {"tile_r": 2048, "chunk_log": 8,
                                    "depth": 3})
-    # two extra int8 tiles [L, TR] and two extra u32 key blocks [8, 128]
-    assert f3 - f1 == 2 * (2048 * 64 + 4 * 8 * 128)
+    # two extra int8 tiles [L, TR]; the key groups keep two slots
+    assert f3 - f1 == 2 * 2048 * 64
 
 
 def test_legalize_couples_chunk_to_tile():

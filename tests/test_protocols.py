@@ -122,9 +122,9 @@ def test_plan_selection_rules():
     with pytest.raises(ValueError, match="additive"):
         build_serve_fn(PIRConfig(n_items=N), make_local_mesh(),
                        n_queries=2, path="matmul")
-    # selector: XOR small db -> materialize; XOR big db -> fused at every
-    # bucket size (a single query's full-domain eval does not fit at
-    # scale); Pallas bodies only on TPU
+    # selector: XOR small db -> materialize; XOR big db -> fused on the
+    # CPU at every bucket size (a single query's full-domain eval does not
+    # fit at scale), the megakernel on a TPU; Pallas bodies only on TPU
     small = plan_for(PIRConfig(n_items=1 << 10), 4, backend="cpu")
     big = plan_for(PIRConfig(n_items=1 << 20), 8, backend="cpu")
     single = plan_for(PIRConfig(n_items=1 << 20), 1, backend="cpu")
@@ -133,9 +133,11 @@ def test_plan_selection_rules():
     assert small.scan == "jnp"   # CPU: interpret-mode Pallas would be slow
     assert plan_for(PIRConfig(n_items=1 << 10), 8, backend="tpu").scan \
         == "pallas"
-    # the fused XOR body never reaches a scan kernel: jnp on any backend
-    assert plan_for(PIRConfig(n_items=1 << 20), 8, backend="tpu").scan \
-        == "jnp" == big.scan
+    # the fused XOR body never reaches a scan kernel: jnp on the CPU; a
+    # TPU takes the megakernel instead
+    assert big.scan == "jnp"
+    tpu_big = plan_for(PIRConfig(n_items=1 << 20), 8, backend="tpu")
+    assert (tpu_big.expand, tpu_big.scan) == ("fused-pallas", "pallas")
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,9 @@ matmul types the MXU lacks.
 
 Shapes are one chip's share of the paper's 1 GB point (2^25 rows x 32 B,
 bucket 4). Each test compiles with ``interpret=False`` and checks that the
-Pallas kernel is in the program (``tpu_custom_call``). Two megakernel
+Pallas kernel is in the program (``tpu_custom_call``). The engine's
+megakernel answer steps, at buckets 1, 2 and 4, also check the step's HBM
+beside its arguments against 1 % of the DB view. Two megakernel
 cases sit on either side of the engine's VMEM footprint model's 16 MiB
 bound and check the model against what the compiler accepts. Nothing is
 executed, so nothing here says anything about results or times.
@@ -18,8 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.config import PIRConfig
 from repro.configs.pir import PIR_1G_LWE
 from repro.core import protocol as protocol_mod
+from repro.engine.backend import FORCE_BACKEND_ENV
 from repro.engine.kernels import ProblemShape, get_kernel
 from repro.engine.tuner import heuristic_plan
 from repro.kernels import dpxor, fused_scan, ggm_expand, pir_matmul
@@ -65,14 +69,14 @@ def _has_kernel(compiled) -> bool:
 
 
 def _fused_xor(tile_r, clog, depth=2):
-    return lambda db, r, t, cs, ct: fused_scan.fused_scan_xor_t(
-        db, r, t, cs, ct, tile_r=tile_r, depth=depth, interpret=False)
+    return lambda db, r, cs, ct: fused_scan.fused_scan_xor_t(
+        db, r, cs, ct, tile_r=tile_r, depth=depth, interpret=False)
 
 
 def _fused_xor_shapes(rows, words, clog):
     c = rows >> clog
-    return [((words, rows), U32), ((Q, c, 4), U32), ((Q, c), U32),
-            ((Q, clog, 4), U32), ((Q, clog, 2), U32)]
+    return [((words, rows), U32), ((Q, 5, c), U32), ((Q, clog, 4), U32),
+            ((Q, clog, 2), U32)]
 
 
 def test_dpxor_compiles(one_chip):
@@ -109,12 +113,11 @@ def test_fused_scan_xor_compiles(one_chip):
 
 def test_fused_scan_add_compiles(one_chip):
     clog, c_roots = 11, ROWS >> 11
-    c = _compile(lambda db, r, t, cs, ct, cf: fused_scan.fused_scan_add(
-        db, r, t, cs, ct, cf, party=1, tile_r=2048, depth=2,
+    c = _compile(lambda db, r, cs, ct, cf: fused_scan.fused_scan_add(
+        db, r, cs, ct, cf, party=1, tile_r=2048, depth=2,
         interpret=False),
-        one_chip, ((32, ROWS), jnp.int8), ((Q, c_roots, 4), U32),
-        ((Q, c_roots), U32), ((Q, clog, 4), U32), ((Q, clog, 2), U32),
-        ((Q,), U32))
+        one_chip, ((32, ROWS), jnp.int8), ((Q, 5, c_roots), U32),
+        ((Q, clog, 4), U32), ((Q, clog, 2), U32), ((Q,), U32))
     assert _has_kernel(c)
     assert c.memory_analysis().temp_size_in_bytes < ROWS
 
@@ -135,6 +138,31 @@ def test_engine_lwe_step_compiles(one_chip):
     c = jax.jit(lambda d, k: proto.answer_local(d, k, 0, cfg.log_n, plan)
                 ).lower(db, keys).compile()
     assert not _has_kernel(c)
+
+
+@pytest.mark.parametrize("bucket", [1, 2, 4])
+@pytest.mark.parametrize("protocol", ["xor-dpf-2", "additive-dpf-2"])
+def test_engine_megakernel_step_fits_hbm(one_chip, monkeypatch, protocol,
+                                         bucket):
+    """The answer step the engine picks on a TPU above one chunk, for both
+    share algebras: the megakernel, whose lane-dense chunk roots keep the
+    step's HBM beside its arguments under 1 % of the DB view."""
+    monkeypatch.setenv(FORCE_BACKEND_ENV, "tpu")   # Mosaic, not interpret
+    cfg = PIRConfig(n_items=ROWS, item_bytes=32, protocol=protocol)
+    plan = heuristic_plan(cfg, bucket, backend="tpu")
+    assert (plan.expand, plan.tile_r) == ("fused-pallas", 2048)
+    proto = protocol_mod.get(cfg.protocol)
+    keys = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        proto.key_specs(cfg, bucket))
+    view = ((ROWS, WORDS), U32) if proto.db_view == "words" \
+        else ((ROWS, 32), jnp.int8)
+    db = jax.ShapeDtypeStruct(*view, sharding=one_chip)
+    c = jax.jit(lambda d, k: proto.answer_local(d, k, 0, cfg.log_n, plan)
+                ).lower(db, keys).compile()
+    assert _has_kernel(c)
+    m = c.memory_analysis()
+    assert m.peak_memory_in_bytes - m.argument_size_in_bytes < ROWS * 32 // 100
 
 
 @pytest.mark.parametrize("item_bytes,fits", [(2048, True), (2560, False)])

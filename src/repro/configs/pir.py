@@ -18,6 +18,13 @@ PIR_2G = PIRConfig(n_items=1 << 26, item_bytes=32)
 PIR_4G = PIRConfig(n_items=1 << 27, item_bytes=32)
 PIR_8G = PIRConfig(n_items=1 << 28, item_bytes=32)
 
+# the 32 B records at the size of a public credential corpus (Have I Been
+# Pwned's Pwned Passwords v8, 847,223,402 hashes, padded to the 2^30 GGM
+# domain): 32 GiB, which no single chip holds; served row-sharded over a
+# 1 x 4 mesh, 8 GiB per chip (credential checking, Li et al., CCS 2019)
+PIR_32G_4CHIP = PIRConfig(n_items=1 << 30, item_bytes=32,
+                          protocol="xor-dpf-2")
+
 # additive-share protocol (the MXU batched-matmul path, beyond-paper)
 PIR_1G_ADD = PIRConfig(n_items=1 << 25, item_bytes=32,
                        protocol="additive-dpf-2")
@@ -80,6 +87,7 @@ PIR_CONFIGS = {
     "pir-2g": PIR_2G,
     "pir-4g": PIR_4G,
     "pir-8g": PIR_8G,
+    "pir-32g-4chip": PIR_32G_4CHIP,
     "pir-1g-add": PIR_1G_ADD,
     "pir-1g-k3": PIR_1G_K3,
     "pir-1g-lwe": PIR_1G_LWE,

@@ -10,7 +10,9 @@ Placement (DESIGN.md §8.2)
     ``jnp.asarray(db_words)`` then ``device_put`` per party — copied the
     whole DB once per party before it ever reached a device). Layout is
     the paper's linear sharding: rows split over the ``model`` axis,
-    replicated across cluster (``data``/``pod``) axes.
+    replicated across cluster (``data``/``pod``) axes. The placement,
+    up to the arrays being on the devices, is the profiler span
+    ``pir.db_place``.
 
 Views (DESIGN.md §8.1)
     Protocols declare the view they contract against
@@ -65,6 +67,15 @@ from repro.launch.mesh import pir_shard_axis
 
 #: most rows a derived view is packed in at once (see ``_derive``)
 _PACK_ROWS = 1 << 20
+
+
+def _place_span(rows: int, nbytes: int,
+                shards: int) -> jax.profiler.TraceAnnotation:
+    """Profiler span ``pir.db_place`` over one placement of the word store:
+    its ``rows``, ``bytes`` and the ``shards`` it is split into."""
+    return jax.profiler.TraceAnnotation(
+        "pir.db_place", rows=int(rows), bytes=int(nbytes),
+        shards=int(shards))
 
 
 @dataclass
@@ -173,10 +184,14 @@ class ShardedDatabase:
         return NamedSharding(self.mesh, self._row_spec)
 
     def _place(self, host_words: np.ndarray) -> jax.Array:
-        """Chunked per-shard placement of the canonical word store."""
-        arr = jax.make_array_from_callback(
-            self.spec.view_shape("words"), self.sharding("words"),
-            lambda idx: host_words[idx])   # numpy view per device chunk
+        """Chunked per-shard placement of the canonical word store, up to
+        the arrays being on the devices: the profiler span
+        ``pir.db_place`` (``rows``, ``bytes``, ``shards``)."""
+        with _place_span(host_words.shape[0], host_words.nbytes,
+                         self.n_shards):
+            arr = jax.block_until_ready(jax.make_array_from_callback(
+                self.spec.view_shape("words"), self.sharding("words"),
+                lambda idx: host_words[idx]))   # numpy view per device chunk
         self.stats.n_full_placements += 1
         self.stats.preload_h2d_bytes += host_words.nbytes
         return arr

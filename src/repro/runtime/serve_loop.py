@@ -797,7 +797,8 @@ class MultiServerPIR:
                  protocol: Optional[PIRProtocol] = None,
                  client_rng: Optional[np.random.Generator] = None,
                  default_deadline_s: Optional[float] = None,
-                 chaos=None, chaos_scope: Optional[str] = None):
+                 chaos=None, chaos_scope: Optional[str] = None,
+                 collective: str = "gather"):
         self.cfg = cfg
         self.protocol = (protocol if protocol is not None
                          else protocol_mod.for_config(cfg))
@@ -814,7 +815,7 @@ class MultiServerPIR:
         self.servers = [
             PIRServer(party=b, database=self.db, cfg=cfg, mesh=mesh,
                       n_queries=n_queries, path=path, buckets=buckets,
-                      protocol=self.protocol)
+                      protocol=self.protocol, collective=collective)
             for b in range(self.n_parties)
         ]
         # key material (DPF keys, xor-dpf-k mask seeds) must not be

@@ -9,20 +9,24 @@ Pallas kernel is in the program (``tpu_custom_call``). The engine's
 megakernel answer steps, at buckets 1, 2 and 4, also check the step's HBM
 beside its arguments against 1 % of the DB view. Two megakernel
 cases sit on either side of the engine's VMEM footprint model's 16 MiB
-bound and check the model against what the compiler accepts. Nothing is
-executed, so nothing here says anything about results or times.
+bound and check the model against what the compiler accepts. One step
+is compiled sharded over a described 1 x 4 mesh at 2^30 rows
+(``pir-32g-4chip``). Nothing is executed, so nothing here says anything
+about results or times.
 """
 import os
 
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.config import PIRConfig
-from repro.configs.pir import PIR_1G_LWE
+from repro.configs.pir import PIR_1G_LWE, PIR_32G_4CHIP
 from repro.core import protocol as protocol_mod
+from repro.core.server import BucketedServeFns
 from repro.engine.backend import FORCE_BACKEND_ENV
 from repro.engine.kernels import ProblemShape, get_kernel
 from repro.engine.tuner import heuristic_plan
@@ -184,3 +188,34 @@ def test_fused_xor_vmem_model_matches_compiler(one_chip, item_bytes, fits):
     else:
         with pytest.raises(Exception, match="vmem"):
             _compile(_fused_xor(tile, clog), one_chip, *shapes)
+
+
+def test_sharded_step_over_four_chips_keeps_one_copy_of_its_shard(
+        topo, monkeypatch):
+    """``pir-32g-4chip``'s bucket-1 serve step, the database row-sharded
+    over a described 1 x 4 v5e mesh (2^28 rows, 8 GiB per chip): the
+    heuristic megakernel at a shard offset and the XOR reduce across the
+    chips. The kernel reads the shard's resident layout transposed as a
+    bitcast, so the step holds no second copy of the shard: its HBM
+    beside its arguments stays under 1 % of the shard."""
+    cfg = PIR_32G_4CHIP
+    monkeypatch.setenv(FORCE_BACKEND_ENV, "tpu")   # Mosaic, not interpret
+    mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 4),
+                ("data", "model"))
+    serve = BucketedServeFns(cfg, mesh, buckets=(1,), path=None)
+    fns, jitted = serve.fns_for(1)
+    assert (fns.plan.expand, fns.plan.collective) == ("fused-pallas",
+                                                      "gather")
+    keys = serve.protocol.key_specs(cfg, 1)
+    keys = jax.tree_util.tree_map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        keys, fns.key_shardings(keys))
+    db = jax.ShapeDtypeStruct((cfg.n_items, WORDS), U32,
+                              sharding=fns.db_sharding)
+    c = jitted.lower(db, keys).compile()
+    text = c.as_text()
+    assert _has_kernel(c) and "all-gather" in text
+    shard = cfg.n_items // 4 * cfg.item_bytes
+    m = c.memory_analysis()
+    assert m.argument_size_in_bytes >= shard
+    assert m.peak_memory_in_bytes - m.argument_size_in_bytes < shard // 100

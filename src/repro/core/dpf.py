@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.crypto.chacha import chacha_rows, ggm_double, prg_bits
+from repro.crypto.chacha import (LANE_BITS, WORD, chacha_block_packed,
+                                 chacha_rows, ggm_double, prg_bits)
 
 U32 = jnp.uint32
 
@@ -86,50 +87,60 @@ def gen_keys(
     payload_mod: int = 1 << 32,  # retained for API clarity; arithmetic is native u32 wrap
     rounds: int = 12,
 ) -> Tuple[DPFKey, DPFKey]:
-    """Gen(1^λ, α, β) -> (k0, k1). See module docstring."""
+    """Gen(1^λ, α, β) -> (k0, k1). See module docstring.
+
+    Runs on the host CPU, as the paper's client does: one path of the
+    tree is a ChaCha block per party per level (``chacha_block_packed``,
+    both parties in one pass), far too little work for a device program,
+    and a client's device programs would queue behind the serve step in
+    flight. The keys' leaves are ``np.ndarray``; the serve step's one
+    ``device_put`` per batch moves them.
+    """
     if not (0 <= alpha < (1 << log_n)):
         raise ValueError(f"alpha={alpha} out of domain 2^{log_n}")
-    root = [
-        jnp.asarray(rng.integers(0, 1 << 32, size=4, dtype=np.uint32)),
-        jnp.asarray(rng.integers(0, 1 << 32, size=4, dtype=np.uint32)),
-    ]
-    s = [root[0], root[1]]
-    t = [jnp.asarray(0, U32), jnp.asarray(1, U32)]
-    cw_seeds, cw_ts = [], []
+    root = [rng.integers(0, 1 << 32, size=4, dtype=np.uint32)
+            for _ in (0, 1)]
+    # both parties' seed words packed, party b in lane b (LANE_BITS * b)
+    hi = LANE_BITS
+    both = 1 | (1 << hi)
+    s = [int(root[0][w]) | (int(root[1][w]) << hi) for w in range(4)]
+    t = [0, 1]                                  # per party: control bit
+    cw_seed, cw_t = [], []
     for level in range(log_n):
         bit = (alpha >> (log_n - 1 - level)) & 1
-        exp = [ggm_double(s[b], rounds=rounds) for b in (0, 1)]
-        s_l = [e[0] for e in exp]
-        t_l = [e[1] for e in exp]
-        s_r = [e[2] for e in exp]
-        t_r = [e[3] for e in exp]
-        s_cw = (s_l[0] ^ s_l[1]) if bit else (s_r[0] ^ s_r[1])
-        t_cw_l = t_l[0] ^ t_l[1] ^ U32(bit) ^ U32(1)
-        t_cw_r = t_r[0] ^ t_r[1] ^ U32(bit)
-        cw_seeds.append(s_cw)
-        cw_ts.append(jnp.stack([t_cw_l, t_cw_r]))
-        new_s, new_t = [], []
-        for b in (0, 1):
-            keep_s = s_r[b] if bit else s_l[b]
-            keep_t = t_r[b] if bit else t_l[b]
-            keep_t_cw = t_cw_r if bit else t_cw_l
-            new_s.append(keep_s ^ (t[b] * s_cw))
-            new_t.append(keep_t ^ (t[b] & keep_t_cw))
-        s, t = new_s, new_t
-    cw_seed = jnp.stack(cw_seeds) if log_n else jnp.zeros((0, 4), U32)
-    cw_t = jnp.stack(cw_ts) if log_n else jnp.zeros((0, 2), U32)
+        blk = chacha_block_packed(s, 2, counter=0, rounds=rounds)
+        # words 0-3 / 8 are the left child's seed / bit, 4-7 / 9 the right's;
+        # a correction word is party 0's word XOR party 1's: the lanes folded
+        keep, lose = (4, 0) if bit else (0, 4)
+        s_cw = [(x ^ (x >> hi)) & WORD for x in blk[lose:lose + 4]]
+        t_l, t_r = ((x ^ (x >> hi)) & 1 for x in blk[8:10])
+        t_cw = [t_l ^ bit ^ 1, t_r ^ bit]
+        cw_seed.append(s_cw)
+        cw_t.append(t_cw)
+        corrected = (WORD if t[0] else 0) | ((WORD << hi) if t[1] else 0)
+        s = [x ^ ((cw * both) & corrected)
+             for x, cw in zip(blk[keep:keep + 4], s_cw)]
+        t = [((blk[8 + bit] >> (hi * b)) & 1) ^ (t[b] & t_cw[bit])
+             for b in (0, 1)]
 
     cw_final = None
     if payload is not None:
         # All payload arithmetic is native mod-2^32 uint32 wraparound; the
         # Z_256 byte mode masks with 0xFF at use time (256 | 2^32, so the
-        # congruence survives the reduction).
-        w = int(np.asarray(payload).shape[-1])
-        conv = [prg_bits(s[b], w, rounds=rounds) for b in (0, 1)]
-        beta = jnp.asarray(np.asarray(payload, dtype=np.uint32))
+        # congruence survives the reduction). The conversion words are
+        # prg_bits of each final seed: blocks at counters 1, 2, ...
+        beta = np.asarray(payload, dtype=np.uint32)
+        n_words = int(beta.shape[-1])
+        blocks = [chacha_block_packed(s, 2, counter=ctr, rounds=rounds)
+                  for ctr in range(1, 1 + -(-n_words // 16))]
+        conv = [np.array([(x >> (hi * b)) & WORD
+                          for blk in blocks for x in blk][:n_words],
+                         np.uint32) for b in (0, 1)]
         diff = beta - conv[0] + conv[1]
-        cw_final = jnp.where(t[1] == 1, (~diff) + U32(1), diff)
+        cw_final = (~diff) + np.uint32(1) if t[1] else diff
 
+    cw_seed = np.array(cw_seed, np.uint32).reshape(log_n, 4)
+    cw_t = np.array(cw_t, np.uint32).reshape(log_n, 2)
     return tuple(
         DPFKey(
             party=b,
@@ -334,8 +345,16 @@ def eval_bits_batch(keys: DPFKey, start_block, log_range) -> jax.Array:
 
 
 def stack_keys(keys) -> DPFKey:
-    """Stack a list of same-shape DPFKeys into one batched pytree."""
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *keys)
+    """Stack a list of same-shape keys into one batched pytree.
+
+    Host leaves (``gen_keys``' numpy arrays) stack on the host, so a batch
+    reaches the device in the serve step's one ``device_put``; device
+    leaves (the LWE ciphertexts of ``lwe-simple-1``) stack on the device.
+    """
+    def stack(*xs):
+        return (np.stack if isinstance(xs[0], np.ndarray) else jnp.stack)(xs)
+
+    return jax.tree_util.tree_map(stack, *keys)
 
 
 def n_queries_of(keys: DPFKey) -> int:
@@ -350,7 +369,8 @@ def pad_keys(keys: DPFKey, n_total: int) -> DPFKey:
     DPF key, so the serve step evaluates it like any other query and the
     extra answers are simply discarded by the caller (DESIGN.md §6 padding
     rule). Because each query's answer is an independent vmap lane, padding
-    can never corrupt the real answers.
+    can never corrupt the real answers. Runs on the host, like
+    :func:`stack_keys` for Gen's keys.
     """
     q = n_queries_of(keys)
     if n_total < q:
@@ -360,8 +380,7 @@ def pad_keys(keys: DPFKey, n_total: int) -> DPFKey:
     pad = n_total - q
 
     def pad_leaf(leaf):
-        reps = (pad,) + (1,) * (leaf.ndim - 1)
-        return jnp.concatenate([leaf, jnp.tile(leaf[-1:], reps)], axis=0)
+        return np.concatenate([leaf, np.repeat(leaf[-1:], pad, axis=0)])
 
     return jax.tree_util.tree_map(pad_leaf, keys)
 

@@ -112,14 +112,17 @@ def batch_queries(rng: np.random.Generator, indices: Sequence[int],
                  for p in range(n_parties))
 
 
-def reconstruct_xor(r0: jax.Array, r1: jax.Array) -> jax.Array:
-    """D[i] = r1 XOR r2 (Algorithm 1, client ⑦)."""
-    return jnp.bitwise_xor(r0, r1)
+def reconstruct_xor(r0, r1) -> np.ndarray:
+    """D[i] = r1 XOR r2 (Algorithm 1, client ⑦), on the host."""
+    from repro.core import protocol as protocol_mod
+    return protocol_mod.get("xor-dpf-2").reconstruct([r0, r1])
 
 
-def reconstruct_additive(r0: jax.Array, r1: jax.Array) -> jax.Array:
-    """D[i] bytes = (r0 + r1) mod 256 (int32 partial sums from the matmul)."""
-    return ((r0.astype(jnp.int32) + r1.astype(jnp.int32)) % 256).astype(jnp.uint8)
+def reconstruct_additive(r0, r1) -> np.ndarray:
+    """D[i] bytes = (r0 + r1) mod 256 (int32 partial sums from the matmul),
+    on the host."""
+    from repro.core import protocol as protocol_mod
+    return protocol_mod.get("additive-dpf-2").reconstruct([r0, r1])
 
 
 # ---------------------------------------------------------------------------

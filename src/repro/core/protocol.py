@@ -233,8 +233,9 @@ class PIRProtocol:
         """
         return self.query_gen(rng, index, cfg), None
 
-    def reconstruct(self, answers: Sequence[jax.Array]) -> jax.Array:
-        """Combine all parties' answer shares into the record."""
+    def reconstruct(self, answers: Sequence[np.ndarray]) -> np.ndarray:
+        """Combine all parties' answer shares into the record, on the host
+        (device arrays are fetched first)."""
         raise NotImplementedError
 
     def reconstruct_with(self, answers: Sequence[jax.Array], states, *,
@@ -419,9 +420,9 @@ class _XorProtocol(PIRProtocol):
         return _xor_reduce(partial_res, axis, n_shards, plan)
 
     def reconstruct(self, answers):
-        out = answers[0]
+        out = np.asarray(answers[0])
         for a in answers[1:]:
-            out = jnp.bitwise_xor(out, a)
+            out = np.bitwise_xor(out, np.asarray(a))
         return out
 
 
@@ -601,10 +602,10 @@ class AdditiveDpf2(PIRProtocol):
         return jax.lax.psum(partial_res, axis)   # additive: native psum
 
     def reconstruct(self, answers):
-        acc = answers[0].astype(jnp.int32)
+        acc = np.asarray(answers[0]).astype(np.int32)
         for a in answers[1:]:
-            acc = acc + a.astype(jnp.int32)
-        return (acc % 256).astype(jnp.uint8)
+            acc = acc + np.asarray(a).astype(np.int32)
+        return (acc % 256).astype(np.uint8)
 
 
 # ---------------------------------------------------------------------------
@@ -650,15 +651,15 @@ class XorDpfK(_XorProtocol):
         d0, d1 = dpf.gen_keys(rng, index, log_n, rounds=rounds)
         seeds = [rng.integers(0, 1 << 32, size=4, dtype=np.uint32)
                  for _ in range(k)]
-        zero_cw = jnp.zeros((log_n, 4), U32)
-        zero_t = jnp.zeros((log_n, 2), U32)
+        zero_cw = np.zeros((log_n, 4), np.uint32)
+        zero_t = np.zeros((log_n, 2), np.uint32)
 
         def mask_key(seed: np.ndarray) -> dpf.DPFKey:
             # zero correction words make eval_range a plain GGM PRG tree:
             # its leaf t-bits depend only on the seed, so both holders of a
             # seed derive identical (cancelling) masks.
             return dpf.DPFKey(party=0, log_n=log_n,
-                              root_seed=jnp.asarray(seed),
+                              root_seed=seed,
                               cw_seed=zero_cw, cw_t=zero_t,
                               cw_final=None, rounds=rounds)
 

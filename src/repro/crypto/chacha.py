@@ -24,6 +24,11 @@ seed yields 512 bits; the DPF consumes:
   out[0:4]  -> left child seed      out[4:8]  -> right child seed
   out[8]&1  -> left control bit     out[9]&1  -> right control bit
   out[10:]  -> payload-conversion words (additive modes)
+
+The servers expand their trees on the device (``chacha_block``,
+``chacha_rows``). The client's Gen walks one path, a block per party per
+level, which is too little work to pay for a device program: it runs the
+same stream on the host CPU (``chacha_block_packed``).
 """
 from __future__ import annotations
 
@@ -36,6 +41,9 @@ from jax import lax
 
 # "expa nd 3 2-by te k" — the standard ChaCha constants.
 SIGMA = np.array([0x61707865, 0x3320646E, 0x79622D32, 0x6B206574], dtype=np.uint32)
+
+#: state words 13-15, after the block counter in word 12
+NONCE = (0x5049522D, 0x494D5049, 0x52212121)
 
 PRG_ROUNDS = {"chacha8": 8, "chacha12": 12, "chacha20": 20}
 
@@ -93,8 +101,7 @@ def chacha_block(key4: jax.Array, *, counter: int = 0, rounds: int = 12) -> jax.
     batch = key4.shape[:-1]
     const = jnp.broadcast_to(jnp.asarray(SIGMA), batch + (4,))
     ctr = jnp.broadcast_to(
-        jnp.asarray([counter & 0xFFFFFFFF, 0x5049522D, 0x494D5049, 0x52212121],
-                    dtype=jnp.uint32),
+        jnp.asarray([counter & 0xFFFFFFFF, *NONCE], dtype=jnp.uint32),
         batch + (4,),
     )
     state = jnp.concatenate([const, key4, key4, ctr], axis=-1)
@@ -123,7 +130,7 @@ def chacha_rows(seed_rows, counter: int = 0, rounds: int = 12):
     """
     shape = seed_rows[0].shape
     const = [jnp.full(shape, np.uint32(c)) for c in SIGMA]
-    ctr_words = [counter & 0xFFFFFFFF, 0x5049522D, 0x494D5049, 0x52212121]
+    ctr_words = [counter & 0xFFFFFFFF, *NONCE]
     ctr = [jnp.full(shape, np.uint32(c)) for c in ctr_words]
     state = const + list(seed_rows) + list(seed_rows) + ctr
     # Rolled double rounds, as in chacha_block: callers like the fused
@@ -135,6 +142,63 @@ def chacha_rows(seed_rows, counter: int = 0, rounds: int = 12):
                           lambda _, xs: tuple(_double_round(list(xs))),
                           tuple(state))
     return [lax.add(xi, si) for xi, si in zip(x, state)]
+
+
+#: lane width of the host ChaCha's packed integers: lane ``i`` of a packed
+#: word holds one key's copy of that word in bits ``64 i`` to ``64 i + 31``
+LANE_BITS = 64
+WORD = 0xFFFFFFFF
+
+
+def _quarter_host(a, b, c, d, m):
+    """:func:`_quarter` on packed integers; ``m`` keeps each lane's low 32
+    bits. A rotate's two shifts spill only into the zero bits between
+    lanes, which the mask clears, as it clears an add's carry."""
+    a = (a + b) & m
+    d ^= a
+    d = ((d << 16) | (d >> 16)) & m
+    c = (c + d) & m
+    b ^= c
+    b = ((b << 12) | (b >> 20)) & m
+    a = (a + b) & m
+    d ^= a
+    d = ((d << 8) | (d >> 24)) & m
+    c = (c + d) & m
+    b ^= c
+    b = ((b << 7) | (b >> 25)) & m
+    return a, b, c, d
+
+
+def chacha_block_packed(key_words, n_lanes: int, *, counter: int = 0,
+                        rounds: int = 12):
+    """:func:`chacha_block` on the host CPU for ``n_lanes`` keys at once.
+
+    key_words: the 4 key words, each a plain integer holding every key's
+    copy of that word in its lane (``LANE_BITS``). Returns the block's 16
+    words packed the same way: one pass of the rounds serves every lane.
+    The client's Gen keeps its two parties' seeds packed from level to
+    level (``core/dpf.py gen_keys``).
+    """
+    if rounds % 2:
+        raise ValueError("rounds must be even")
+    ones = sum(1 << (LANE_BITS * i) for i in range(n_lanes))
+    m = WORD * ones
+    init = ([int(c) * ones for c in SIGMA] + list(key_words) * 2
+            + [c * ones for c in (counter & WORD, *NONCE)])
+    (x0, x1, x2, x3, x4, x5, x6, x7,
+     x8, x9, x10, x11, x12, x13, x14, x15) = init
+    for _ in range(rounds // 2):
+        x0, x4, x8, x12 = _quarter_host(x0, x4, x8, x12, m)
+        x1, x5, x9, x13 = _quarter_host(x1, x5, x9, x13, m)
+        x2, x6, x10, x14 = _quarter_host(x2, x6, x10, x14, m)
+        x3, x7, x11, x15 = _quarter_host(x3, x7, x11, x15, m)
+        x0, x5, x10, x15 = _quarter_host(x0, x5, x10, x15, m)
+        x1, x6, x11, x12 = _quarter_host(x1, x6, x11, x12, m)
+        x2, x7, x8, x13 = _quarter_host(x2, x7, x8, x13, m)
+        x3, x4, x9, x14 = _quarter_host(x3, x4, x9, x14, m)
+    return [(x + s) & m for x, s in zip(
+        (x0, x1, x2, x3, x4, x5, x6, x7,
+         x8, x9, x10, x11, x12, x13, x14, x15), init)]
 
 
 def ggm_double(seeds: jax.Array, *, rounds: int = 12):

@@ -880,13 +880,16 @@ class MultiServerPIR:
             answers, _ = raw
             jax.block_until_ready(answers)
             with _reconstruct_span(answers[0].shape[0], n):
-                # reconstruct_with routes through checksum verification
-                # when cfg.checksum — a corrupted share raises
+                # the shares are fetched, sliced and combined on the host:
+                # device programs here would queue behind the next batch's
+                # step, already dispatched, and answer this batch a step
+                # late. reconstruct_with routes through checksum
+                # verification when cfg.checksum — a corrupted share raises
                 # IntegrityError here (failing this batch's futures)
                 # instead of resolving garbage
-                rec = np.asarray(proto.reconstruct_with(
-                    [r[:n] for r in answers], [None] * n, cfg=cfg))
-                return list(rec)
+                shares = [np.asarray(r)[:n] for r in answers]
+                return list(proto.reconstruct_with(shares, [None] * n,
+                                                   cfg=cfg))
 
         return QueryScheduler(
             collate=collate, stage=stage, dispatch=dispatch,

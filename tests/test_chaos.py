@@ -613,7 +613,12 @@ def test_random_fault_plans_never_lose_or_corrupt_answers(seed):
             assert f.epoch is not None
             assert f.epoch <= router.published_epoch
     assert 0 <= s.min_epoch <= router.published_epoch
-    if "corrupt" in injector.fired_actions("replica.serve_step"):
-        # every fired corruption became a counted IntegrityError — the
-        # "never silent" half of the contract
+    visits = {a: {(f.target, f.visit) for f in injector.fired
+                  if f.seam == "replica.serve_step" and f.action == a}
+              for a in ("corrupt", "kill")}
+    if visits["corrupt"] - visits["kill"]:
+        # every applied corruption became a counted IntegrityError — the
+        # "never silent" half of the contract. A kill on the same visit
+        # raises before any share is touched, so that corruption never
+        # reaches a client.
         assert router.integrity_failures >= 1

@@ -5,12 +5,14 @@ uses AES-128 via AES-NI); the DPF construction is PRF-agnostic and the
 repo's production PRG is the ChaCha ARX permutation (DESIGN.md §2).
 """
 import numpy as np
+import pytest
 from _prop import given, settings, st
 
 import jax.numpy as jnp
 
 from repro.crypto.aes_ref import aes_ggm_double, encrypt_block
-from repro.crypto.chacha import chacha_block, ggm_double, prg_bits
+from repro.crypto.chacha import (LANE_BITS, chacha_block, chacha_block_packed,
+                                 ggm_double, prg_bits)
 
 
 def test_aes128_fips197_vector():
@@ -31,6 +33,28 @@ def test_aes_ggm_double_deterministic_and_split():
     assert t_l == t_l2
     assert not np.array_equal(s_l, s_r)     # children differ
     assert t_l in (0, 1) and t_r in (0, 1)
+
+
+@pytest.mark.parametrize("counter", [0, 1, 2])
+@pytest.mark.parametrize("rounds", [8, 12, 20])
+def test_chacha_block_packed_matches_device_stream(rounds, counter):
+    """The client's host ChaCha is the servers' stream, bit for bit, for
+    one key alone and for several packed side by side into one pass."""
+    keys = np.random.default_rng(rounds * 10 + counter).integers(
+        0, 1 << 32, size=(5, 4), dtype=np.uint32)
+    want = np.asarray(chacha_block(jnp.asarray(keys), counter=counter,
+                                   rounds=rounds))
+    shifts = [LANE_BITS * i for i in range(len(keys))]
+    packed = [sum(int(k[w]) << s for s, k in zip(shifts, keys))
+              for w in range(4)]
+    out = chacha_block_packed(packed, len(keys), counter=counter,
+                              rounds=rounds)
+    got = np.array([[(x >> s) & 0xFFFFFFFF for x in out] for s in shifts],
+                   np.uint32)
+    np.testing.assert_array_equal(got, want)
+    one = chacha_block_packed([int(w) for w in keys[0]], 1,
+                              counter=counter, rounds=rounds)
+    np.testing.assert_array_equal(np.array(one, np.uint32), want[0])
 
 
 def test_chacha_block_shape_and_determinism():

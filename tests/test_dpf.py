@@ -8,13 +8,16 @@ Invariants under test (the cryptographic contract of core/dpf.py):
   P5  each key alone is (statistically) uninformative: leaf bits of a
       single party are ~balanced — a smoke-level distinguisher check
 """
+import hashlib
+
 import numpy as np
 import pytest
 from _prop import given, settings, st
 
 import jax.numpy as jnp
 
-from repro.core import dpf
+from repro.config import PIRConfig
+from repro.core import dpf, protocol
 
 RNG = np.random.default_rng(7)
 
@@ -121,3 +124,57 @@ def test_batched_eval_matches_single():
 def test_invalid_alpha_raises():
     with pytest.raises(ValueError):
         _keys(1 << 5, 5)
+
+
+def _key_digest(keys) -> str:
+    """SHA-256 over every key's leaves: dtype, shape and bytes."""
+    h = hashlib.sha256()
+    for key in keys:
+        for leaf in (key.root_seed, key.cw_seed, key.cw_t, key.cw_final):
+            if leaf is None:
+                h.update(b"none")
+                continue
+            a = np.asarray(leaf)
+            h.update(str((a.dtype.str, a.shape)).encode())
+            h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def _rng(seed):
+    return np.random.default_rng(seed)
+
+
+#: digests of the keys that the device Gen (a per-level loop of jnp ops
+#: over ``chacha_block``) made for these rng seeds; the host Gen must make
+#: the same bytes, so a key from either is served alike
+GOLDEN_KEYS = {
+    "xor-6": (
+        lambda: dpf.gen_keys(_rng(101), 37, 6),
+        "cac957d80a4ee9aa092b44437c491da4a1d7d48bebdda2edd044e9aad58b5079"),
+    "xor-25": (
+        lambda: dpf.gen_keys(_rng(202), 31_415_926, 25),
+        "e443b2e3101b6d46c1d4482fb70be9edca2e931da9a4684cd4b6154b244ccc01"),
+    "xor-6-chacha8": (
+        lambda: dpf.gen_keys(_rng(404), 63, 6, rounds=8),
+        "61608011f9480fb0c318103936462bc2fcd753dd12451a4fc57ba984b59392f7"),
+    "additive-20-words": (
+        lambda: dpf.gen_keys(_rng(303), 5, 10,
+                             payload=np.arange(1, 21, dtype=np.uint32)),
+        "19b1588edd1e10daede47efff3d4ba519f0b52dc789025e758914d8d723fb0df"),
+    "additive-dpf-2": (
+        lambda: protocol.get("additive-dpf-2").query_gen(
+            _rng(505), 4000, PIRConfig(n_items=1 << 12, item_bytes=32,
+                                       protocol="additive-dpf-2")),
+        "e8e2db34e7d28857156afca590b562ffbb099093ad05ff167b875cd8c6c4a8fd"),
+    "xor-dpf-k3": (
+        lambda: protocol.get("xor-dpf-k").query_gen(
+            _rng(606), 200, PIRConfig(n_items=1 << 8, item_bytes=32,
+                                      protocol="xor-dpf-k", n_servers=3)),
+        "11b8ebb08a876164598116fc2a6a5ff772f0c969e9fb70838760fdc1517d138c"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GOLDEN_KEYS))
+def test_gen_keys_match_golden_bytes(case):
+    make, digest = GOLDEN_KEYS[case]
+    assert _key_digest(make()) == digest

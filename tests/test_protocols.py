@@ -58,6 +58,38 @@ def test_registry_names():
     assert PIRConfig(n_items=N, protocol="lwe-simple-1").share_kind == "lwe"
 
 
+@pytest.mark.parametrize("name", ["xor-dpf-2", "additive-dpf-2",
+                                  "xor-dpf-k"])
+def test_client_work_stays_on_host(name):
+    """Gen, key batching and reconstruction move nothing to or from a
+    device and hand back numpy arrays: no client program can queue on
+    the chip behind a serve step."""
+    cfg = PIRConfig(n_items=N, item_bytes=32, protocol=name, n_servers=3)
+    proto = get(name)
+    rng = np.random.default_rng(11)
+    tail, dtype = proto.record_struct(cfg)
+    with jax.transfer_guard("disallow"):
+        queries = [proto.query_gen(rng, i, cfg) for i in (1, 2, 60)]
+        for p in range(proto.n_parties(cfg)):
+            batch = proto.pad(dpf.stack_keys([q[p] for q in queries]), 4)
+            assert dpf.n_queries_of(batch) == 4
+            leaves = jax.tree_util.tree_leaves(batch)
+            assert leaves and all(isinstance(x, np.ndarray)
+                                  for x in leaves)
+        if proto.share_kind == "additive":
+            shares = [rng.integers(-2**31, 2**31, size=(3,) + tail,
+                                   dtype=np.int32) for _ in range(2)]
+            want = ((shares[0].astype(np.int64) + shares[1]) % 256
+                    ).astype(np.uint8)
+        else:
+            shares = [rng.integers(0, 2**32, size=(3,) + tail,
+                                   dtype=np.uint32) for _ in range(3)]
+            want = shares[0] ^ shares[1] ^ shares[2]
+        rec = proto.reconstruct_with(shares, [None] * 3, cfg=cfg)
+    assert isinstance(rec, np.ndarray) and rec.dtype == dtype
+    np.testing.assert_array_equal(rec, want)
+
+
 def test_config_protocol_defaults_and_mode_shim():
     import dataclasses
     cfg = PIRConfig(n_items=N)

@@ -26,7 +26,7 @@ def main():
     rng = np.random.default_rng(0)
     db_host = pir.make_database(rng, cfg.n_items, cfg.item_bytes)
 
-    system = BatchPIR(db_host, cfg, make_local_mesh(), path="fused")
+    system = BatchPIR(db_host, cfg, make_local_mesh())
     bdb = system.db
     print(f"DB: {cfg.n_items} records x {cfg.item_bytes} B -> "
           f"m={cfg.batch_m} batch: B={bdb.n_buckets} buckets x "

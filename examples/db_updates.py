@@ -28,8 +28,8 @@ def main():
     # one bucket keeps this demo to one XLA compile per party (~40-90 s
     # each on a 1-core CPU container); all 3 parties share ONE placed
     # ShardedDatabase — the DB is public, only key material is per-party
-    system = MultiServerPIR(db_host, cfg, make_local_mesh(), path="fused",
-                            n_queries=2, buckets=(2,))
+    system = MultiServerPIR(db_host, cfg, make_local_mesh(), n_queries=2,
+                            buckets=(2,))
     print(f"DB: {cfg.n_items} records x {cfg.item_bytes} B; "
           f"protocol={cfg.protocol} ({system.n_parties} parties, "
           f"one shared placement: "

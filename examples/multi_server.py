@@ -34,8 +34,8 @@ def main():
 
     # one bucket keeps this demo to one XLA compile per party (~40 s each
     # on a 1-core CPU container); ragged traffic pads up to it
-    system = MultiServerPIR(db, cfg, make_local_mesh(), path="fused",
-                            n_queries=4, buckets=(4,))
+    system = MultiServerPIR(db, cfg, make_local_mesh(), n_queries=4,
+                            buckets=(4,))
     assert len(system.servers) == 3
 
     secret_indices = [7, 1234, 4000, cfg.n_items - 1]

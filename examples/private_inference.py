@@ -65,8 +65,7 @@ def main(argv=None):
     pir_cfg = PIRConfig(n_items=v_pow2, item_bytes=cfg.d_model * 2,
                         batch_queries=args.streams)
     mesh = make_local_mesh()
-    servers = TwoServerPIR(words, pir_cfg, mesh, path="fused",
-                           n_queries=args.streams)
+    servers = TwoServerPIR(words, pir_cfg, mesh, n_queries=args.streams)
 
     B = args.streams
     prompt = np.asarray([[3 + i, 17, 41] for i in range(B)], np.int32)

@@ -22,11 +22,12 @@ def main():
     print(f"DB: {cfg.n_items} records x {cfg.item_bytes} B "
           f"({cfg.db_bytes / (1 << 20):.0f} MiB)")
 
-    # Two servers, each holding a full replica; the 'fused' path runs DPF
-    # evaluation and the select-XOR scan in one pass (IM-PIR's offload,
+    # Two servers, each holding a full replica. The engine picks the kernel
+    # plan per batch bucket: here (2^14 rows, CPU) the fused path, which runs
+    # DPF evaluation and the select-XOR scan in one pass (IM-PIR's offload,
     # with the GGM tree on-device — see DESIGN.md §2).
     mesh = make_local_mesh()
-    system = TwoServerPIR(db, cfg, mesh, path="fused", n_queries=4)
+    system = TwoServerPIR(db, cfg, mesh, n_queries=4)
 
     secret_indices = [7, 4242, 9000, cfg.n_items - 1]
     print(f"querying indices {secret_indices} (servers never see these)")

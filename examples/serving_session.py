@@ -42,8 +42,8 @@ def main():
                            cfg.item_bytes)
     # one bucket keeps this demo to a single XLA compile per party (~40 s
     # on a 1-core CPU container); ragged traffic pads up to it
-    system = TwoServerPIR(db, cfg, make_local_mesh(), path="fused",
-                          n_queries=4, buckets=(4,))
+    system = TwoServerPIR(db, cfg, make_local_mesh(), n_queries=4,
+                          buckets=(4,))
     print(f"DB: {cfg.n_items} records x {cfg.item_bytes} B; "
           f"buckets={system.servers[0].buckets}")
 

@@ -13,7 +13,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
 
-python -m compileall -q src benchmarks examples scripts tests
+python -m compileall -q src examples scripts tests
 python -m pytest -q
 # smoke gate: one compiled serve step per party (~1 min each on the dev
 # container), full client -> two servers -> reconstruct round trip

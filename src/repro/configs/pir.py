@@ -59,15 +59,15 @@ PIR_SMOKE_UPD = PIRConfig(n_items=1 << 10, item_bytes=32,
 PIR_SMOKE_LWE = PIRConfig(n_items=1 << 14, item_bytes=32,
                           protocol="lwe-simple-1", n_servers=1,
                           batch_queries=4)
-# replica-plane smoke (examples/replicas.py, benchmarks/bench_replicas.py):
+# replica-plane smoke (examples/replicas.py, tests):
 # every replica pays its own serve-step compile at construction, so the
 # fleet demos run the cheap LWE step at 2^12 records to keep N compiles
 # inside the CI gate's budget
 PIR_SMOKE_REPL = PIRConfig(n_items=1 << 12, item_bytes=32,
                            protocol="lwe-simple-1", n_servers=1,
                            batch_queries=4)
-# verified-reconstruction smoke (python -m repro.chaos --smoke,
-# benchmarks/bench_chaos.py): replica scale + the per-row checksum column,
+# verified-reconstruction smoke (python -m repro.chaos --smoke, tests):
+# replica scale + the per-row checksum column,
 # so chaos-corrupted shares surface as IntegrityError instead of garbage
 PIR_SMOKE_CHK = PIRConfig(n_items=1 << 12, item_bytes=32,
                           protocol="lwe-simple-1", n_servers=1,

@@ -15,8 +15,8 @@ replaces it (DESIGN.md §7):
                  (materialize selection bits vs fused chunked expand+scan),
                  which scan kernel (pure-jnp oracle vs the Pallas
                  ``dpxor``/``pir_matmul`` bodies), and which aggregation
-                 collective. Picked per (db size, batch bucket, backend) by
-                 :func:`plan_for`, or forced via the legacy ``path`` strings.
+                 collective. The engine plane picks one per (db size, batch
+                 bucket, backend): ``repro.engine.resolve``.
 
 Registered protocols
 --------------------
@@ -45,7 +45,7 @@ lwe-simple-1    single-server SimplePIR-style LWE PIR (beyond-paper,
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from functools import partial
 from typing import Dict, Optional, Sequence, Tuple
 
@@ -103,10 +103,10 @@ class ExecutionPlan:
     depth      fused-pallas: rotating DMA buffer count (2 = classic double
                buffer; other paths ignore it).
     provenance "heuristic" (rule-picked fallback) | "tuned" (measured
-               winner from the plan cache) | "forced" (legacy ``path=``
-               string). Excluded from equality/hashing: two plans that
-               execute identically compare equal regardless of how they
-               were chosen.
+               winner from the plan cache) | "warm" (recorded from a
+               peer, ``engine.record_plans``). Excluded from
+               equality/hashing: two plans that execute identically
+               compare equal regardless of how they were chosen.
     """
     expand: str = "materialize"
     scan: str = "jnp"
@@ -121,72 +121,6 @@ class ExecutionPlan:
     @property
     def name(self) -> str:
         return f"{self.expand}/{self.scan}"
-
-    def describe(self) -> Dict[str, object]:
-        """Reporting form (dry-run JSONL, ``lower()`` provenance)."""
-        return {"name": self.name, "expand": self.expand, "scan": self.scan,
-                "chunk_log": self.chunk_log, "collective": self.collective,
-                "tile_r": self.tile_r, "tile_q": self.tile_q,
-                "tile_l": self.tile_l, "depth": self.depth,
-                "provenance": self.provenance}
-
-
-#: legacy ``path=`` strings -> plans (the pre-registry server API).
-PATH_PLANS: Dict[str, ExecutionPlan] = {
-    "baseline": ExecutionPlan(expand="materialize", scan="jnp"),
-    "fused": ExecutionPlan(expand="fused", scan="jnp"),
-    "matmul": ExecutionPlan(expand="materialize", scan="jnp"),
-    "pallas": ExecutionPlan(expand="materialize", scan="pallas"),
-    "fused-pallas": ExecutionPlan(expand="fused-pallas", scan="pallas"),
-}
-
-
-def resolve_plan(path: Optional[str], cfg: PIRConfig, n_queries: int, *,
-                 chunk_log: int = 12, collective: str = "gather"
-                 ) -> ExecutionPlan:
-    """A plan from a legacy path string, or the engine when path is None.
-
-    ``path=None/"auto"`` delegates to the engine plane (DESIGN.md §9):
-    plan-cache hit → measured tuned plan; miss → the deterministic
-    heuristic (:func:`plan_for`). Legacy strings stay forced plans
-    (provenance ``"forced"``); additive protocols pin the GEMM reduction
-    tile to its pre-engine kernel default.
-    """
-    if path is None or path == "auto":
-        from repro import engine
-        return engine.resolve(cfg, n_queries, chunk_log=chunk_log,
-                              collective=collective)
-    if path not in PATH_PLANS:
-        raise ValueError(f"unknown path {path!r}; "
-                         f"expected one of {sorted(PATH_PLANS)} or 'auto'")
-    plan = replace(PATH_PLANS[path], chunk_log=chunk_log,
-                   collective=collective, provenance="forced")
-    if get(cfg.protocol).share_kind in ("additive", "lwe"):
-        from repro.engine.kernels import GEMM_TILE_R_DEFAULT
-        plan = replace(plan, tile_r=GEMM_TILE_R_DEFAULT)
-    return plan
-
-
-def plan_for(cfg: PIRConfig, n_queries: int, *,
-             backend: Optional[str] = None,
-             chunk_log: int = 12) -> ExecutionPlan:
-    """Pick the kernel path per (db size, batch bucket, backend).
-
-    Since the engine plane this is a thin alias of
-    ``engine.heuristic_plan`` — the deterministic fallback the plan cache
-    misses to. The selection rules (DESIGN.md §7.3), in short: the
-    selection vector is materialized only while the DB fits one chunk
-    (db <= 2^chunk_log rows — a global-size rule: a sharded mesh divides
-    the per-device rows further, only making materialization cheaper);
-    past that, on a TPU, XOR and additive protocols take the
-    ``fused-pallas`` megakernel where a tile fits its VMEM; elsewhere XOR
-    takes the fused chunked expand+scan and additive the materialized
-    GEMM (on CPU the Pallas bodies would execute in interpret mode); LWE
-    always contracts with XLA's int32 dot.
-    """
-    from repro.engine.tuner import heuristic_plan
-    return heuristic_plan(cfg, n_queries, backend=backend,
-                          chunk_log=chunk_log)
 
 
 # ---------------------------------------------------------------------------

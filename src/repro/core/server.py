@@ -22,27 +22,27 @@ per-shard answer contraction (``answer_local``), the cross-shard reduction
 algebra (``reduce`` — XOR all-reduce for the XOR schemes, psum for
 additive), and the key pytree shapes (``key_specs``); an ``ExecutionPlan``
 picks the kernel path (materialized vs fused expansion, jnp oracle vs the
-Pallas bodies, gather vs butterfly collective). The *database plane*
-(``db/``, DESIGN.md §8) owns what the data looks like and where it lives:
+Pallas bodies, gather vs butterfly collective), and the engine plane
+(``repro.engine.resolve``) picks that plan per batch bucket. The
+*database plane* (``db/``, DESIGN.md §8) owns what the data looks like and where it lives:
 ``DatabaseSpec`` centralizes shape/packing math, ``ShardedDatabase`` owns
 chunked mesh placement, the per-protocol views (u32 words / int8 bytes —
 declared via ``PIRProtocol.db_view``) and epoched online updates. This
 module only owns the mesh plumbing: shard_map specs and the
-lower-once-per-bucket compile cache. Legacy
-``path="baseline"|"fused"|"matmul"`` strings map onto plans via
-``protocol.resolve_plan``.
+lower-once-per-bucket compile cache.
 """
 from __future__ import annotations
 
 import math
 from dataclasses import dataclass
-from typing import Callable, NamedTuple, Optional, Sequence, Tuple, Union
+from typing import Callable, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import engine
 from repro.config import PIRConfig
 from repro.core import dpf
 from repro.core import protocol as protocol_mod
@@ -68,20 +68,6 @@ def _axis_size(mesh, names) -> int:
     return n
 
 
-def key_specs(cfg: PIRConfig, n_queries: int, *, party: int = 0,
-              protocol: Optional[PIRProtocol] = None) -> dpf.DPFKey:
-    """ShapeDtypeStruct stand-ins for a batched key pytree (dry-run input).
-
-    Delegates to the config's protocol — key pytree shapes (payload
-    correction words, the k-server component axis) are scheme-defined.
-    ``party`` and the PRG round count are pytree *aux data*, so they must
-    match the real keys exactly for treedef-sensitive uses (e.g. the
-    per-bucket ``jit`` in_shardings).
-    """
-    proto = protocol if protocol is not None else protocol_mod.for_config(cfg)
-    return proto.key_specs(cfg, n_queries, party=party)
-
-
 def _key_pspec(keys_like, cluster: Tuple[str, ...]):
     """PartitionSpecs matching the batched-key pytree (batch axis sharded)."""
     def spec(leaf):
@@ -98,42 +84,11 @@ class ServeFns:
     (``ShardedDatabase.view(protocol.db_view)``) — never a raw host array.
     """
     serve: Callable            # (db_view, keys) -> per-query answer shares
-    mesh: jax.sharding.Mesh
     db_sharding: NamedSharding
-    cfg: PIRConfig
-    n_local_queries: int       # queries per cluster per step
     plan: ExecutionPlan
     protocol: PIRProtocol
     # batched-key pytree -> NamedSharding pytree (for async host staging)
     key_shardings: Optional[Callable] = None
-
-    def plan_report(self) -> dict:
-        """Provenance + predicted-bytes row for the resolved plan
-        (engine-plane reporting, DESIGN.md §9): the modeled HBM traffic of
-        one device's contraction — ``n_local_queries`` against its own
-        DB shard."""
-        from repro import engine
-        n_shards = _axis_size(self.mesh, _shard_axis(self.mesh))
-        return engine.plan_report(self.cfg, self.plan, self.n_local_queries,
-                                  n_shards=n_shards)
-
-
-class LoweredServe(NamedTuple):
-    """``PIRServer.lower`` result: the jax lowering plus plan provenance.
-
-    ``lowered`` keeps the full jax API (``.compile()``, ``.as_text()``);
-    ``plan``/``report`` surface which kernel path this bucket resolved to
-    and the engine's predicted step bytes (DESIGN.md §9).
-    """
-    lowered: object
-    plan: ExecutionPlan
-    report: dict
-
-    def compile(self):
-        return self.lowered.compile()
-
-    def as_text(self, *a, **k):
-        return self.lowered.as_text(*a, **k)
 
 
 def build_serve_fn(
@@ -141,31 +96,20 @@ def build_serve_fn(
     mesh: jax.sharding.Mesh,
     *,
     n_queries: int,
-    path: Optional[str] = "baseline",  # legacy plan names; None/"auto" selects
-    chunk_log: int = 12,               # fused: leaves per expand+scan chunk
-    collective: str = "gather",        # gather | butterfly
     protocol: Optional[PIRProtocol] = None,
     plan: Optional[ExecutionPlan] = None,
 ) -> ServeFns:
     """Build the sharded serve function for one step of ``n_queries``.
 
     The protocol defaults to the one named by ``cfg.protocol``; the plan
-    defaults to the legacy ``path`` mapping (or ``plan_for`` selection when
-    ``path`` is None/"auto"). No share-scheme branching happens here — the
-    protocol owns the contraction and reduction.
+    defaults to the engine's (``repro.engine.resolve``). ``plan=`` is the
+    one place a caller supplies a plan of its own. No share-scheme
+    branching happens here — the protocol owns the contraction and
+    reduction.
     """
     proto = protocol if protocol is not None else protocol_mod.for_config(cfg)
-    if path == "matmul" and proto.share_kind != "additive":
-        # the GEMM path contracts additive Z_256 shares; silently falling
-        # back to the XOR scan would mislabel benchmarks/tests
-        raise ValueError(
-            f"path='matmul' requires an additive protocol; "
-            f"{proto.name!r} is {proto.share_kind} — use "
-            f"protocol='additive-dpf-2'")
     if plan is None:
-        plan = protocol_mod.resolve_plan(path, cfg, n_queries,
-                                         chunk_log=chunk_log,
-                                         collective=collective)
+        plan = engine.resolve(cfg, n_queries)
     cluster = _cluster_axes(mesh)
     shard = _shard_axis(mesh)
     n_clusters = _axis_size(mesh, cluster)
@@ -207,10 +151,7 @@ def build_serve_fn(
 
     return ServeFns(
         serve=serve,
-        mesh=mesh,
         db_sharding=NamedSharding(mesh, db_spec),
-        cfg=cfg,
-        n_local_queries=n_queries // max(n_clusters, 1),
         plan=plan,
         protocol=proto,
         key_shardings=key_shardings,
@@ -253,19 +194,17 @@ class BucketedServeFns:
     Ragged traffic never recompiles: a batch of Q queries is padded up to
     the smallest bucket >= Q (``PIRProtocol.pad``) and answered by that
     bucket's cached ``jax.jit`` step. ``n_compiles`` counts cache misses so
-    tests/benches can assert reuse. When ``path`` is None/"auto", each
-    bucket's plan comes from the engine plane (plan-cache hit → measured
-    tuned plan, miss → the ``plan_for`` heuristic) — so e.g. small and
-    large buckets of the same server family may take different kernel
-    paths. Plan resolution happens HERE, once per bucket at build time
-    (``plan_for_bucket``); dispatch never touches the tuner or cache I/O.
+    tests/benches can assert reuse. Each bucket's plan comes from the
+    engine plane (plan-cache hit → measured tuned or warm plan, miss → the
+    heuristic) — so e.g. small and large buckets of the same server family
+    may take different kernel paths. Plan resolution happens HERE, once
+    per bucket at build time (``plan_for_bucket``); dispatch never touches
+    the tuner or cache I/O.
     """
 
     def __init__(self, cfg: PIRConfig, mesh: jax.sharding.Mesh, *,
-                 buckets: Sequence[int], path: Optional[str] = "baseline",
-                 collective: str = "gather", party: int = 0,
-                 protocol: Optional[PIRProtocol] = None,
-                 chunk_log: int = 12):
+                 buckets: Sequence[int], party: int = 0,
+                 protocol: Optional[PIRProtocol] = None):
         n_clusters = _axis_size(mesh, _cluster_axes(mesh))
         for b in buckets:
             if b % max(n_clusters, 1):
@@ -273,9 +212,6 @@ class BucketedServeFns:
                     f"bucket {b} not divisible by {n_clusters} clusters")
         self.cfg = cfg
         self.mesh = mesh
-        self.path = path
-        self.collective = collective
-        self.chunk_log = chunk_log
         self.party = party
         self.protocol = (protocol if protocol is not None
                          else protocol_mod.for_config(cfg))
@@ -288,18 +224,15 @@ class BucketedServeFns:
         return bucket_for(self.buckets, n)
 
     def plan_for_bucket(self, bucket: int) -> ExecutionPlan:
-        """The bucket's resolved plan — one engine/heuristic resolution per
-        bucket, cached, shared with the compiled step (``fns_for``)."""
+        """The bucket's resolved plan — one engine resolution per bucket,
+        cached, shared with the compiled step (``fns_for``)."""
         if bucket not in self._plans:
-            self._plans[bucket] = protocol_mod.resolve_plan(
-                self.path, self.cfg, bucket, chunk_log=self.chunk_log,
-                collective=self.collective)
+            self._plans[bucket] = engine.resolve(self.cfg, bucket)
         return self._plans[bucket]
 
     def plan_report(self) -> dict:
         """{bucket: plan provenance + predicted bytes} for every bucket —
         resolved without compiling anything (runtime/launch reporting)."""
-        from repro import engine
         n_shards = _axis_size(self.mesh, _shard_axis(self.mesh))
         n_clusters = max(_axis_size(self.mesh, _cluster_axes(self.mesh)), 1)
         return {b: engine.plan_report(self.cfg, self.plan_for_bucket(b),
@@ -309,8 +242,6 @@ class BucketedServeFns:
     def fns_for(self, bucket: int) -> Tuple[ServeFns, Callable]:
         if bucket not in self._cache:
             fns = build_serve_fn(self.cfg, self.mesh, n_queries=bucket,
-                                 path=self.path, collective=self.collective,
-                                 chunk_log=self.chunk_log,
                                  protocol=self.protocol,
                                  plan=self.plan_for_bucket(bucket))
             # explicit in_shardings: host-resident and pre-staged
@@ -399,8 +330,6 @@ class PIRServer:
         *,
         database: Optional[ShardedDatabase] = None,
         n_queries: int = 32,
-        path: Optional[str] = "baseline",
-        collective: str = "gather",
         buckets: Optional[Sequence[int]] = None,
         protocol: Optional[PIRProtocol] = None,
     ):
@@ -426,7 +355,6 @@ class PIRServer:
         self.party = party
         self.cfg = cfg
         self.mesh = mesh
-        self.path = path
         n_clusters = _axis_size(mesh, _cluster_axes(mesh))
         if buckets is None:
             buckets = default_buckets(n_clusters,
@@ -434,8 +362,7 @@ class PIRServer:
         if n_queries not in buckets:
             buckets = tuple(sorted(set(buckets) | {n_queries}))
         self.bucketed = BucketedServeFns(
-            cfg, mesh, buckets=buckets, path=path, collective=collective,
-            party=party, protocol=protocol)
+            cfg, mesh, buckets=buckets, party=party, protocol=protocol)
         self.protocol = self.bucketed.protocol
         self.n_queries = n_queries
         self.fns = self.bucketed.fns_for(n_queries)[0]
@@ -460,7 +387,7 @@ class PIRServer:
         return self.bucketed.stage(keys)
 
     def plan_report(self) -> dict:
-        """Per-bucket plan provenance (tuned vs heuristic vs forced) +
+        """Per-bucket plan provenance (tuned vs warm vs heuristic) +
         predicted step bytes — the engine plane's reporting surface."""
         return self.bucketed.plan_report()
 
@@ -475,18 +402,3 @@ class PIRServer:
         Returns exactly [Q, ...] answer shares.
         """
         return self.bucketed.answer(self.db, keys)
-
-    def lower(self, n_queries: int) -> "LoweredServe":
-        """Lower (no execution) against ShapeDtypeStructs — dry-run entry.
-
-        Returns the lowered artifact *with its plan*: dry-run consumers
-        report which kernel path a bucket compiled to and whether it was
-        ``tuned`` (plan-cache hit), ``heuristic``, or ``forced``
-        (legacy ``path=``), next to the HLO cost numbers.
-        """
-        keys = self.protocol.key_specs(self.cfg, n_queries, party=self.party)
-        db_spec = DatabaseSpec.from_config(self.cfg).view_struct(
-            self.protocol.db_view)
-        fns = self.bucketed.fns_for(self.bucketed.bucket_for(n_queries))[0]
-        return LoweredServe(lowered=jax.jit(fns.serve).lower(db_spec, keys),
-                            plan=fns.plan, report=fns.plan_report())

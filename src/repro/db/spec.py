@@ -2,7 +2,7 @@
 
 Before the database plane, this arithmetic was smeared across four layers:
 ``core/pir.py db_as_bytes`` re-packed the whole DB on the host per call,
-``core/server.py`` and ``launch/dryrun.py`` each rebuilt the
+``core/server.py`` and a dry-run launcher each rebuilt the
 ``(n_items, item_bytes // 4)`` struct by hand, and the additive protocol
 converted words to bytes inside every compiled serve step. The spec
 centralizes it: record geometry, the two protocol *views* (u32 words for

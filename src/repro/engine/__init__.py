@@ -15,15 +15,13 @@ This package owns plan selection end to end (DESIGN.md §9):
 ``engine/cache.py``     persistent JSON plan cache keyed by
                         (backend, protocol, spec signature, bucket).
 
-:func:`resolve` is the seam the protocol plane delegates to
-(``core/protocol.py resolve_plan`` with ``path=None/"auto"``): cache hit →
-tuned plan; miss → the deterministic heuristic (``heuristic_plan``, which
-``plan_for`` aliases). Resolution happens once per bucket at
-``BucketedServeFns`` build time — never on the dispatch path.
+:func:`resolve` is the one owner of the kernel choice: cache hit → the
+tuned (or warm-recorded) plan; miss → the deterministic heuristic
+(``heuristic_plan``). ``BucketedServeFns.plan_for_bucket`` calls it once
+per bucket at build time — never on the dispatch path.
 """
 from __future__ import annotations
 
-from dataclasses import replace as _replace
 from typing import Optional
 
 # the probe is re-exported under a DIFFERENT name on purpose: a package
@@ -69,20 +67,15 @@ def plan_cache(reload: bool = False) -> PlanCache:
     return _PLAN_CACHE
 
 
-def resolve(cfg, n_queries: int, *, backend_name: Optional[str] = None,
-            chunk_log: int = 12, collective: str = "gather"):
-    """A plan for (cfg, bucket): tuned on cache hit, heuristic on miss.
-
-    The tuned plan keeps its measured tiling (including chunk_log); only
-    the collective — a topology choice the tuner does not measure — is
-    taken from the caller. The miss path is ``heuristic_plan``.
-    """
+def resolve(cfg, n_queries: int, *, backend_name: Optional[str] = None):
+    """The plan for (cfg, bucket): the cached plan on a hit, as it was
+    recorded (tiling, chunk size and collective); ``heuristic_plan`` on a
+    miss (chunk_log 12, the gather collective)."""
     be = backend_name or probe_backend()
     hit = plan_cache().get(be, cfg.protocol, spec_signature(cfg), n_queries)
     if hit is not None:
-        return _replace(hit, collective=collective)
-    plan = heuristic_plan(cfg, n_queries, backend=be, chunk_log=chunk_log)
-    return _replace(plan, collective=collective)
+        return hit
+    return heuristic_plan(cfg, n_queries, backend=be)
 
 
 def record_plans(cfg, plans: dict, *, backend_name: Optional[str] = None,
@@ -113,7 +106,7 @@ def plan_report(cfg, plan, bucket: int, *, n_shards: int = 1,
     """Reporting row for one bucket's chosen plan: provenance, the modeled
     HBM bytes its answer step moves, and the bandwidth roof of this
     process's device (``analysis.roofline.PEAKS``, keyed by device kind)
-    those bytes are judged against (dry-run / launch / bench surfaces).
+    those bytes are judged against (``BucketedServeFns.plan_report``).
 
     Pass ``measured_wall_s`` (e.g. a tuner timing) to additionally report
     ``achieved_frac`` — the fraction of peak bandwidth the measured run

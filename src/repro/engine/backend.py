@@ -6,7 +6,7 @@ registry wraps) and the engine's own registry/tuner/cache modules.
 
 Before the engine plane, backend sniffing lived in two places with two
 spellings — ``kernels/ops.py _on_tpu()`` (the interpret-mode switch) and
-``core/protocol.py plan_for``'s ``jax.default_backend()`` call (kernel-path
+the plan heuristic's ``jax.default_backend()`` call (kernel-path
 selection). They could never disagree in practice, but nothing *made* them
 agree, and neither was overridable — CI could not pin plan selection on a
 machine whose real backend differs from the one under test. ``backend()``
@@ -29,7 +29,7 @@ def backend() -> str:
     """The platform plans are selected for: forced via env, else probed.
 
     The one backend probe for the whole stack — ``kernels/ops.py``'s
-    interpret default, ``plan_for``'s kernel-path choice, the tuner's
+    interpret default, the heuristic's kernel-path choice, the tuner's
     search space and the plan-cache key all read this.
     """
     forced = os.environ.get(FORCE_BACKEND_ENV, "").strip().lower()

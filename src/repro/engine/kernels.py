@@ -15,8 +15,8 @@ the standalone GGM level expansion — and declares:
     ``VMEM_BYTES`` are pruned *without running* — the tuner never wastes
     budget timing a plan Mosaic would refuse to schedule,
   * a **predicted-bytes model**: HBM traffic of one answer step, the
-    memory-roofline numerator that dry-run/launch reporting surfaces next
-    to each chosen plan.
+    memory-roofline numerator that plan reports surface next to each
+    chosen plan.
 
 Serve-path descriptors (``serve=True``) emit ``ExecutionPlan`` candidates;
 the GGM expansion is registered ``serve=False`` — it is tuned standalone
@@ -439,25 +439,14 @@ def plans_from_kernel(desc: KernelDescriptor, shape: ProblemShape, *,
 
 
 def descriptor_for_plan(plan, share_kind: str) -> KernelDescriptor:
-    """The registered descriptor a plan executes on (for byte models).
-
-    Matching mirrors ``answer_local`` dispatch: ``expand="fused-pallas"``
-    is matched exactly first (the megakernel serves XOR *and* additive
-    protocols); beyond that, additive and LWE protocols ignore ``expand``
-    (the GEMM always materializes its operand matrix), so any such plan —
-    including a legacy ``path="fused"`` one — maps to the GEMM descriptor
-    of its ``scan``; the fused XOR body ignores ``scan`` (its inner fold
-    is always the jnp dpxor).
+    """The registered descriptor a plan executes on (for byte models):
+    the one of the share algebra with the plan's ``expand`` and ``scan``.
+    The fused XOR body ignores ``scan`` (its inner fold is always the jnp
+    dpxor).
     """
     for d in serve_kernels(share_kind):
-        if plan.expand == "fused-pallas":
-            if d.expand == "fused-pallas":
-                return d
-        elif share_kind in ("additive", "lwe"):
-            if d.expand != "fused-pallas" and d.scan == plan.scan:
-                return d
-        elif d.expand == plan.expand and (plan.expand == "fused"
-                                          or d.scan == plan.scan):
+        if d.expand == plan.expand and (plan.expand == "fused"
+                                        or d.scan == plan.scan):
             return d
     raise KeyError(f"no registered kernel for plan {plan.name!r} "
                    f"({share_kind})")
@@ -474,8 +463,8 @@ def predicted_step_bytes(plan, share_kind: str, shape: ProblemShape) -> int:
     """Modeled HBM bytes one answer step moves under ``plan`` (per shard).
 
     The memory-roofline numerator (`analysis/roofline.py` HBM_BW divides
-    it into a time bound); surfaced by dry-run and launch reporting next
-    to each bucket's chosen plan.
+    it into a time bound); surfaced by ``engine.plan_report`` next to each
+    bucket's chosen plan.
     """
     desc = descriptor_for_plan(plan, share_kind)
     return desc.bytes_fn(shape, plan_params(plan))

@@ -2,7 +2,7 @@
 
 IM-PIR's thesis is that PIR answering is memory-bandwidth-bound — which
 makes kernel path + tiling *the* throughput story. The pre-engine stack
-chose both by folklore: a hand-written heuristic (``plan_for``) plus tile
+chose both by folklore: a hand-written heuristic plus tile
 constants hardcoded in ``kernels/ops.py``, never validated against
 measurement. The tuner closes that loop:
 
@@ -18,7 +18,7 @@ measurement. The tuner closes that loop:
 
 The **heuristic is always candidate #0** and is always measured, so a tune
 can only ever match or beat it — and a cache miss falls back to it
-bit-for-bit (``heuristic_plan``, the rules ``plan_for`` aliases).
+bit-for-bit (``heuristic_plan``).
 
 Budgets: measurement costs wall clock (and, on this CPU container, XLA
 compiles of interpret-mode Pallas bodies), so every entry point takes a
@@ -43,7 +43,7 @@ from repro.engine.kernels import (ProblemShape, GEMM_TILE_R_DEFAULT,
 
 
 # ---------------------------------------------------------------------------
-# The deterministic fallback (what plan_for aliases)
+# The deterministic fallback
 # ---------------------------------------------------------------------------
 
 def heuristic_plan(cfg, n_queries: int, *, backend: Optional[str] = None,
@@ -343,9 +343,9 @@ def autotune(cfg, buckets: Sequence[int], *,
     """Tune every bucket of a config and (optionally) persist the winners.
 
     ``cache=None`` uses the process-wide plan cache (``repro.engine.
-    plan_cache()``), so servers built afterwards with ``path=None/"auto"``
-    in the same process pick the tuned plans up immediately; ``persist``
-    additionally writes the JSON store for future processes.
+    plan_cache()``), so servers built afterwards in the same process pick
+    the tuned plans up immediately; ``persist`` additionally writes the
+    JSON store for future processes.
     """
     from repro import engine
     cache = cache if cache is not None else engine.plan_cache()
@@ -434,13 +434,12 @@ def smoke() -> int:
     rules; this is the fast CI spot check.)
     """
     from repro.config import PIRConfig
-    from repro.core.protocol import plan_for
     from repro.engine.cache import PlanCache
 
     for (proto, log_n, n_q, be), want in _EXPECTED_PLANS.items():
         cfg = PIRConfig(n_items=1 << log_n, item_bytes=32, protocol=proto,
                         n_servers=1 if proto == "lwe-simple-1" else 2)
-        got = plan_for(cfg, n_q, backend=be)
+        got = heuristic_plan(cfg, n_q, backend=be)
         assert (got.expand, got.scan) == want, (
             f"heuristic drifted from its pinned choice: "
             f"{proto} 2^{log_n} n_q={n_q} {be}: "

@@ -1,10 +1,8 @@
-"""Mesh construction for the production pod topologies.
+"""Mesh construction over this process's devices.
 
-``make_production_mesh`` is a *function* (not a module-level constant) so that
-importing this module never touches JAX device state — critical because the
-dry-run launcher must set ``XLA_FLAGS=--xla_force_host_platform_device_count``
-before the first JAX initialization, while unit tests must see the single real
-CPU device.
+Meshes are built by functions, never at import, so importing this module
+never touches JAX device state (a caller may still set ``XLA_FLAGS`` before
+the first JAX initialization).
 
 Axis semantics (see DESIGN.md §3):
   pod    cross-pod data parallelism (train) / extra cluster parallelism (PIR)
@@ -14,26 +12,10 @@ Axis semantics (see DESIGN.md §3):
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import numpy as np
-
-from repro.config import MeshConfig
-
-SINGLE_POD = MeshConfig(shape=(16, 16), axes=("data", "model"))
-MULTI_POD = MeshConfig(shape=(2, 16, 16), axes=("pod", "data", "model"))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(cfg: MeshConfig) -> jax.sharding.Mesh:
-    """Build a mesh for an arbitrary MeshConfig (used by tests & elastic)."""
-    return jax.make_mesh(tuple(cfg.shape), tuple(cfg.axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
@@ -70,20 +52,6 @@ def split_devices(n_groups: int, devices=None, *,
     if per < max(min_per_group, 1):
         return [list(devs) for _ in range(n_groups)]
     return [devs[i * per:(i + 1) * per] for i in range(n_groups)]
-
-
-def mesh_axis_size(mesh: jax.sharding.Mesh, name: str) -> int:
-    return mesh.shape.get(name, 1)
-
-
-def batch_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:
-    """Axes over which the global batch is sharded."""
-    return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-
-
-def pir_cluster_axes(mesh: jax.sharding.Mesh) -> Tuple[str, ...]:
-    """Axes that enumerate PIR clusters (DB replicas)."""
-    return batch_axes(mesh)
 
 
 def pir_shard_axis(mesh: jax.sharding.Mesh) -> Optional[str]:

@@ -8,9 +8,6 @@ Examples:
   # ~100M-class end-to-end run on this container (examples/train_lm.py
   # wraps this with a fixed recipe):
   python -m repro.launch.train --arch granite-3-2b --smoke --steps 200
-
-  # full-config step construction against the production mesh is exercised
-  # by launch/dryrun.py (lower+compile only — no CPU can execute it).
 """
 from __future__ import annotations
 

@@ -3,9 +3,8 @@
 Everything the operator dashboards need from the fleet, computed from
 state the router and schedulers already keep (no new instrumentation on
 the dispatch path): per-replica QPS, queue depth, epoch lag, latency
-percentiles; router-level failover and resubmission counters. The bench
-(``benchmarks/bench_replicas.py``) and the example embed these snapshots
-in their artifacts.
+percentiles; router-level failover and resubmission counters. The
+example (``examples/replicas.py``) prints these snapshots.
 """
 from __future__ import annotations
 

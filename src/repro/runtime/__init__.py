@@ -1,3 +1,5 @@
-from repro.runtime.steps import (make_pir_serve_step, make_serve_step,
-                                 make_train_step)
-__all__ = ["make_pir_serve_step", "make_serve_step", "make_train_step"]
+"""The serving runtime (sessions, scheduler, batch composite, faults).
+
+Importing the package loads nothing else: the language-model step
+builders live in ``repro.runtime.steps`` and are imported from there.
+"""

@@ -67,8 +67,7 @@ class BatchPIR(MultiServerPIR):
     """
 
     def __init__(self, db_words, cfg: PIRConfig, mesh,
-                 *, path: Optional[str] = "fused",
-                 rounds: Sequence[int] = (1,),
+                 *, rounds: Sequence[int] = (1,),
                  max_wait_s: float = DEFAULT_MAX_WAIT_S,
                  n_clusters: int = 1,
                  protocol: Optional[PIRProtocol] = None,
@@ -99,7 +98,7 @@ class BatchPIR(MultiServerPIR):
         # (same shape + sharding -> one compiled step per rounds-bucket)
         self.serve = [
             BucketedServeFns(self.inner_cfg, mesh, buckets=rounds,
-                             path=path, party=p, protocol=self.protocol)
+                             party=p, protocol=self.protocol)
             for p in range(self.n_parties)]
         self.rng = (client_rng if client_rng is not None
                     else np.random.default_rng())

@@ -33,7 +33,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import itertools
-import queue
+import math
 import threading
 import time
 from collections import deque
@@ -273,9 +273,16 @@ class QueryScheduler:
     swap.
 
     Queries arrive via :meth:`submit` (returns an :class:`AnswerFuture`).
-    Batches are cut when a full bucket's worth is pending, or when the
-    oldest query has waited ``max_wait_s`` (then padded up to the smallest
-    covering bucket). Work is spread round-robin over ``n_clusters``
+    Batches are cut when a full bucket's worth is pending, or, with no
+    batch dispatched or queued, when the oldest query has waited
+    ``max_wait_s`` and ``max_wait_s`` has passed since the last batch
+    completed (then padded up to the smallest covering bucket). While the
+    device has work an under-full batch would only queue behind it, so
+    the queries wait for company; the second clock lets the clients a
+    completion just answered join too. Without both, a closed loop keeps
+    the grouping of any under-full cut (a client whose next query came
+    a few ms late), and a run's rate depends on how many such cuts it met.
+    Work is spread round-robin over ``n_clusters``
     logical lanes; :meth:`rebalance` sheds a flagged straggler's queued
     batches onto healthy lanes.
 
@@ -331,6 +338,7 @@ class QueryScheduler:
             f"cluster{i}": [] for i in range(self.n_clusters)}
         self._rr = 0                          # round-robin lane counter
         self._n_cut = 0                       # batches formed so far
+        self._t_done = -math.inf              # clock() of the last completion
         self._n_inflight = 0                  # real queries dispatched, unresolved
         self._thread: Optional[threading.Thread] = None
         self._stopping = False
@@ -456,11 +464,20 @@ class QueryScheduler:
             fut.context["batch"] = batch.seq
         self.queues[lane].append(batch)
 
+    def _ripe_in_locked(self) -> float:
+        """Seconds until the pending queries may be cut under-full: both
+        the oldest query and the last completion ``max_wait_s`` old."""
+        now = self.clock()
+        return max(self.max_wait_s - (now - self._pending[0][2]),
+                   self.max_wait_s - (now - self._t_done), 0.0)
+
     def _cut_ripe_locked(self) -> bool:
-        """Cut under-full batches whose oldest query aged past max_wait_s."""
+        """Cut under-full batches once they are ripe (``_ripe_in_locked``)
+        and no batch is dispatched or queued ahead of them."""
+        if self._n_inflight or any(self.queues.values()):
+            return False
         cut = False
-        while self._pending and \
-                self.clock() - self._pending[0][2] >= self.max_wait_s:
+        while self._pending and self._ripe_in_locked() <= 0.0:
             self._cut_locked(min(len(self._pending), self.buckets[-1]))
             cut = True
         return cut
@@ -540,6 +557,7 @@ class QueryScheduler:
             _FINALIZING_BATCH.reset(finalizing)
             with self._cv:
                 self._n_inflight -= len(batch.items)
+                self._t_done = self.clock()
         self.monitor.record(batch.cluster, dt)
         self.stats.observe_window(t0, t0 + dt)
         self.stats.latencies.append(dt)
@@ -648,11 +666,8 @@ class QueryScheduler:
                         return
                     if batch is None and not inflight:
                         # idle: sleep until a submit arrives or one ripens
-                        wait = self.max_wait_s
-                        if self._pending:
-                            age = self.clock() - self._pending[0][2]
-                            wait = max(self.max_wait_s - age, 0.0)
-                        self._cv.wait(timeout=wait if self._pending else None)
+                        self._cv.wait(timeout=self._ripe_in_locked()
+                                      if self._pending else None)
                         continue
                 if batch is not None:
                     inflight.append(self._launch(batch))
@@ -679,69 +694,6 @@ class QueryScheduler:
                 victims.append(fut)
         for fut in victims:          # outside the lock: done-callbacks may
             fut.set_exception(exc)   # re-enter other schedulers (failover)
-
-
-class PIRServeLoop:
-    """Single-party serve loop over a cluster-sharded PIR server."""
-
-    def __init__(self, server: PIRServer, *, n_clusters: int = 1):
-        self.server = server
-        self.n_clusters = n_clusters
-        self.task_q: "queue.Queue" = queue.Queue()
-        self.straggler = StragglerMonitor()
-        self.stats = ServeStats()
-
-    def submit(self, keys: dpf.DPFKey):
-        """Enqueue a batch of stacked DPF keys (one cluster-step of work)."""
-        self.task_q.put(keys)
-
-    def drain(self) -> List[jax.Array]:
-        """Serial baseline: answer every queued batch, blocking per batch.
-
-        Kept as the §Perf comparison point for :meth:`drain_pipelined` —
-        this is the paper's strictly synchronous Figure 8 loop.
-        """
-        out = []
-        while not self.task_q.empty():
-            keys = self.task_q.get()
-            t0 = time.monotonic()
-            ans = self.server.answer(keys)
-            ans.block_until_ready()
-            self._record(keys, t0, time.monotonic() - t0)
-            out.append(ans)
-        return out
-
-    def drain_pipelined(self, depth: int = PIPELINE_DEPTH) -> List[jax.Array]:
-        """Double-buffered drain: stage batch k+1 while batch k executes.
-
-        Same answers as :meth:`drain` — staged batches are padded to their
-        bucket, so the pad rows are sliced back off here; the
-        ``block_until_ready`` bubble is overlapped with the next batch's
-        host-side staging + dispatch.
-        """
-        out: List[jax.Array] = []
-        inflight: deque = deque()
-        while not self.task_q.empty() or inflight:
-            if not self.task_q.empty() and len(inflight) < depth:
-                keys = self.task_q.get()
-                staged = self.server.stage_keys(keys)
-                t0 = time.monotonic()
-                inflight.append((keys, self.server.answer(staged), t0))
-                continue
-            keys, ans, t0 = inflight.popleft()
-            ans = ans[: dpf.n_queries_of(keys)]      # drop pad-slot answers
-            ans.block_until_ready()
-            self._record(keys, t0, time.monotonic() - t0)
-            out.append(ans)
-        return out
-
-    def _record(self, keys: dpf.DPFKey, t0: float, dt: float):
-        self.stats.observe_window(t0, t0 + dt)
-        self.stats.latencies.append(dt)
-        self.stats.batches += 1
-        self.stats.answered += dpf.n_queries_of(keys)
-        self.straggler.record(
-            f"cluster{self.stats.batches % max(self.n_clusters, 1)}", dt)
 
 
 class MultiServerPIR:
@@ -790,7 +742,7 @@ class MultiServerPIR:
     _supports_hint_protocols = False
 
     def __init__(self, db_words, cfg: PIRConfig, mesh,
-                 *, path: Optional[str] = "fused", n_queries: int = 4,
+                 *, n_queries: int = 4,
                  buckets: Optional[Sequence[int]] = None,
                  max_wait_s: float = DEFAULT_MAX_WAIT_S,
                  n_clusters: int = 1,
@@ -798,7 +750,14 @@ class MultiServerPIR:
                  client_rng: Optional[np.random.Generator] = None,
                  default_deadline_s: Optional[float] = None,
                  chaos=None, chaos_scope: Optional[str] = None,
-                 collective: str = "gather"):
+                 path: None = None):
+        # ``path`` is accepted only as None, for bench/run.py, which still
+        # passes ``path=None``; the engine picks every bucket's plan
+        if path is not None:
+            raise TypeError(
+                f"path={path!r}: the engine picks the plan "
+                f"(repro.engine.resolve); seed one with "
+                f"engine.record_plans")
         self.cfg = cfg
         self.protocol = (protocol if protocol is not None
                          else protocol_mod.for_config(cfg))
@@ -814,8 +773,8 @@ class MultiServerPIR:
                    else ShardedDatabase(db_words, cfg, mesh))
         self.servers = [
             PIRServer(party=b, database=self.db, cfg=cfg, mesh=mesh,
-                      n_queries=n_queries, path=path, buckets=buckets,
-                      protocol=self.protocol, collective=collective)
+                      n_queries=n_queries, buckets=buckets,
+                      protocol=self.protocol)
             for b in range(self.n_parties)
         ]
         # key material (DPF keys, xor-dpf-k mask seeds) must not be
@@ -1026,16 +985,12 @@ class SingleServerPIR(MultiServerPIR):
         caches are invalidated exactly when the data changes
         (``hint_fetches`` counts the round trips; the server side
         maintains the hint itself incrementally via the registered delta).
-
-    ``path`` defaults to ``None``: the plan is resolved through the engine
-    plane (plan-cache hit -> tuned LWE GEMM tiles, miss -> heuristic).
     """
 
     _supports_hint_protocols = True
 
     def __init__(self, db_words, cfg: PIRConfig, mesh,
-                 *args, path: Optional[str] = None,
-                 protocol: Optional[PIRProtocol] = None, **kwargs):
+                 *args, protocol: Optional[PIRProtocol] = None, **kwargs):
         proto = (protocol if protocol is not None
                  else protocol_mod.for_config(cfg))
         k = proto.n_parties(cfg)
@@ -1048,8 +1003,8 @@ class SingleServerPIR(MultiServerPIR):
         self._hint_lock = threading.Lock()
         self._hint_cache: Dict[int, np.ndarray] = {}
         self.hint_fetches = 0
-        super().__init__(db_words, cfg, mesh, *args, path=path,
-                         protocol=proto, **kwargs)
+        super().__init__(db_words, cfg, mesh, *args, protocol=proto,
+                         **kwargs)
 
     def _client_hint(self, epoch: int) -> np.ndarray:
         """The hint for one epoch, through the client-side cache."""

@@ -11,21 +11,19 @@ Train step structure:
 
 Serve steps:
   prefill: full causal pass -> (last logits, KV cache)
-  decode:  one token against the cache (``write=False`` for the dry-run
-           cells whose cache is at capacity; the serve loop uses write=True)
-  pir:     the bucketed PIR answer-step family (one compiled step per batch
-           bucket, DESIGN.md §6) consumed by runtime.serve_loop
+  decode:  one token against the cache (``write=False`` for cells whose
+           cache is at capacity; the serve loop uses write=True)
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.config import PIRConfig, RunConfig
+from repro.config import RunConfig
 from repro.models import build_model, input_specs
 from repro.optim import compression
 from repro.optim.optimizer import opt_init, opt_update, spec_for_state
@@ -335,63 +333,3 @@ def make_serve_step(run: RunConfig, mesh: Mesh, *,
     return ServeStep(prefill=jit_prefill, decode=jit_decode, model=model,
                      param_shardings=param_sh, cache_shardings=cache_sh,
                      input_structs=structs)
-
-
-class PIRStep(NamedTuple):
-    """Compiled PIR serving entry points (one bucket family, one party).
-
-    ``answer`` takes either a ``ShardedDatabase`` (the database plane
-    resolves the protocol's declared view per dispatch — DESIGN.md §8) or
-    that view's raw device array; ``db_view`` names which view the
-    compiled steps contract against. ``plan_report`` surfaces each
-    bucket's resolved plan — kernel path, provenance (tuned vs heuristic
-    vs forced), predicted step bytes — resolved once at build time by the
-    engine plane (DESIGN.md §9), never on the dispatch path.
-    """
-    answer: Callable           # (db, keys) -> [bucket, ...] shares (async)
-    stage_keys: Callable       # keys -> padded + device_put keys
-    buckets: Tuple[int, ...]
-    db_sharding: NamedSharding
-    n_compiles: Callable[[], int]    # cache-miss counter (tests/benches)
-    db_view: str = "words"
-    plan_report: Callable[[], Dict[int, dict]] = lambda: {}
-
-
-def make_pir_serve_step(
-    cfg: PIRConfig,
-    mesh: Mesh,
-    *,
-    buckets: Optional[Sequence[int]] = None,
-    path: Optional[str] = "fused",
-    collective: str = "gather",
-    party: int = 0,
-    protocol=None,
-) -> PIRStep:
-    """Build the bucketed PIR answer-step family in the step-builder idiom.
-
-    Mirrors ``make_train_step``/``make_serve_step``: configs in, compiled
-    jit entry points with explicit shardings out. Each batch bucket lowers
-    exactly once (``core.server.BucketedServeFns``); the scheduler pads
-    ragged batches up to the covering bucket so odd-sized traffic never
-    triggers recompilation (DESIGN.md §6). The share scheme comes from
-    ``protocol`` (a ``core.protocol.PIRProtocol`` or ``cfg.protocol`` by
-    default); ``path=None`` lets ``protocol.plan_for`` pick the kernel
-    path per bucket.
-    """
-    from repro.core.server import BucketedServeFns, default_buckets
-    from repro.launch.mesh import mesh_axis_size, pir_cluster_axes
-
-    n_clusters = 1
-    for a in pir_cluster_axes(mesh):
-        n_clusters *= mesh_axis_size(mesh, a)
-    if buckets is None:
-        buckets = default_buckets(n_clusters)
-    bucketed = BucketedServeFns(cfg, mesh, buckets=buckets, path=path,
-                                collective=collective, party=party,
-                                protocol=protocol)
-    db_sharding = bucketed.fns_for(bucketed.buckets[0])[0].db_sharding
-    return PIRStep(answer=bucketed.answer, stage_keys=bucketed.stage,
-                   buckets=bucketed.buckets, db_sharding=db_sharding,
-                   n_compiles=lambda: bucketed.n_compiles,
-                   db_view=bucketed.protocol.db_view,
-                   plan_report=bucketed.plan_report)

@@ -510,8 +510,7 @@ def test_two_server_session_serves_updates(mesh):
     n = 1 << 8
     host = pir.make_database(np.random.default_rng(2), n, 32)
     cfg = PIRConfig(n_items=n, batch_queries=2)
-    sys2 = TwoServerPIR(host, cfg, mesh, path="fused", n_queries=2,
-                        buckets=(2,))
+    sys2 = TwoServerPIR(host, cfg, mesh, n_queries=2, buckets=(2,))
     idx = [7, 200]
     np.testing.assert_array_equal(sys2.query(idx), host[idx])
     new_row = RNG.integers(0, 1 << 32, size=(1, 8), dtype=np.uint32)

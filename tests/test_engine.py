@@ -23,11 +23,11 @@ from repro import engine
 from repro.config import PIRConfig
 from repro.core import pir
 from repro.core import protocol as protocol_mod
-from repro.core.protocol import ExecutionPlan, plan_for, resolve_plan
+from repro.core.protocol import ExecutionPlan
 from repro.engine.backend import FORCE_BACKEND_ENV, legal_tile
 from repro.engine.cache import PlanCache, spec_signature
 from repro.engine.kernels import ProblemShape
-from repro.engine.tuner import TuneBudget, plan_label
+from repro.engine.tuner import TuneBudget, heuristic_plan, plan_label
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(23)
@@ -48,10 +48,10 @@ def test_backend_probe_and_force_override(monkeypatch):
     assert engine.probe_backend() == "tpu"
     assert ops.default_interpret() is False
     # plan selection is pinned too: CI can force the TPU plan rules on CPU
-    plan = plan_for(PIRConfig(n_items=N), 4)
+    plan = heuristic_plan(PIRConfig(n_items=N), 4)
     assert plan.scan == "pallas"
     monkeypatch.setenv(FORCE_BACKEND_ENV, "cpu")
-    assert plan_for(PIRConfig(n_items=N), 4).scan == "jnp"
+    assert heuristic_plan(PIRConfig(n_items=N), 4).scan == "jnp"
 
 
 def test_backend_submodule_not_shadowed_by_reexport():
@@ -157,25 +157,13 @@ def test_heuristic_reproduces_pre_engine_plan_for(protocol):
         for n_q in (1, 4, 32):
             for be in ("cpu", "tpu"):
                 want = _pre_engine_plan_for(cfg, n_q, be)
-                assert plan_for(cfg, n_q, backend=be) == want
+                assert heuristic_plan(cfg, n_q, backend=be) == want
                 # a cache miss must resolve identically (the fallback)
                 got = engine.resolve(cfg, n_q, backend_name=be)
                 if engine.plan_cache().get(be, cfg.protocol,
                                            spec_signature(cfg), n_q) is None:
                     assert got == want
                     assert got.provenance == "heuristic"
-
-
-def test_resolve_plan_paths_and_provenance():
-    cfg = PIRConfig(n_items=N)
-    forced = resolve_plan("fused", cfg, 4, chunk_log=9)
-    assert forced.provenance == "forced" and forced.chunk_log == 9
-    # additive forced paths pin the GEMM reduction tile to the pre-engine
-    # kernel default (ops.py used 1024, the scan used 2048)
-    add = resolve_plan("matmul", PIRConfig(n_items=N,
-                                           protocol="additive-dpf-2"), 4)
-    assert add.tile_r == 1024
-    assert plan_for(cfg, 4, backend="cpu").provenance == "heuristic"
 
 
 # ---------------------------------------------------------------------------
@@ -249,7 +237,7 @@ def test_lwe_pallas_gemm_not_offered_on_tpu():
     assert not engine.get_kernel("lwe-gemm-pallas").mosaic
     plans = engine.candidate_plans(cfg, 2, backend="tpu")
     assert {(p.expand, p.scan) for p in plans} == {("materialize", "jnp")}
-    assert plan_for(cfg, 4, backend="tpu").scan == "jnp"
+    assert heuristic_plan(cfg, 4, backend="tpu").scan == "jnp"
     # every other share algebra keeps its Pallas candidates on a TPU
     for proto in ("xor-dpf-2", "additive-dpf-2"):
         got = engine.candidate_plans(PIRConfig(n_items=N, protocol=proto),
@@ -263,7 +251,7 @@ def test_additive_megakernel_tile_fits_vmem_per_bucket():
     desc = engine.get_kernel("gemm-fused-pallas")
     cfg = PIRConfig(n_items=1 << 25, protocol="additive-dpf-2")
     for bucket, tile in ((1, 2048), (8, 2048), (16, 1024), (32, 512)):
-        plan = plan_for(cfg, bucket, backend="tpu")
+        plan = heuristic_plan(cfg, bucket, backend="tpu")
         assert (plan.expand, plan.tile_r) == ("fused-pallas", tile)
         shape = engine.problem_shape(cfg, bucket)
         assert desc.feasible(shape, {"tile_r": plan.tile_r,
@@ -277,7 +265,7 @@ def test_xor_megakernel_tile_fits_vmem_per_bucket():
     desc = engine.get_kernel("xor-fused-pallas")
     cfg = PIRConfig(n_items=1 << 25)
     for bucket, tile in ((1, 2048), (8, 2048), (16, 1024), (32, 512)):
-        plan = plan_for(cfg, bucket, backend="tpu")
+        plan = heuristic_plan(cfg, bucket, backend="tpu")
         assert (plan.expand, plan.tile_r) == ("fused-pallas", tile)
         shape = engine.problem_shape(cfg, bucket)
         assert desc.feasible(shape, {"tile_r": plan.tile_r,
@@ -328,8 +316,7 @@ def test_lwe_plan_resolution_through_engine(tmp_path, monkeypatch):
         assert descriptor_for_plan(
             ExecutionPlan(scan="pallas"), "lwe").name == "lwe-gemm-pallas"
         # cache hit (bucket 2) -> tuned provenance through plan_report
-        b = BucketedServeFns(cfg, make_local_mesh(), buckets=(2,),
-                             path=None)
+        b = BucketedServeFns(cfg, make_local_mesh(), buckets=(2,))
         rep = b.plan_report()[2]
         assert rep["provenance"] == "tuned"
         assert b.plan_for_bucket(2).tile_r == 512
@@ -407,10 +394,12 @@ def test_plan_cache_roundtrip(tmp_path):
 
 
 def test_engine_resolve_uses_cache_hit(tmp_path, monkeypatch):
+    from repro.core.server import BucketedServeFns
+    from repro.launch.mesh import make_local_mesh
     path = str(tmp_path / "plans.json")
     cfg = PIRConfig(n_items=N)
     tuned = ExecutionPlan(expand="fused", scan="jnp", chunk_log=5,
-                          provenance="tuned")
+                          collective="butterfly", provenance="tuned")
     c = PlanCache(path)
     c.put("cpu", cfg.protocol, spec_signature(cfg), 4, tuned)
     c.save()
@@ -418,15 +407,17 @@ def test_engine_resolve_uses_cache_hit(tmp_path, monkeypatch):
     monkeypatch.setenv(FORCE_BACKEND_ENV, "cpu")
     engine.plan_cache(reload=True)
     try:
-        got = engine.resolve(cfg, 4, collective="butterfly")
+        got = engine.resolve(cfg, 4)
         assert got.provenance == "tuned"
-        # tuned tiling survives; only the (untuned) collective is caller's
-        assert got.chunk_log == 5 and got.collective == "butterfly"
-        # other buckets still miss -> heuristic
-        assert engine.resolve(cfg, 8).provenance == "heuristic"
+        # the cached plan comes back as recorded: tiling and collective
+        assert got == tuned and got.collective == "butterfly"
+        # other buckets still miss -> heuristic, with the gather collective
+        miss = engine.resolve(cfg, 8)
+        assert miss.provenance == "heuristic" and miss.collective == "gather"
         # the serving stack resolves through the same seam
-        assert resolve_plan(None, cfg, 4).provenance == "tuned"
-        assert resolve_plan("auto", cfg, 8).provenance == "heuristic"
+        b = BucketedServeFns(cfg, make_local_mesh(), buckets=(4, 8))
+        assert b.plan_for_bucket(4).provenance == "tuned"
+        assert b.plan_for_bucket(8).provenance == "heuristic"
     finally:
         monkeypatch.delenv("REPRO_PLAN_CACHE")
         monkeypatch.delenv(FORCE_BACKEND_ENV)
@@ -452,7 +443,7 @@ def test_plan_cache_degrades_to_heuristic(tmp_path, monkeypatch, payload):
     try:
         cfg = PIRConfig(n_items=N)
         got = engine.resolve(cfg, 4, backend_name="cpu")
-        assert got == plan_for(cfg, 4, backend="cpu")
+        assert got == heuristic_plan(cfg, 4, backend="cpu")
         assert got.provenance == "heuristic"
     finally:
         monkeypatch.delenv("REPRO_PLAN_CACHE")
@@ -495,12 +486,11 @@ def test_bucketed_serve_fns_resolve_plans_at_build_time():
     from repro.core.server import BucketedServeFns
     from repro.launch.mesh import make_local_mesh
     cfg = PIRConfig(n_items=N)
-    b = BucketedServeFns(cfg, make_local_mesh(), buckets=(2, 4),
-                         path=None)
+    b = BucketedServeFns(cfg, make_local_mesh(), buckets=(2, 4))
     assert b.n_compiles == 0
     p2, p4 = b.plan_for_bucket(2), b.plan_for_bucket(4)
-    assert p2 == resolve_plan(None, cfg, 2)
-    assert p4 == resolve_plan(None, cfg, 4)
+    assert p2 == engine.resolve(cfg, 2)
+    assert p4 == engine.resolve(cfg, 4)
     assert b.plan_for_bucket(2) is p2      # cached: one resolution/bucket
     rep = b.plan_report()
     assert set(rep) == {2, 4}
@@ -508,23 +498,6 @@ def test_bucketed_serve_fns_resolve_plans_at_build_time():
         assert row["provenance"] in ("heuristic", "tuned")
         assert row["predicted_step_bytes"] > 0
     assert b.n_compiles == 0               # nothing was lowered for this
-
-
-def test_plan_report_handles_additive_fused_path():
-    """Regression: an additive protocol under the legacy ``path="fused"``
-    (dryrun's default) yields a fused/jnp plan that the GEMM ignores —
-    plan_report/descriptor mapping must follow answer_local dispatch
-    (scan only) instead of raising KeyError."""
-    from repro.core.server import BucketedServeFns
-    from repro.engine.kernels import descriptor_for_plan
-    from repro.launch.mesh import make_local_mesh
-    cfg = PIRConfig(n_items=N, protocol="additive-dpf-2")
-    plan = resolve_plan("fused", cfg, 2)
-    assert descriptor_for_plan(plan, "additive").name == "gemm-jnp"
-    b = BucketedServeFns(cfg, make_local_mesh(), buckets=(2,), path="fused")
-    rep = b.plan_report()[2]
-    assert rep["provenance"] == "forced"
-    assert rep["predicted_step_bytes"] > 0
 
 
 def test_predicted_bytes_models_are_sane():

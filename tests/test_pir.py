@@ -1,8 +1,9 @@
 """End-to-end PIR protocol tests: client + two servers, all server paths.
 
 Covers the paper's Algorithm 1 on the reference (single-shard) forms and
-the sharded server (shard_map over a local mesh) in baseline / fused /
-matmul paths, plus the cluster topology and the aggregation collectives.
+the sharded server (shard_map over a local mesh) under the materialized
+and fused plans and the additive GEMM, plus the cluster topology and the
+aggregation collectives.
 """
 import numpy as np
 import pytest
@@ -13,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.config import PIRConfig
 from repro.core import dpf, pir
+from repro.core.protocol import ExecutionPlan
 from repro.core.server import PIRServer, build_serve_fn
 from repro.launch.mesh import make_local_mesh
 
@@ -54,11 +56,15 @@ def test_additive_roundtrip_reference():
 
 @pytest.mark.slow   # jit-compiles serve/eval steps (~1 min each here)
 @pytest.mark.parametrize("path", ["baseline", "fused", "matmul"])
-def test_sharded_server_paths(mesh, path):
+def test_sharded_server_paths(mesh, path, plan_cache_off):
+    from repro import engine
     mode = "additive" if path == "matmul" else "xor"
     cfg = PIRConfig(n_items=N, mode=mode, batch_queries=4)
+    expand = "fused" if path == "fused" else "materialize"
+    engine.record_plans(cfg, {4: ExecutionPlan(expand=expand)})
     servers = [PIRServer(party=b, db_words=DB, cfg=cfg, mesh=mesh,
-                         n_queries=4, path=path) for b in (0, 1)]
+                         n_queries=4) for b in (0, 1)]
+    assert servers[0].bucketed.plan_for_bucket(4).expand == expand
     indices = [3, 99, 512, N - 1]
     k0, k1 = pir.batch_queries(RNG, indices, cfg)
     r0 = servers[0].answer(k0)
@@ -79,8 +85,8 @@ def test_collective_variants_agree(mesh):
     k0, _ = pir.batch_queries(RNG, idxs, cfg)
     outs = []
     for coll in ("gather", "butterfly"):
-        fns = build_serve_fn(cfg, mesh, n_queries=2, path="baseline",
-                             collective=coll)
+        fns = build_serve_fn(cfg, mesh, n_queries=2,
+                             plan=ExecutionPlan(collective=coll))
         db = jax.device_put(jnp.asarray(DB), fns.db_sharding)
         outs.append(np.asarray(fns.serve(db, k0)))
     np.testing.assert_array_equal(outs[0], outs[1])
@@ -91,18 +97,19 @@ def test_fused_equals_baseline(mesh):
     cfg = PIRConfig(n_items=N, batch_queries=2)
     k0, _ = pir.batch_queries(RNG, [11, 222], cfg)
     res = {}
-    for path in ("baseline", "fused"):
-        fns = build_serve_fn(cfg, mesh, n_queries=2, path=path)
+    for expand in ("materialize", "fused"):
+        fns = build_serve_fn(cfg, mesh, n_queries=2,
+                             plan=ExecutionPlan(expand=expand))
         db = jax.device_put(jnp.asarray(DB), fns.db_sharding)
-        res[path] = np.asarray(fns.serve(db, k0))
-    np.testing.assert_array_equal(res["baseline"], res["fused"])
+        res[expand] = np.asarray(fns.serve(db, k0))
+    np.testing.assert_array_equal(res["materialize"], res["fused"])
 
 
 @pytest.mark.slow   # jit-compiles serve/eval steps (~1 min each here)
 def test_two_server_deployment(mesh):
     from repro.runtime.serve_loop import TwoServerPIR
     cfg = PIRConfig(n_items=N, batch_queries=4)
-    sys2 = TwoServerPIR(DB, cfg, mesh, path="fused", n_queries=4)
+    sys2 = TwoServerPIR(DB, cfg, mesh, n_queries=4)
     idx = [1, 2, 3, 1000]
     out = sys2.query(idx)
     np.testing.assert_array_equal(out, DB[idx])

@@ -25,8 +25,8 @@ import jax.numpy as jnp
 from repro.config import PIRConfig
 from repro.core import dpf, pir
 from repro.core import protocol as protocol_mod
-from repro.core.protocol import (ExecutionPlan, PATH_PLANS, available,
-                                 for_config, get, plan_for, resolve_plan)
+from repro.core.protocol import ExecutionPlan, available, for_config, get
+from repro.engine import heuristic_plan
 from repro.kernels import ops, ref
 
 RNG = np.random.default_rng(7)
@@ -138,37 +138,31 @@ def test_k_server_party_counts_and_specs():
 
 
 def test_plan_selection_rules():
-    # legacy path strings keep their meaning
-    assert PATH_PLANS["baseline"].expand == "materialize"
-    assert PATH_PLANS["fused"].expand == "fused"
-    plan = resolve_plan("fused", PIRConfig(n_items=N), 4, chunk_log=9,
-                        collective="butterfly")
-    assert (plan.expand, plan.chunk_log, plan.collective) == \
-        ("fused", 9, "butterfly")
-    with pytest.raises(ValueError, match="unknown path"):
-        resolve_plan("warp-drive", PIRConfig(n_items=N), 4)
-    # the GEMM path needs additive shares: XOR protocols must refuse, not
-    # silently fall back to the XOR scan (would mislabel benchmarks)
-    from repro.core.server import build_serve_fn
-    from repro.launch.mesh import make_local_mesh
-    with pytest.raises(ValueError, match="additive"):
-        build_serve_fn(PIRConfig(n_items=N), make_local_mesh(),
-                       n_queries=2, path="matmul")
+    # the heuristic fills the non-kernel axes: chunk_log 12, gather
+    plan = heuristic_plan(PIRConfig(n_items=N), 4, backend="cpu")
+    assert (plan.chunk_log, plan.collective) == (12, "gather")
+    # additive protocols on the CPU take the materialized GEMM at its
+    # pre-engine reduction tile
+    add = heuristic_plan(PIRConfig(n_items=1 << 20,
+                                   protocol="additive-dpf-2"), 4,
+                         backend="cpu")
+    assert (add.expand, add.scan, add.tile_r) == ("materialize", "jnp",
+                                                  1024)
     # selector: XOR small db -> materialize; XOR big db -> fused on the
     # CPU at every bucket size (a single query's full-domain eval does not
     # fit at scale), the megakernel on a TPU; Pallas bodies only on TPU
-    small = plan_for(PIRConfig(n_items=1 << 10), 4, backend="cpu")
-    big = plan_for(PIRConfig(n_items=1 << 20), 8, backend="cpu")
-    single = plan_for(PIRConfig(n_items=1 << 20), 1, backend="cpu")
+    small = heuristic_plan(PIRConfig(n_items=1 << 10), 4, backend="cpu")
+    big = heuristic_plan(PIRConfig(n_items=1 << 20), 8, backend="cpu")
+    single = heuristic_plan(PIRConfig(n_items=1 << 20), 1, backend="cpu")
     assert small.expand == "materialize" and big.expand == "fused"
     assert single.expand == "fused"
     assert small.scan == "jnp"   # CPU: interpret-mode Pallas would be slow
-    assert plan_for(PIRConfig(n_items=1 << 10), 8, backend="tpu").scan \
-        == "pallas"
+    assert heuristic_plan(PIRConfig(n_items=1 << 10), 8,
+                          backend="tpu").scan == "pallas"
     # the fused XOR body never reaches a scan kernel: jnp on the CPU; a
     # TPU takes the megakernel instead
     assert big.scan == "jnp"
-    tpu_big = plan_for(PIRConfig(n_items=1 << 20), 8, backend="tpu")
+    tpu_big = heuristic_plan(PIRConfig(n_items=1 << 20), 8, backend="tpu")
     assert (tpu_big.expand, tpu_big.scan) == ("fused-pallas", "pallas")
 
 

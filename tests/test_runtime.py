@@ -1,4 +1,7 @@
-"""Runtime: fault policies, straggler logic, elastic planning, train loop."""
+"""Runtime: fault policies, straggler logic, elastic planning, the local
+mesh and the compile-cache location, train loop."""
+import pathlib
+
 import numpy as np
 import pytest
 
@@ -7,10 +10,13 @@ import jax
 from repro.config import MeshConfig, OptimizerConfig, RunConfig
 from repro.configs import SMOKES
 from repro.configs.shapes import SMOKE_TRAIN
+from repro.launch import compile_cache
 from repro.launch.mesh import make_local_mesh, split_devices
 from repro.runtime.elastic import carve_submeshes, plan_mesh, rebuild_mesh
 from repro.runtime.fault import (HeartbeatRegistry, PoisonPolicy,
                                  RetryStats, StragglerMonitor, retry_step)
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 # ---------------------------------------------------------------------------
@@ -222,3 +228,30 @@ def test_train_loop_resume(tmp_path):
         res = loop2.run_loop(resume=True)
     assert res.final_step == 4       # resumed at 4, nothing left to do
     assert res.losses == []
+
+
+# ---------------------------------------------------------------------------
+# launch: the local mesh and the compile-cache location
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_dir_from_env_or_fixed_path(monkeypatch):
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir is None   # JAX reads it
+        monkeypatch.delenv(compile_cache.ENV)
+        path = compile_cache.enable_compile_cache()
+        assert path == str(ROOT / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path   # fixed
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_local_mesh_never_clamps():
+    n = len(jax.devices())
+    assert make_local_mesh(data=1, model=n).devices.size == n
+    with pytest.raises(ValueError, match="needs"):
+        make_local_mesh(data=1, model=n + 1)

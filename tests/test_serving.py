@@ -146,6 +146,106 @@ def test_background_session_thread():
     assert sched.pump() == 0
 
 
+def test_underfull_batch_waits_for_the_clients_a_completion_answered():
+    """An under-full batch is cut only once ``max_wait_s`` has also passed
+    since the last completion: the queries pending when a batch completes
+    and the next query of a client it answered leave as one batch, so a
+    closed loop's groups merge instead of keeping its first cut."""
+    t = [0.0]
+    gates = [threading.Event(), threading.Event()]
+    dispatched = []
+
+    def stage(payload):
+        b = next(bb for bb in (1, 2, 4) if bb >= len(payload))
+        return payload + [payload[-1]] * (b - len(payload))
+
+    def dispatch(staged):
+        dispatched.append(tuple(staged))
+        return staged, gates[len(dispatched) - 1]
+
+    def finalize(raw, n):
+        staged, gate = raw
+        assert gate.wait(30.0)
+        return [x * 2 for x in staged[:n]]
+
+    def wait_dispatched(n):
+        deadline = time.monotonic() + 30.0
+        while len(dispatched) < n and time.monotonic() < deadline:
+            time.sleep(0.001)
+        assert len(dispatched) == n
+
+    sched = QueryScheduler(collate=list, stage=stage, dispatch=dispatch,
+                           finalize=finalize, buckets=(1, 2, 4),
+                           max_wait_s=0.005, clock=lambda: t[0])
+    sched.start()
+    try:
+        f0 = sched.submit(0)
+        t[0] = 0.01                      # q0 ripe: no completion yet
+        wait_dispatched(1)
+        pending = [sched.submit(1), sched.submit(2)]
+        t[0] = 1.0                       # both long ripe by their own age
+        gates[0].set()
+        assert f0.result(30.0) == 0      # completion at t = 1.0
+        pending.append(sched.submit(3))  # the answered client's next query
+        time.sleep(0.05)
+        assert len(dispatched) == 1      # held: no max_wait_s since then
+        t[0] = 1.006
+        wait_dispatched(2)
+        assert dispatched[1] == (1, 2, 3, 3)     # one batch, bucket 4
+        gates[1].set()
+        assert [f.result(30.0) for f in pending] == [2, 4, 6]
+    finally:
+        gates[1].set()
+        sched.stop()
+
+
+def test_underfull_batch_waits_while_a_batch_is_in_flight():
+    """Queries ripe by both clocks are not cut under-full while a batch
+    is dispatched: they queue for the device anyway, so they wait there
+    for company. Here two queries arrive during a launch that takes a
+    second; the next two complete the bucket, and all four leave as one
+    batch once the first completes."""
+    t = [0.0]
+    gates = [threading.Event(), threading.Event()]
+    dispatched = []
+    late = []
+
+    def dispatch(staged):
+        dispatched.append(tuple(staged))
+        if len(dispatched) == 1:         # two queries arrive mid-launch
+            late.extend(sched.submit(q) for q in (10, 11))
+            t[0] = 1.0
+        return staged, gates[len(dispatched) - 1]
+
+    def finalize(raw, n):
+        staged, gate = raw
+        assert gate.wait(30.0)
+        return [x * 2 for x in staged[:n]]
+
+    sched = QueryScheduler(collate=list, stage=lambda p: p,
+                           dispatch=dispatch, finalize=finalize,
+                           buckets=(1, 2, 4), max_wait_s=0.005,
+                           clock=lambda: t[0])
+    sched.start()
+    try:
+        first = [sched.submit(q) for q in range(4)]      # cut at submit
+        deadline = time.monotonic() + 30.0
+        while len(late) < 2 and time.monotonic() < deadline:
+            time.sleep(0.001)
+        time.sleep(0.05)
+        assert dispatched == [(0, 1, 2, 3)]      # (10, 11) held, not cut
+        late.extend(sched.submit(q) for q in (12, 13))
+        gates[0].set()
+        assert [f.result(30.0) for f in first] == [0, 2, 4, 6]
+        gates[1].set()
+        assert [f.result(30.0) for f in late] == [20, 22, 24, 26]
+        assert dispatched[1] == (10, 11, 12, 13)
+    finally:
+        for gate in gates:
+            gate.set()
+        sched.stop()
+
+
 def test_submit_after_stop_raises():
     """submit() on a stopped session must raise, not enqueue into a dead
     loop (the future would otherwise never resolve)."""
@@ -408,8 +508,7 @@ def test_profiler_spans_join_requests_to_their_batch(tmp_path):
     n = 1 << 6
     db = pir.make_database(np.random.default_rng(0), n, 32)
     system = MultiServerPIR(db, PIRConfig(n_items=n, item_bytes=32),
-                            make_local_mesh(), path="fused", n_queries=2,
-                            buckets=(2,),
+                            make_local_mesh(), n_queries=2, buckets=(2,),
                             client_rng=np.random.default_rng(1))
     system.query([1, 2])                             # compile outside the trace
     options = jax.profiler.ProfileOptions()
@@ -492,8 +591,8 @@ N = 1 << LOG_N
 def system():
     db = pir.make_database(np.random.default_rng(0), N, 32)
     cfg = PIRConfig(n_items=N, item_bytes=32, batch_queries=4)
-    sys2 = TwoServerPIR(db, cfg, make_local_mesh(), path="fused",
-                        n_queries=4, buckets=(4,))
+    sys2 = TwoServerPIR(db, cfg, make_local_mesh(), n_queries=4,
+                        buckets=(4,))
     return sys2, db
 
 

@@ -6,7 +6,9 @@ the ``QueryScheduler`` and reconstruction, every record compared with a
 numpy row lookup. The indices are the first and last row of every shard,
 so every shard's non-zero ``start_block`` and both ends of its leaf range
 are read. The fused chunked expand+scan and the megakernel (in interpret
-mode) each run under both cross-shard XOR collectives; one more case
+mode) each run under both cross-shard XOR collectives, each case's plan
+recorded with ``engine.record_plans`` before the session is built; one
+more case
 zeroes shard 2's partial answer before the reduce and sees exactly that
 shard's records come back wrong, so the comparison catches a dropped
 shard.
@@ -29,7 +31,7 @@ SHARDS = 4
 DROPPED = 2                      # the shard the fault case zeroes
 SEED = 2147483659
 TIMEOUT_S = 120
-#: case -> (path, collective, drop shard DROPPED)
+#: case -> (the plan's expand, its collective, drop shard DROPPED)
 CASES = {
     "fused-gather": ("fused", "gather", False),
     "fused-butterfly": ("fused", "butterfly", False),
@@ -55,8 +57,9 @@ def _serve(case: str) -> dict:
     import jax
     import jax.numpy as jnp
 
+    from repro import engine
     from repro.config import PIRConfig
-    from repro.core.protocol import XorDpf2
+    from repro.core.protocol import ExecutionPlan, XorDpf2
     from repro.launch.mesh import make_local_mesh
     from repro.runtime.serve_loop import MultiServerPIR
 
@@ -69,11 +72,14 @@ def _serve(case: str) -> dict:
                                     partial_res)
             return super().reduce(partial_res, axis, n_shards, plan)
 
-    path, collective, drop = CASES[case]
+    expand, collective, drop = CASES[case]
+    cfg = PIRConfig(n_items=N, item_bytes=32)
+    engine.record_plans(cfg, {4: ExecutionPlan(
+        expand=expand, scan="jnp" if expand == "fused" else "pallas",
+        collective=collective)})
     system = MultiServerPIR(
-        _database(), PIRConfig(n_items=N, item_bytes=32),
-        make_local_mesh(data=1, model=SHARDS), path=path,
-        collective=collective, n_queries=4, buckets=(4,),
+        _database(), cfg, make_local_mesh(data=1, model=SHARDS),
+        n_queries=4, buckets=(4,),
         protocol=DropShard() if drop else None,
         client_rng=np.random.default_rng(SEED + 1))
     held = sorted((s.device.id, s.index[0].start or 0,
@@ -90,15 +96,15 @@ def _serve(case: str) -> dict:
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_sharded_path_exact_on_four_devices(case):
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
+    env = dict(os.environ, JAX_PLATFORMS="cpu", REPRO_PLAN_CACHE="off",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env.pop("REPRO_FORCE_BACKEND", None)
     done = subprocess.run([sys.executable, __file__, case], env=env,
                           capture_output=True, text=True, timeout=TIMEOUT_S)
     assert done.returncode == 0, done.stderr[-4000:]
     got = json.loads(done.stdout.strip().splitlines()[-1])
-    path, collective, drop = CASES[case]
-    assert (got["expand"], got["collective"]) == (path, collective)
+    expand, collective, drop = CASES[case]
+    assert (got["expand"], got["collective"]) == (expand, collective)
     rows = N // SHARDS
     # each device holds exactly its N/4 rows, in row order
     assert got["held"] == [[d, d * rows, (d + 1) * rows]

@@ -2,9 +2,8 @@
 
 1. Private retrieval is *correct* at system level (client never sends the
    index; two servers answer independently; reconstruction yields the
-   record) — across DB sizes, batch sizes and server paths.
-2. The serve loop batches queries and tracks throughput stats.
-3. The LM serving integration: PIR-backed private token-embedding lookup
+   record) — across DB sizes and batch sizes, under the engine's plans.
+2. The LM serving integration: PIR-backed private token-embedding lookup
    returns bit-exact embedding rows (the Lam et al. [61] use case the
    paper benchmarks against).
 """
@@ -15,10 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import PIRConfig
-from repro.core import dpf, pir
-from repro.core.server import PIRServer
+from repro.core import pir
 from repro.launch.mesh import make_local_mesh
-from repro.runtime.serve_loop import PIRServeLoop, TwoServerPIR
+from repro.runtime.serve_loop import TwoServerPIR
 
 pytestmark = pytest.mark.slow    # compile-heavy: full-step jits on a 1-core CPU
 
@@ -34,27 +32,9 @@ def test_end_to_end_retrieval(mesh, log_n, item_bytes):
     n = 1 << log_n
     db = pir.make_database(np.random.default_rng(0), n, item_bytes)
     cfg = PIRConfig(n_items=n, item_bytes=item_bytes, batch_queries=2)
-    sys2 = TwoServerPIR(db, cfg, mesh, path="fused", n_queries=2)
+    sys2 = TwoServerPIR(db, cfg, mesh, n_queries=2)
     idx = [0, n - 1]
     np.testing.assert_array_equal(sys2.query(idx), db[idx])
-
-
-def test_serve_loop_stats(mesh):
-    n = 1 << 10
-    db = pir.make_database(np.random.default_rng(1), n, 32)
-    cfg = PIRConfig(n_items=n, batch_queries=4)
-    server = PIRServer(party=0, db_words=db, cfg=cfg, mesh=mesh,
-                       n_queries=4, path="baseline")
-    loop = PIRServeLoop(server, n_clusters=2)
-    rng = np.random.default_rng(2)
-    for step in range(3):
-        k0, _ = pir.batch_queries(rng, [step, step + 1, step + 2, step + 3],
-                                  cfg)
-        loop.submit(k0)
-    answers = loop.drain()
-    assert len(answers) == 3
-    assert loop.stats.answered == 12
-    assert loop.stats.qps > 0
 
 
 def test_private_embedding_lookup(mesh):
@@ -74,7 +54,7 @@ def test_private_embedding_lookup(mesh):
     table_words = ((table_u16[:, 1::2] << 16) | table_u16[:, 0::2])
 
     cfg = PIRConfig(n_items=vocab_pow2, item_bytes=d * 2, batch_queries=2)
-    sys2 = TwoServerPIR(table_words, cfg, mesh, path="fused", n_queries=2)
+    sys2 = TwoServerPIR(table_words, cfg, mesh, n_queries=2)
     token_ids = [17, 513]
     rows = sys2.query(token_ids)                     # [2, d/2] uint32
     # unpack back to the bf16 bit pattern

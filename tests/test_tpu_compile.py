@@ -202,7 +202,7 @@ def test_sharded_step_over_four_chips_keeps_one_copy_of_its_shard(
     monkeypatch.setenv(FORCE_BACKEND_ENV, "tpu")   # Mosaic, not interpret
     mesh = Mesh(np.asarray(topo.devices[:4]).reshape(1, 4),
                 ("data", "model"))
-    serve = BucketedServeFns(cfg, mesh, buckets=(1,), path=None)
+    serve = BucketedServeFns(cfg, mesh, buckets=(1,))
     fns, jitted = serve.fns_for(1)
     assert (fns.plan.expand, fns.plan.collective) == ("fused-pallas",
                                                       "gather")
